@@ -158,6 +158,18 @@ def serve(argv: list[str]) -> int:
 
     n_workers, why = prefork.plan_workers()
     if n_workers > 1:
+        if os.environ.get("MINIO_TPU_CODEC", "").lower() == "device":
+            # Fail in the master, once, not in N crash-looping workers: the
+            # install guard (runtime.install_data_plane_codec) would refuse
+            # in each of them. Read from the env, not through runtime: the
+            # master imports nothing stateful before it forks.
+            print(
+                "FATAL: MINIO_TPU_CODEC=device with MTPU_WORKERS>1: pre-fork "
+                "workers are separate processes and one chip belongs to one "
+                "process",
+                file=sys.stderr,
+            )
+            return 1
         _log(a.quiet, a.json, msg="prefork", workers=n_workers, detail=why)
         return prefork.run_master(n_workers, lambda _wid: serve(argv))
 
@@ -259,11 +271,18 @@ def serve(argv: list[str]) -> int:
         t.join(5)
         return 0
     n_sets = sum(len(p.sets) for p in node.pools.pools)
+    from .object.codec import default_codec
+    from .runtime import install_status
+
+    # The codec serving NOW and where the device install stands: under the
+    # background install the host codec serves first, and runtime logs the
+    # takeover (platform, device kind and count, kernels) when it lands.
     _log(
         a.quiet,
         a.json,
         msg="online",
-        codec=type(node.codec).__name__,
+        codec=type(default_codec()).__name__,
+        device_codec=install_status()["state"],
         drives=len(node.drives),
         pools=len(node.pools.pools),
         sets=n_sets,

@@ -355,6 +355,15 @@ class MetricsSys:
                 help_="1 when the probe found a usable accelerator.",
                 type_="gauge",
             )
+        # The takeover itself: under the background install the host codec
+        # serves first, and this flips once the warmed device codec has.
+        inst = runtime.install_status()
+        metric(
+            "minio_tpu_device_codec_serving", 1 if inst["state"] == "serving" else 0,
+            {"platform": inst.get("platform") or "none"},
+            help_="1 once the warmed, oracle-checked device codec serves.",
+            type_="gauge",
+        )
         # Verdict flips (ok->fail "fallback", fail->ok "recovery"): the two
         # probe events an operator pages on, counted per process.
         for kind, n in sorted(runtime.probe_transition_counts().items()):

@@ -236,13 +236,19 @@ class Node:
             # Install the served data-plane codec: the cross-request batching
             # device pipeline when an accelerator is reachable, host C++
             # otherwise (the reference's always-on fast codec,
-            # erasure-coding.go:63). Probed with a bounded timeout on a
-            # background thread so a wedged device tunnel cannot hang boot;
-            # the layer is built with codec=None so it resolves the process
-            # default lazily and picks up the async device upgrade.
+            # erasure-coding.go:63). Probe, chip open and warm-up of this
+            # deployment's geometry run on a background thread, so neither a
+            # wedged device init nor a cold compile can hang boot; the layer
+            # is built with codec=None so it resolves the process default
+            # lazily and picks up the device codec when it takes over.
+            from ..object.erasure import default_parity
             from ..runtime import install_data_plane_codec
 
-            self.codec = install_data_plane_codec(background=True)
+            n = self.set_drive_count
+            m = default_parity(n) if self.parity is None else self.parity
+            self.codec = install_data_plane_codec(
+                background=True, geometry=(n - m, m) if m > 0 else None
+            )
             layer_codec = None
         else:
             codec_mod.set_default_codec(self.codec)

@@ -34,6 +34,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import jaxenv
 from . import highwayhash_jax as hhj
 from .highwayhash import MAGIC_KEY, _INIT0, _INIT1
 
@@ -131,7 +132,7 @@ def _run_chain(init: jax.Array, packets: jax.Array, n_chunks: int) -> jax.Array:
         out_specs=pl.BlockSpec((4, 2, 4, TILE_N), lambda i, j: (0, 0, 0, i)),
         out_shape=jax.ShapeDtypeStruct((4, 2, 4, n), jnp.uint32),
         scratch_shapes=[pltpu.VMEM((4, 2, 4, TILE_N), jnp.uint32)],
-        interpret=jax.default_backend() == "cpu",
+        interpret=not jaxenv.on_tpu(),
     )(init, packets)
 
 

@@ -1,9 +1,14 @@
 """ctypes loader for the native host kernels (native/minio_native.cpp).
 
 Builds the shared library on first use if g++ is available (no pip deps);
-callers fall back to numpy when the toolchain is missing. The native kernels
-are bit-exact with the Python ones -- tests cross-check all three paths
-(numpy / native / JAX) against the reference golden vectors.
+callers fall back to numpy when the toolchain is missing -- about 10x slower
+on host tails and GET verification, so the fallback is logged, exported
+(minio_tpu_native_codec_available) and refused by chip_smoke.py. The library
+is compiled -march=native and git-ignored: it belongs to the machine that
+built it (build() recompiles in place; .chiprunignore keeps a sandbox binary
+off the chip's host). The native kernels are bit-exact with the Python ones
+-- tests cross-check all three paths (numpy / native / JAX) against the
+reference golden vectors.
 """
 
 from __future__ import annotations
@@ -24,10 +29,13 @@ _lock = san_lock("native._lock")
 _tried = False
 
 
-def _build() -> bool:
+def build() -> str | None:
+    """Compile native/*.cpp for THIS machine's CPU and install the library;
+    None on success, else why not (the compiler's own words). A process that
+    already loaded the library keeps the mapping it has."""
     kernel = os.path.join(_NATIVE_DIR, "minio_native.cpp")
     if not os.path.isfile(kernel):
-        return False  # the RS/HH kernels are mandatory; IO layer is additive
+        return f"{kernel} missing"  # the RS/HH kernels are mandatory; IO layer is additive
     srcs = [kernel]
     io_src = os.path.join(_NATIVE_DIR, "minio_io.cpp")
     if os.path.isfile(io_src):
@@ -45,9 +53,11 @@ def _build() -> bool:
             timeout=120,
         )
         os.replace(tmp, _LIB_PATH)
-        return True
-    except (subprocess.SubprocessError, FileNotFoundError, OSError):
-        return False
+        return None
+    except subprocess.CalledProcessError as e:
+        return f"g++ exit {e.returncode}: {e.stderr.decode(errors='replace')[-2000:]}"
+    except (subprocess.SubprocessError, FileNotFoundError, OSError) as e:
+        return f"{type(e).__name__}: {e}"
 
 
 def _stale() -> bool:
@@ -70,12 +80,13 @@ def load() -> ctypes.CDLL | None:
         if _lib is not None or _tried:
             return _lib
         _tried = True
-        if _stale() and not _build() and not os.path.isfile(_LIB_PATH):
-            return None
+        why = build() if _stale() else None
+        if why is not None and not os.path.isfile(_LIB_PATH):
+            return _numpy_serves(why)
         try:
             lib = ctypes.CDLL(_LIB_PATH)
-        except OSError:
-            return None
+        except OSError as e:
+            return _numpy_serves(f"cannot load {_LIB_PATH}: {e}")
         u8p = ctypes.POINTER(ctypes.c_uint8)
         lib.rs_encode.argtypes = [ctypes.c_int, ctypes.c_int, u8p, u8p, u8p, ctypes.c_size_t]
         lib.rs_apply.argtypes = lib.rs_encode.argtypes
@@ -118,6 +129,16 @@ def load() -> ctypes.CDLL | None:
             pass
         _lib = lib
         return _lib
+
+
+def _numpy_serves(why: str) -> None:
+    from ..control.logging import GLOBAL_LOGGER
+
+    GLOBAL_LOGGER.error(
+        "native host kernels unavailable; numpy serves host-codec blocks and "
+        f"GET verification about 10x slower: {why}"
+    )
+    return None
 
 
 def available() -> bool:

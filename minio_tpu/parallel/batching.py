@@ -235,6 +235,92 @@ class BatchingDeviceCodec(BlockCodec):
                 self._threads[key] = t
         return self._queues[key]
 
+    # -- warm-up ---------------------------------------------------------------
+
+    def warm(self, k: int, m: int) -> dict:
+        """Compile, run and oracle-check the programs a (k, m) deployment's
+        PUT path reaches, before this codec serves.
+
+        A request waits at most 60 s for its batch (_encode), and on the chip
+        each program costs seconds to tens of seconds to compile, so nothing
+        the encode path can reach may compile under a request: one fused
+        encode+hash program per padded batch size, and one parity program per
+        (batch bucket x shard-length bucket) of the small-object queue. On
+        the CPU backend (tests, sandbox dry runs) programs compile in about a
+        second and are not worth a minute of boot, so only the smallest of
+        each kind runs there -- same code, shorter list. Two reconstruct
+        programs (degraded GET and heal of the first m data rows) prove the
+        decode side; other loss patterns compile on first use, off any
+        timeout.
+
+        Every output is compared with the host codec: a kernel that runs but
+        disagrees raises here, not in a user's object. Returns
+        {"programs", "seconds"}."""
+        from concurrent.futures import ThreadPoolExecutor
+
+        from .. import jaxenv
+
+        t0 = _time.perf_counter()
+        self._ensure_worker(k, m)  # builds the pipeline: kernel selection runs here
+        pipe = self._pipelines[(k, m)]
+        full = jaxenv.on_tpu()
+        s = rs_matrix.shard_size(self.block_size, k)
+        dp = pipe.mesh.shape["dp"] if pipe.mesh is not None else 1
+        batches = _BUCKETS if full else _BUCKETS[:1]
+        plan: list[tuple] = [
+            ("encode", b, s) for b in sorted({-(-b // dp) * dp for b in batches})
+        ]
+        if self.small_wait_s is not None:
+            lens = []  # every shard-length bucket a [4 KiB, block) tail maps to
+            n = _len_bucket(rs_matrix.shard_size(_SMALL_MIN, k))
+            while n <= _len_bucket(rs_matrix.shard_size(self.block_size - 1, k)):
+                lens.append(n)
+                n <<= 1
+            plan += [("parity", b, n) for b in batches for n in (lens if full else lens[:1])]
+        recon_b = 16 if full else 2  # one codec group, as GET and heal send it
+        plan += [("reconstruct", recon_b, s), ("reconstruct+digests", recon_b, s)]
+
+        def host_digests(rows: np.ndarray) -> np.ndarray:  # [B, T, S] -> [B, T, 32]
+            flat = np.ascontiguousarray(rows).reshape(-1, rows.shape[-1])
+            return self._host._digests(flat).reshape(*rows.shape[:2], 32)
+
+        def run(step: tuple) -> None:
+            kind, b, n = step
+            rng = np.random.default_rng(b * 1_000_003 + n)
+            data = rng.integers(0, 256, (b, k, n), dtype=np.uint8)
+            want = np.stack([self._host._encode_one(data[i], m) for i in range(b)])
+            if kind == "encode":
+                got, digests = pipe.encode(data)
+                want_rows = want
+            elif kind == "parity":
+                got, digests = pipe.encode_parity(data), None
+                want_rows = want[:, k:]
+            else:  # the first m data rows lost, rebuilt from the next k rows
+                present = (False,) * m + (True,) * k
+                got, digests = pipe.reconstruct(
+                    want[:, m : m + k], present, tuple(range(m)),
+                    with_digests=kind.endswith("digests"),
+                )
+                want_rows = want[:, :m]
+            ok = np.array_equal(np.asarray(got), want_rows) and (
+                digests is None
+                or np.array_equal(np.asarray(digests), host_digests(want_rows))
+            )
+            if not ok:
+                raise RuntimeError(
+                    f"device {kind} program (batch {b}, shard {n} B, {k}+{m}) "
+                    "disagrees with the host codec"
+                )
+
+        # XLA compiles outside the GIL: a few threads cut a cold boot's
+        # compile wall without changing what is compiled.
+        with ThreadPoolExecutor(
+            max_workers=min(8, os.cpu_count() or 1), thread_name_prefix="codec-warm"
+        ) as pool:
+            for f in [pool.submit(run, step) for step in plan]:
+                f.result()
+        return {"programs": len(plan), "seconds": round(_time.perf_counter() - t0, 3)}
+
     def _collect(self, q: queue.Queue, first, window_s: float) -> list:
         batch = [first]
         start = _time.monotonic()

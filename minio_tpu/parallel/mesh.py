@@ -30,25 +30,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 AXES = ("dp", "tp", "sp")
 
 
-def shard_map_compat(f, mesh: Mesh, in_specs, out_specs):
-    """shard_map across jax versions.
-
-    Newer jax exposes jax.shard_map (check_vma kwarg); 0.4.x only has
-    jax.experimental.shard_map.shard_map (check_rep kwarg). Both flags off:
-    the encode->hash all-to-all mixes parameter-aliasing and computed rows,
-    which the replication checker rejects.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
-    )
-
-
 def factor_mesh(n: int) -> tuple[int, int, int]:
     """Split n devices into (dp, tp, sp), preferring dp >= tp >= sp."""
     best = (n, 1, 1)

@@ -45,13 +45,86 @@ def test_install_auto_cpu_platform_uses_host(monkeypatch):
     assert isinstance(codec, HostCodec)
 
 
-def test_install_auto_accelerator_uses_batching(monkeypatch):
-    monkeypatch.setattr(runtime, "probe_device", lambda t: runtime.ProbeResult("tpu"))
+@pytest.fixture
+def _fake_tpu(monkeypatch):
+    """The probe child and this process both report a TPU: the install runs
+    its device branch on the CPU backend (the short warm-up plan)."""
+    monkeypatch.setattr(runtime, "probe_device", lambda t: runtime.ProbeResult("tpu", "fake", 1))
+    monkeypatch.setattr(runtime, "_open_backend", lambda: "tpu")
+
+
+def test_install_auto_accelerator_uses_batching(_fake_tpu):
     codec = runtime.install_data_plane_codec(mode="auto")
     try:
         assert isinstance(codec, BatchingDeviceCodec)
+        # Warmed and oracle-checked before it serves, and the takeover is
+        # exported: nothing about the device install is silent.
+        inst = runtime.probe_summary()["install"]
+        assert inst["state"] == "serving" and inst["codec"] == "BatchingDeviceCodec"
+        assert inst["warm"]["programs"] >= 4 and inst["geometry"] == [12, 4]
+        assert set(inst["kernels"]) == {"rs", "hash"}
+        assert codec.blocks_encoded == 0  # warm-up is not served traffic
     finally:
         runtime.shutdown_data_plane(codec)
+
+
+def test_install_device_mode_raises_without_accelerator():
+    """MINIO_TPU_CODEC=device on a host whose jax opens the CPU must raise,
+    not serve XLA-on-CPU under the device codec's name."""
+    prev = codec_mod._default
+    with pytest.raises(RuntimeError, match="'tpu' backend but jax opened 'cpu'"):
+        runtime.install_data_plane_codec(mode="device")
+    assert codec_mod._default is prev
+
+
+def test_install_auto_raises_when_parent_opens_another_backend(monkeypatch):
+    """The probe child saw a chip but this process's jax fell back to the
+    CPU: the synchronous install raises instead of hiding the device."""
+    monkeypatch.setattr(runtime, "probe_device", lambda t: runtime.ProbeResult("tpu"))
+    with pytest.raises(RuntimeError, match="jax opened 'cpu'"):
+        runtime.install_data_plane_codec(mode="auto")
+
+
+def test_install_rejects_unknown_mode():
+    with pytest.raises(ValueError, match="MINIO_TPU_CODEC"):
+        runtime.install_data_plane_codec(mode="gpu")
+
+
+def test_warmup_failure_keeps_host_codec_and_is_exported(_fake_tpu, monkeypatch):
+    """A device that fails (or disagrees with the oracle) at warm-up never
+    takes over in the background install; the reason is exported."""
+    def boom(self, k, m):
+        raise RuntimeError("device encode program disagrees with the host codec")
+
+    monkeypatch.setattr(BatchingDeviceCodec, "warm", boom)
+    host = codec_mod.HostCodec()
+    codec_mod.set_default_codec(host)
+    runtime._takeover("tpu", (12, 4))
+    assert codec_mod.default_codec() is host
+    inst = runtime.probe_summary()["install"]
+    assert inst["state"] == "failed" and "disagrees" in inst["reason"]
+    with pytest.raises(RuntimeError, match="disagrees"):
+        runtime.install_data_plane_codec(mode="auto")  # synchronous: raises
+
+
+def test_prefork_worker_never_opens_the_device(monkeypatch):
+    """MTPU_WORKERS: N processes on one chip. Workers install the host codec
+    without probing and say why; device mode there is an error."""
+    from minio_tpu.api.prefork import WORKER_ENV
+
+    def no_probe(t):
+        raise AssertionError("a pre-fork worker must not probe")
+
+    monkeypatch.setenv(WORKER_ENV, "1")
+    monkeypatch.setattr(runtime, "probe_device", no_probe)
+    monkeypatch.setattr(runtime, "_open_backend", no_probe)
+    for background in (False, True):
+        codec = runtime.install_data_plane_codec(mode="auto", background=background)
+        assert isinstance(codec, HostCodec)
+        inst = runtime.probe_summary()["install"]
+        assert inst["state"] == "host" and "pre-fork worker" in inst["reason"]
+    with pytest.raises(RuntimeError, match="MTPU_WORKERS"):
+        runtime.install_data_plane_codec(mode="device")
 
 
 def test_put_object_runs_device_pipeline(tmp_path):
@@ -59,7 +132,7 @@ def test_put_object_runs_device_pipeline(tmp_path):
     pipeline when the device codec is installed -- even on a layer built
     before the install (lazy default-codec resolution)."""
     hz = ErasureHarness(tmp_path, n_disks=8)  # built while HostCodec is default
-    codec = runtime.install_data_plane_codec(mode="device")
+    codec = runtime.install_data_plane_codec(mode="xla-cpu", geometry=(4, 4))
     try:
         assert isinstance(codec, BatchingDeviceCodec)
         assert hz.layer.codec is codec
@@ -67,7 +140,6 @@ def test_put_object_runs_device_pipeline(tmp_path):
         body = rng.integers(0, 256, (1 << 20) + 4096, dtype=np.uint8).tobytes()
         hz.layer.make_bucket("b")
         hz.layer.put_object("b", "o", body)
-        # Warmup may add blocks; the served full block must be among them.
         assert codec.blocks_encoded >= 1
         assert codec.batches_run >= 1
         _, got = hz.layer.get_object("b", "o")
@@ -94,6 +166,7 @@ def test_background_upgrade_reaches_serving_layer(tmp_path, monkeypatch):
         return runtime.ProbeResult("tpu")
 
     monkeypatch.setattr(runtime, "probe_device", slow_probe)
+    monkeypatch.setattr(runtime, "_open_backend", lambda: "tpu")
     monkeypatch.setenv("MINIO_TPU_CODEC", "auto")
     endpoints = [str(tmp_path / f"d{i}") for i in range(4)]
     node = Node(endpoints, root_user="a" * 8, root_password="b" * 12).build()
@@ -103,7 +176,7 @@ def test_background_upgrade_reaches_serving_layer(tmp_path, monkeypatch):
         assert isinstance(layer.codec, HostCodec)
         assert probe_started.wait(5)
         probe_release.set()
-        deadline = 10
+        deadline = 60  # the takeover follows the geometry's warm-up
         import time
 
         t0 = time.monotonic()
@@ -121,7 +194,7 @@ def test_node_build_installs_codec(tmp_path, monkeypatch):
     serves through it."""
     from minio_tpu.dist.node import Node
 
-    monkeypatch.setenv("MINIO_TPU_CODEC", "device")
+    monkeypatch.setenv("MINIO_TPU_CODEC", "xla-cpu")
     endpoints = [str(tmp_path / f"d{i}") for i in range(4)]
     node = Node(endpoints, root_user="a" * 8, root_password="b" * 12).build()
     try:
@@ -219,13 +292,14 @@ def test_recovery_reprobe_reinstalls_device_codec(monkeypatch):
         return verdicts.pop(0) if verdicts else runtime.ProbeResult("tpu")
 
     monkeypatch.setattr(runtime, "probe_device", probe)
+    monkeypatch.setattr(runtime, "_open_backend", lambda: "tpu")
     monkeypatch.setenv("MTPU_PROBE_RECOVERY_S", "0.05")
     codec = runtime.install_data_plane_codec(mode="auto")
     try:
         assert isinstance(codec, HostCodec)  # boot verdict: fall back
         t0 = time.monotonic()
         while not isinstance(codec_mod.default_codec(), BatchingDeviceCodec):
-            assert time.monotonic() - t0 < 10, "recovery re-probe never landed"
+            assert time.monotonic() - t0 < 60, "recovery re-probe never landed"
             time.sleep(0.02)
         # The daemon exits after the swap: one recovery, then done.
         t = runtime._reprobe_thread
@@ -262,7 +336,7 @@ def test_recovery_reprobe_stops_on_shutdown(monkeypatch):
 def test_probe_summary_shape(monkeypatch):
     monkeypatch.setenv("MTPU_PROBE_RECOVERY_S", "0")
     s = runtime.probe_summary()
-    assert set(s) >= {"done", "ok", "platform", "cached",
-                      "transition", "transition_counts", "recovery"}
+    assert set(s) >= {"done", "ok", "platform", "cached", "install", "error",
+                      "detail", "transition", "transition_counts", "recovery"}
     assert s["recovery"]["interval_s"] == 0.0
     assert set(s["transition_counts"]) == {"fallback", "recovery"}

@@ -54,13 +54,8 @@ def main(argv: list[str]) -> int:
                     help="write Prometheus exposition of the report here")
     args = ap.parse_args(argv)
 
-    # Satellite knobs: cache the device-probe verdict across runs (no
-    # re-paying a 180 s init wedge per invocation), and sample trace-span
-    # publication so high concurrency doesn't flood the hub/slow-ring
-    # (the perf ledger still sees every request).
-    os.environ.setdefault(
-        "MTPU_PROBE_CACHE", os.path.join(tempfile.gettempdir(), "mtpu_probe_cache.json")
-    )
+    # Sample trace-span publication so high concurrency doesn't flood the
+    # hub/slow-ring (the perf ledger still sees every request).
     os.environ.setdefault("MTPU_TRACE_SAMPLE", "0.1")
 
     from minio_tpu.loadgen.runner import ScenarioRunner
