@@ -1,0 +1,167 @@
+"""A cell from its data files.
+
+``BENCHMARK.json`` names a cell's configuration and traffic mix; the files are
+found by those names:
+
+    benchmark/configs/<config>.json     the deployment
+    benchmark/traffic/<traffic>.json    the traffic mix, parameters of the one
+                                        generator (client_worker.py)
+    benchmark/metrics/<metric>.json     a per-layer metric and its reader
+
+An unknown field in any of them is an error: a typo must not silently run the
+default. Adding a cell, a configuration or a metric with an existing reader is
+adding files and manifest entries, never code.
+"""
+
+from __future__ import annotations
+
+import json
+import os
+
+from . import readers, roofline
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+BENCH_DIR = os.path.dirname(HERE)
+ROOT = os.path.dirname(BENCH_DIR)
+MANIFEST = os.path.join(ROOT, "BENCHMARK.json")
+
+CONFIG_FIELDS = {
+    "name", "source", "deployment", "drives", "parity", "data", "block_bytes",
+    "chips", "drive_medium", "drive_medium_why", "env", "guarantees", "reduced",
+    "assumed", "device_state",
+}
+TRAFFIC_FIELDS = {
+    "name", "source", "reduced", "assumed", "why", "loop", "clients", "object_bytes",
+    "mix", "keys", "prepare", "typical_op_s", "ramp_s", "trace_seconds", "check",
+    "op_timeout_s", "tmpfs_bytes", "tmpfs_why",
+}
+METRIC_FIELDS = {"name", "unit", "better", "layer", "moves", "source", "workloads",
+                 "reader", "what"}
+PREPARE_STEPS = {"populate", "lose_shards"}
+CHECK_FIELDS = {"readback_sample", "degraded_sample"}
+OPS = {"PUT", "GET", "STAT", "DELETE"}
+
+
+def _load(path: str, fields: set[str], what: str) -> dict:
+    with open(path) as f:
+        doc = json.load(f)
+    extra = set(doc) - fields
+    if extra:
+        raise ValueError(f"{what} {path}: unknown fields {sorted(extra)}")
+    return doc
+
+
+def manifest() -> dict:
+    with open(MANIFEST) as f:
+        return json.load(f)
+
+
+def load_config(name: str) -> dict:
+    cfg = _load(os.path.join(BENCH_DIR, "configs", f"{name}.json"), CONFIG_FIELDS, "configuration")
+    for key in ("name", "source", "drives", "parity", "data", "block_bytes", "chips",
+                "drive_medium", "guarantees"):
+        if key not in cfg:
+            raise ValueError(f"configuration {name}: missing {key!r}")
+    if cfg["name"] != name:
+        raise ValueError(f"configuration file {name}.json names itself {cfg['name']!r}")
+    if cfg["data"] + cfg["parity"] != cfg["drives"]:
+        raise ValueError(f"configuration {name}: data + parity != drives")
+    if cfg["drive_medium"] != "tmpfs":
+        raise ValueError(f"configuration {name}: drive_medium {cfg['drive_medium']!r}: only "
+                         "tmpfs has a harness; a real-disk medium is a later benchmark PR")
+    return cfg
+
+
+def load_traffic(name: str) -> dict:
+    t = _load(os.path.join(BENCH_DIR, "traffic", f"{name}.json"), TRAFFIC_FIELDS, "traffic mix")
+    for key in ("name", "source", "why", "loop", "clients", "object_bytes", "mix", "keys",
+                "typical_op_s", "ramp_s", "tmpfs_bytes"):
+        if key not in t:
+            raise ValueError(f"traffic mix {name}: missing {key!r}")
+    if t["name"] != name:
+        raise ValueError(f"traffic file {name}.json names itself {t['name']!r}")
+    if t["loop"] != "closed":
+        raise ValueError(f"traffic mix {name}: loop {t['loop']!r}: the generator is closed-loop; "
+                         "an open loop at a fixed rate is a later benchmark PR")
+    if set(t["mix"]) - OPS or sum(t["mix"].values()) != 100:
+        raise ValueError(f"traffic mix {name}: mix must share 100 among {sorted(OPS)}")
+    if t["keys"].get("kind") not in ("ring", "pool"):
+        raise ValueError(f"traffic mix {name}: keys.kind must be ring or pool")
+    if set(t["mix"]) != {"PUT"} and t["keys"]["kind"] != "pool":
+        raise ValueError(f"traffic mix {name}: reads and deletes need keys.kind pool")
+    for step in t.get("prepare", []):
+        if len(step) != 1 or set(step) - PREPARE_STEPS:
+            raise ValueError(f"traffic mix {name}: unknown prepare step {step}")
+    if set(t.get("check", {})) - CHECK_FIELDS:
+        raise ValueError(f"traffic mix {name}: unknown check fields")
+    if t["ramp_s"] < t["typical_op_s"]:
+        raise ValueError(f"traffic mix {name}: ramp_s is shorter than typical_op_s")
+    return t
+
+
+def load_metric(name: str) -> dict:
+    m = _load(os.path.join(BENCH_DIR, "metrics", f"{name}.json"), METRIC_FIELDS, "metric")
+    if m.get("name") != name:
+        raise ValueError(f"metric file {name}.json names itself {m.get('name')!r}")
+    readers.validate(name, m["reader"])
+    return m
+
+
+class Cell:
+    """One entry of ``workloads`` with everything it names, resolved."""
+
+    def __init__(self, workload: str, man: dict | None = None, rehearse: bool = False):
+        man = man or manifest()
+        rows = [w for w in man["workloads"] if w["name"] == workload]
+        if not rows:
+            raise ValueError(f"workload {workload!r} is not in BENCHMARK.json "
+                             f"({[w['name'] for w in man['workloads']]})")
+        self.entry = rows[0]
+        self.name = workload
+        self.chips = int(self.entry["chips"])
+        self.config = load_config(self.entry["config"])
+        self.traffic = load_traffic(self.entry["traffic"])
+        if rehearse:
+            self._shrink()
+        if self.config["chips"] != self.chips:
+            raise ValueError(f"cell {workload}: chips {self.chips} but configuration "
+                             f"{self.config['name']} is laid out for {self.config['chips']}")
+        self.end_to_end = [m for m in man["end_to_end"]
+                           if workload in m.get("workloads", [workload])]
+        self.per_layer = []
+        for m in man["per_layer"]:
+            if workload in m.get("workloads", [workload]):
+                self.per_layer.append({**load_metric(m["name"]), **m})
+        k, m_ = self.config["data"], self.config["parity"]
+        self.geometry = (k, m_, roofline.shard_bytes(self.config["block_bytes"], k))
+
+    def _shrink(self) -> None:
+        """The sandbox rehearsal's tiny sizes: the same files, control flow and
+        check, on a CPU that encodes a few MiB a second."""
+        t = self.traffic
+        t["object_bytes"] = min(int(t["object_bytes"]), 2 << 20)
+        t["clients"] = min(int(t["clients"]), 4)
+        if t["keys"]["kind"] == "pool":
+            t["keys"] = {"kind": "pool", "objects": 3 * t["clients"]}
+        t["ramp_s"], t["typical_op_s"] = 1.0, 0.5
+        t["tmpfs_bytes"] = int(t["tmpfs_bytes"]) // 100
+
+    @property
+    def clients(self) -> int:
+        return int(self.traffic["clients"])
+
+    def footprint_bytes(self) -> int:
+        """Room the drives need under /dev/shm: the traffic file states it, for
+        the wider geometry of its cells (the file says why); the rehearsal's
+        tiny objects need a hundredth."""
+        return int(self.traffic["tmpfs_bytes"])
+
+    def client_spec(self, idx: int, seed: int, endpoint: str, bucket: str,
+                    access: str, secret: str, region: str) -> dict:
+        t = self.traffic
+        return {
+            "client": idx, "clients": self.clients, "seed": seed, "endpoint": endpoint,
+            "bucket": bucket, "access": access, "secret": secret, "region": region,
+            "object_bytes": int(t["object_bytes"]), "mix": t["mix"], "keys": t["keys"],
+            "timeout_s": float(t.get("op_timeout_s", 120.0)),
+        }

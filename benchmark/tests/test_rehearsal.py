@@ -1,0 +1,215 @@
+"""The whole run on jax's CPU backend at tiny sizes: the output check passes on
+the sound program, and comes out false for the control and for each fault the
+cells can have, planted underneath the timed path.
+
+Run by hand (about four minutes; each case boots a server in this process):
+
+    JAX_PLATFORMS=cpu python3 -m pytest benchmark/tests/test_rehearsal.py -q -p no:cacheprovider
+
+These skip the harness's look for a chip (``--rehearse``) and drive the rest of
+a run: clients as processes, ramp, window, drain, read-back, degraded read-back.
+Of the faults the builder's contract lists, "the exchange between chips left
+out" has no cell here: every cell takes one chip.
+"""
+
+import argparse
+import os
+
+import numpy as np
+import pytest
+
+from benchmark.harness import run as bench_run
+from benchmark.harness import server
+
+pytestmark = pytest.mark.skipif(
+    os.environ.get("JAX_PLATFORMS", "") != "cpu",
+    reason="the rehearsal serves the device programs on jax's CPU backend: set JAX_PLATFORMS=cpu",
+)
+
+
+def args(workload, seed, seconds=8.0, control=None):
+    return argparse.Namespace(workload=workload, seed=seed, seconds=seconds, trace=0,
+                              rehearse=True, control=control, describe_trace=None, dump_ops=None)
+
+
+def numbers(line):
+    return {k: v["value"] for k, v in line["compared"].items()}
+
+
+def test_sound_program_is_correct_and_prints_no_device_metric():
+    rc, line = bench_run.execute(args("mixed10m-c20", 11))
+    assert rc == 0 and line["correct"] is True, line
+    assert line["metrics"] == {} and line["rehearsal"] is True
+    assert line["device"]["platform"] == "cpu"
+    assert all(v == 0 for v in numbers(line).values())
+    assert list(line)[-1] == "compared"
+    assert not [d for d in os.listdir(server.SHM) if d == f"{server.PREFIX}{os.getpid()}"]
+
+
+def test_control_one_parity_shard_fewer_is_not_correct():
+    """The step that would tempt a later PR: 13+3 writes less and encodes
+    faster, and nothing shows until four drives are lost."""
+    rc, line = bench_run.execute(args("put64m-c8", 12, seconds=10.0, control="parity-1"))
+    assert rc == 0 and line["correct"] is False
+    n = numbers(line)
+    assert n["degraded_mismatch"] >= 1
+    assert n["ops_failed"] == 0 and n["readback_mismatch"] == 0
+
+
+def _break_encode(mutate):
+    """Plant a fault where the device's answer is produced: the pipeline's
+    encode, which returns (shards [B, K+M, S], digests [B, K+M, 32])."""
+    from minio_tpu.models import pipeline
+
+    orig = pipeline.ErasurePipeline.encode
+
+    def broken(self, data_shards):
+        shards, digests = orig(self, data_shards)
+        shards, digests = np.array(shards), np.array(digests)
+        mutate(self, shards, digests)
+        return shards, digests
+
+    pipeline.ErasurePipeline.encode = broken
+    return lambda: setattr(pipeline.ErasurePipeline, "encode", orig)
+
+
+def _run_broken(workload, seed, mutate):
+    """The fault goes in once the server has started: the install's own
+    warm-up holds every program to the host codec and refuses a wrong one, so a
+    fault that is there from the start never serves."""
+    restore = []
+    try:
+        return bench_run.execute(args(workload, seed, seconds=10.0),
+                                 deployment_hook=lambda dep: restore.append(_break_encode(mutate)))
+    finally:
+        for r in restore:
+            r()
+
+
+def test_fault_parity_altered_where_it_is_produced():
+    """One parity byte of every block flipped, and the row's digest made to
+    match: bitrot verification passes, only the bytes read back can tell."""
+    from minio_tpu.ops.highwayhash import hash256
+
+    def mutate(pipe, shards, digests):
+        k = pipe.geom.data
+        shards[:, k, 0] ^= 0x5A
+        for b in range(shards.shape[0]):
+            digests[b, k] = np.frombuffer(hash256(shards[b, k].tobytes()), dtype=np.uint8)
+
+    rc, line = _run_broken("put64m-c8", 13, mutate)
+    assert rc == 0 and line["correct"] is False
+    assert numbers(line)["degraded_mismatch"] >= 1
+
+
+def test_fault_half_of_the_batch_left_out():
+    """The second half of every device batch comes back without parity."""
+
+    def mutate(pipe, shards, digests):
+        half = max(1, shards.shape[0] // 2)
+        shards[half:, pipe.geom.data:] = 0
+        if shards.shape[0] == 1:
+            shards[:, pipe.geom.data:] = 0
+
+    rc, line = _run_broken("put64m-c8", 14, mutate)
+    assert rc == 0 and line["correct"] is False
+    assert numbers(line)["degraded_mismatch"] >= 1
+
+
+def test_fault_put_returns_the_state_unchanged():
+    """A PUT over an existing key is acknowledged and stores nothing."""
+    from minio_tpu.utils import errors
+    from minio_tpu.object.erasure import ErasureObjects
+
+    orig = ErasureObjects.put_object
+
+    def unchanged(self, bucket, object_name, data, opts=None):
+        try:
+            info = self.get_object_info(bucket, object_name)
+        except errors.ObjectNotFound:
+            return orig(self, bucket, object_name, data, opts)
+        if hasattr(data, "read"):
+            while data.read(1 << 20):
+                pass
+        return info
+
+    ErasureObjects.put_object = unchanged
+    try:
+        rc, line = bench_run.execute(args("mixed10m-c20", 15, seconds=10.0))
+    finally:
+        ErasureObjects.put_object = orig
+    assert rc == 0 and line["correct"] is False
+    n = numbers(line)
+    assert n["ops_failed"] + n["readback_mismatch"] >= 1
+
+
+def test_fault_get_answer_altered():
+    """One byte of every GET's answer flipped on its way out."""
+    from minio_tpu.object.erasure import ErasureObjects
+
+    orig = ErasureObjects.get_object_stream
+
+    def altered(self, *a, **kw):
+        return _flip_first_byte(orig(self, *a, **kw))
+
+    ErasureObjects.get_object_stream = altered
+    try:
+        rc, line = bench_run.execute(args("mixed10m-c20", 16))
+    finally:
+        ErasureObjects.get_object_stream = orig
+    assert rc == 0 and line["correct"] is False
+    assert numbers(line)["ops_failed"] >= 1
+
+
+def _flip_first_byte(out):
+    """get_object_stream returns (info, iterator of chunks): flip the first
+    byte of the first chunk."""
+    info, body = out
+
+    def gen():
+        first = True
+        for chunk in body:
+            if first and len(chunk):
+                c = bytearray(chunk)
+                c[0] ^= 0xFF
+                chunk, first = bytes(c), False
+            yield chunk
+
+    return info, gen()
+
+
+def test_lose_shards_prepare_step_removes_data_shards_and_reads_still_answer():
+    """`prepare: [{populate}, {lose_shards: {data: 4}}]` (no first cell uses it;
+    the degraded-GET cell will): every object then reads through reconstruct."""
+    seen = {}
+
+    def hook(dep):
+        orig = dep.lose_shards
+
+        def counting(key, data):
+            victims = orig(key, data)
+            seen[key] = victims
+            return victims
+
+        dep.lose_shards = counting
+
+    cell_cls = bench_run.Cell
+
+    class WithLoss(cell_cls):
+        def __init__(self, *a, **kw):
+            super().__init__(*a, **kw)
+            self.traffic["prepare"] = [{"populate": {}}, {"lose_shards": {"data": 4}}]
+            self.traffic["mix"] = {"GET": 70, "STAT": 30}
+
+    bench_run.Cell = WithLoss
+    try:
+        rc, line = bench_run.execute(args("mixed10m-c20", 17), deployment_hook=hook)
+    finally:
+        bench_run.Cell = cell_cls
+    assert rc == 0, line
+    n = numbers(line)
+    assert n["ops_failed"] == 0 and n["readback_mismatch"] == 0
+    assert len(seen) >= 12 and all(len(v) == 4 for v in seen.values())
+    # Nothing was PUT in the window, so there is no degraded sample: the run is
+    # not `correct` by default, it says what it could not check.
+    assert n["degraded_short"] > 0 and line["correct"] is False
