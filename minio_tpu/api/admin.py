@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import asyncio
 import json
+import os
 import time
 import urllib.parse
 from dataclasses import dataclass
@@ -880,7 +881,11 @@ def make_admin_app(ctx: AdminContext) -> web.Application:
     # a zip with per-node entries in a cluster. The profiler samples
     # sys._current_frames() from its own thread (control/profiler.py):
     # cProfile's per-thread hook enabled inside a request handler would
-    # profile nothing but that handler's executor thread. -------------------
+    # profile nothing but that handler's executor thread. With `device=1`
+    # start also opens a jax.profiler trace on THIS node (one chip, one
+    # process) and stop adds the .xplane.pb and devtrace.json -- the idle
+    # gaps of the device with the host stages overlapping each
+    # (control/devtrace.py) -- to the zip. Keep such a window to seconds. ---
 
     _profiler: dict = {}
 
@@ -893,6 +898,17 @@ def make_admin_app(ctx: AdminContext) -> web.Application:
 
         if "p" in _profiler:
             raise S3Error("InvalidArgument", "profiling already running")
+        if request.query.get("device") == "1":
+            import tempfile
+
+            from .. import runtime
+
+            trace_dir = tempfile.mkdtemp(prefix="mtpu-devtrace-")
+            try:
+                runtime.device_trace_start(trace_dir)
+            except RuntimeError as e:
+                raise S3Error("InvalidArgument", str(e)) from None
+            _profiler["trace_dir"] = trace_dir
         p = SamplingProfiler()
         p.start()
         _profiler["p"] = p
@@ -914,13 +930,27 @@ def make_admin_app(ctx: AdminContext) -> web.Application:
         p.stop()
         text = p.report()
         peers = _peer_clients()
-        if not peers:
+        trace_dir = _profiler.pop("trace_dir", None)
+        if not peers and trace_dir is None:
             return web.Response(text=text, content_type="text/plain")
         import zipfile
 
         zbuf = io.BytesIO()
         with zipfile.ZipFile(zbuf, "w", zipfile.ZIP_DEFLATED) as z:
             z.writestr("local/profile.txt", text)
+            if trace_dir is not None:
+                import shutil
+
+                from .. import runtime
+
+                try:
+                    xplane, reduced = runtime.device_trace_stop(trace_dir)
+                    z.writestr("local/devtrace.json", json.dumps(reduced, indent=1))
+                    z.write(xplane, "local/" + os.path.basename(xplane))
+                except RuntimeError as e:
+                    z.writestr("local/devtrace.json", json.dumps({"error": str(e)}))
+                finally:
+                    shutil.rmtree(trace_dir, ignore_errors=True)
             for peer in peers:
                 try:
                     peer_text = peer.profile_stop().get("text", "")

@@ -39,7 +39,7 @@ from ..control.logging import GLOBAL_LOGGER
 from ..control.perf import GLOBAL_PERF, op_class
 from ..control import policy as policy_mod
 from ..control import tracing
-from ..control.profiler import COPIED, GLOBAL_PROFILER, MOVED
+from ..control.profiler import COPIED, GLOBAL_PROFILER, MOVED, loop_heartbeat
 from ..object.pools import ServerPools
 from ..object.types import (
     DeleteObjectOptions,
@@ -133,12 +133,23 @@ class _RequestBodyReader:
         self._loop = loop
         self._chunk: bytes = b""
         self._pos = 0
+        self._hop_s = 0.0  # seconds in the hops to the event loop, so far
 
     def _refill(self) -> bool:
+        t0 = _time.perf_counter()
         fut = asyncio.run_coroutine_threadsafe(self._content.readany(), self._loop)
         self._chunk = fut.result(timeout=600)
+        self._hop_s += _time.perf_counter() - t0
         self._pos = 0
         return bool(self._chunk)
+
+    def close(self) -> None:
+        """Record the body's hops as ONE api/body-hop observation (the
+        streaming PUT entry calls this when the request is done with the
+        body; a second call records nothing)."""
+        if self._hop_s:
+            GLOBAL_PERF.ledger.record("api", "body-hop", self._hop_s)
+            self._hop_s = 0.0
 
     def read(self, n: int) -> bytes:
         if n <= 0:
@@ -183,15 +194,33 @@ class _HashVerifyReader:
         self._limit = limit
         self._n = 0
         self._checked = False
+        self._hash_s = 0.0  # wall and cpu seconds in the digests, so far
+        self._hash_cpu_s = 0.0
 
     def _consumed(self, nbytes: int, view=None) -> None:
         self._n += nbytes
         if self._n > self._limit:
             raise S3Error("EntityTooLarge")
+        if self._sha is None and self._md5 is None:
+            return
+        c0 = _time.thread_time()
+        t0 = _time.perf_counter()
         if self._sha is not None:
             self._sha.update(view)
         if self._md5 is not None:
             self._md5.update(view)
+        self._hash_s += _time.perf_counter() - t0
+        self._hash_cpu_s += _time.thread_time() - c0
+
+    def close(self) -> None:
+        """Record the payload digests as ONE api/payload-hash observation,
+        cpu beside wall (the streaming PUT entry calls this; a second call
+        records nothing)."""
+        if self._hash_s:
+            GLOBAL_PERF.ledger.record(
+                "api", "payload-hash", self._hash_s, self._hash_cpu_s
+            )
+            self._hash_s = self._hash_cpu_s = 0.0
 
     def _at_eof(self) -> None:
         if self._checked:
@@ -354,6 +383,7 @@ class S3Server:
         self._inflight = 0
         self._inflight_lock = san_lock("S3Server._inflight_lock")
         self.app = web.Application(client_max_size=MAX_OBJECT_SIZE)
+        self.app.cleanup_ctx.append(loop_heartbeat)  # ledger row runtime/loop-lag
         self.app.router.add_route("*", "/{tail:.*}", self._entry)
         # Hooks filled in by the control plane (events, metrics, trace).
         self.on_event = None
@@ -475,6 +505,11 @@ class S3Server:
                 with self._inflight_lock:
                     self._inflight -= 1
         duration = _time.perf_counter() - t0
+        # A stream that died after its headers (a shard read failing under a
+        # lazy GET) answered 200 and then closed the connection: an error to
+        # the client, so an error to the metrics and the ops/s ring -- the
+        # flight recorder's error-spike trigger reads the ring.
+        ok = resp.status < 400 and not request.get("stream_aborted", False)
         if not resp.prepared:  # streamed responses already sent their headers
             resp.headers["x-amz-request-id"] = request_id
             for hk, hv in self._cors_headers(request).items():
@@ -485,7 +520,7 @@ class S3Server:
                 resp.headers.setdefault("Retry-After", "1")
         if self.metrics is not None:
             self.metrics.record_http(request.method, resp.status)
-            self.metrics.record_api(api_name, duration, resp.status < 400)
+            self.metrics.record_api(api_name, duration, ok)
         # Always-on ops/s ring (control/perf.py OpsTimeSeries): one bump per
         # request under its op class. Bytes from the headers -- rx is the
         # client's declared body, tx what we are about to send.
@@ -496,7 +531,7 @@ class S3Server:
         except (TypeError, ValueError):
             nbytes = 0
         GLOBAL_PERF.timeseries.record(
-            op_class(api_name), duration, ok=resp.status < 400, nbytes=nbytes
+            op_class(api_name), duration, ok=ok, nbytes=nbytes
         )
         if self.trace is not None and self.trace.enabled():
             self.trace.publish(
@@ -643,11 +678,17 @@ class S3Server:
         else:
             size = request.content_length or 0
         await asyncio.to_thread(self._check_quota, bucket, size)
-        if "uploadId" in q and "partNumber" in q:
-            return await asyncio.to_thread(
-                self._upload_part, bucket, key, q["uploadId"], int(q["partNumber"]), reader
-            )
-        return await asyncio.to_thread(self._put_object, bucket, key, reader, request)
+        try:
+            if "uploadId" in q and "partNumber" in q:
+                return await asyncio.to_thread(
+                    self._upload_part, bucket, key, q["uploadId"], int(q["partNumber"]), reader
+                )
+            return await asyncio.to_thread(self._put_object, bucket, key, reader, request)
+        finally:
+            # One api/body-hop and one api/payload-hash record per request,
+            # whether the body reached EOF or the PUT failed half way.
+            base.close()
+            reader.close()
 
     @staticmethod
     def _policy_context(request: web.Request | None) -> dict:
@@ -2446,20 +2487,34 @@ class S3Server:
         # the socket -- the time a GET spends after headers.
         wr = tracing.span("response-write", "api", bytes=plan.content_length)
         sent = 0
+        # The two halves of response-write, accumulated per chunk and
+        # recorded once per response: waiting for the read generator on a
+        # worker thread, and handing the chunk to the socket.
+        pull_s = write_s = 0.0
+
+        def finish(error: str | None = None) -> None:
+            wr.finish(error=error)
+            GLOBAL_PERF.ledger.record("api", "stream-pull", pull_s)
+            GLOBAL_PERF.ledger.record("api", "socket-write", write_s)
+
         try:
             while True:
+                t0 = _time.perf_counter()
                 chunk = await asyncio.to_thread(next, it, None)
+                t1 = _time.perf_counter()
+                pull_s += t1 - t0
                 if chunk is None:
                     break
                 sent += len(chunk)
                 await resp.write(chunk)
+                write_s += _time.perf_counter() - t1
         except Exception as e:
             # Headers (and a Content-Length promise) are already on the
             # wire: substituting an error response here would interleave
             # a second set of headers into the half-sent body and leave
             # the client waiting out the original length. Close the
             # connection instead so the client fails fast on truncation.
-            wr.finish(error=type(e).__name__)
+            finish(error=type(e).__name__)
             # Copy-ledger hop: chunks handed to aiohttp by reference --
             # zero-copy from this layer's point of view (partial count on
             # an aborted stream is honest: those bytes did cross the hop).
@@ -2467,12 +2522,15 @@ class S3Server:
             cur = tracing.current()
             if cur is not None:
                 cur.set(stream_aborted=type(e).__name__)
+            # The status line said 200 before the read failed: _entry counts
+            # the request as the error the client saw.
+            request["stream_aborted"] = True
             with contextlib.suppress(Exception):
                 it.close()
             if request.transport is not None:
                 request.transport.close()
         else:
-            wr.finish()
+            finish()
             GLOBAL_PROFILER.copy.record("response-write", MOVED, sent)
             with contextlib.suppress(Exception):
                 await resp.write_eof()

@@ -55,6 +55,7 @@ import threading
 import time
 from collections import deque
 
+from . import tracing
 from .degrade import GLOBAL_DEGRADE
 from .perf import (
     GLOBAL_PERF,
@@ -507,7 +508,8 @@ class FlightRecorder:
     def _run(self) -> None:
         while not self._stop.wait(self.poll_s):
             try:
-                self.poll_once()
+                with tracing.stage("flight-trigger", "background"):
+                    self.poll_once()
             except Exception:  # noqa: BLE001 - the watchdog must outlive one bad snapshot
                 with self._lock:
                     self.capture_errors += 1

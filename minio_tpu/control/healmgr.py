@@ -22,6 +22,7 @@ from dataclasses import asdict, dataclass, field
 
 from ..storage.format import SYS_DIR
 from ..utils import errors
+from . import tracing
 from .sanitizer import san_lock, san_rlock
 
 HEALING_FILE = "healing.bin"
@@ -100,7 +101,8 @@ class MRFQueue:
                 # thread past stop()'s bounded join. The scanner sweep
                 # re-finds anything dropped here.
                 break
-            self._heal_one(entry)
+            with tracing.stage("mrf-drain", "background"):
+                self._heal_one(entry)
 
     def join(self, timeout: float = 5.0) -> None:
         if self._thread is not None:
@@ -318,7 +320,8 @@ class DiskHealMonitor:
     def _loop(self) -> None:
         while not self._stop.is_set():
             try:
-                self.tick()
+                with tracing.stage("heal-monitor", "background"):
+                    self.tick()
             except Exception:  # noqa: BLE001 - monitor must survive anything
                 pass
             self._stop.wait(self.interval)

@@ -63,9 +63,6 @@ class MetricsSys:
         self.api_hist_sum: dict[str, float] = defaultdict(float)
         self.bytes_received = 0
         self.bytes_sent = 0
-        self.encode_batches = 0
-        self.encode_blocks = 0
-        self.encode_device_ns = 0
         self.start_time = time.time()
         self.layer = None  # set by the server for storage gauges
         self.replication = None  # ReplicationSys for replication gauges
@@ -103,12 +100,6 @@ class MetricsSys:
             self.api_hist_sum[api] += seconds
         self.api_latency[api].add(seconds)
 
-    def record_encode(self, blocks: int, device_ns: int) -> None:
-        with self._lock:
-            self.encode_batches += 1
-            self.encode_blocks += blocks
-            self.encode_device_ns += device_ns
-
     # -- exposition ----------------------------------------------------------
 
     def render(self) -> str:
@@ -142,7 +133,6 @@ class MetricsSys:
             calls = dict(self.api_calls)
             errs = dict(self.api_errors)
             rx, tx = self.bytes_received, self.bytes_sent
-            enc = (self.encode_batches, self.encode_blocks, self.encode_device_ns)
 
         metric("minio_tpu_uptime_seconds", round(time.time() - self.start_time, 1),
                help_="Server uptime.", type_="gauge")
@@ -192,12 +182,6 @@ class MetricsSys:
                 f'minio_tpu_s3_request_duration_seconds_sum{{api="{api}"}} {round(total_s, 6)}'
             )
             lines.append(f'minio_tpu_s3_request_duration_seconds_count{{api="{api}"}} {cum}')
-        metric("minio_tpu_encode_batches_total", enc[0],
-               help_="Device encode batches run.")
-        metric("minio_tpu_encode_blocks_total", enc[1],
-               help_="Blocks encoded via record_encode.")
-        metric("minio_tpu_encode_device_seconds_total", round(enc[2] / 1e9, 6),
-               help_="Device encode wall time via record_encode.")
 
         self._render_drives(metric)
         self._render_codec(metric)
@@ -424,10 +408,28 @@ class MetricsSys:
             ("verify", "device_verify_seconds"),
         ):
             metric(
-                "minio_tpu_codec_device_seconds_total", round(st[key], 6),
+                "minio_tpu_codec_roundtrip_seconds_total", round(st[key], 6),
                 {"kernel": kernel},
-                help_="Wall time inside device kernels.",
+                help_="Host-clock seconds from a batch's launch to its bytes' "
+                      "arrival on the host, per kernel class; not device time "
+                      "(an admin profile with device=1 reads that from a trace).",
             )
+        # The life of a full-block batch (the same measurements feed the
+        # codec/* ledger rows): queueing, the workers' idle share, and the
+        # bytes that crossed to the device and back per user byte.
+        metric("minio_tpu_codec_queue_wait_block_seconds_total",
+               round(st["queue_wait_block_seconds"], 6),
+               help_="Seconds full blocks sat queued before their batch "
+                     "was dispatched, summed over blocks.")
+        for state, key in (("idle", "worker_idle_seconds"), ("all", "worker_wall_seconds")):
+            metric("minio_tpu_codec_worker_seconds_total", round(st[key], 6),
+                   {"state": state},
+                   help_="Batch worker loop seconds: idle on an empty queue, and all.")
+        for direction, key in (("h2d", "h2d_bytes"), ("d2h", "d2h_bytes")):
+            metric("minio_tpu_codec_transfer_bytes_total", st[key], {"dir": direction},
+                   help_="Bytes of full-block batches sent to the device and brought back.")
+        metric("minio_tpu_codec_encoded_user_bytes_total", st["encoded_user_bytes"],
+               help_="User bytes of the full blocks the device encoded.")
         if "compiled_verify_lens" in st:
             metric(
                 "minio_tpu_codec_compiled_verify_lengths", st["compiled_verify_lens"],
@@ -552,6 +554,15 @@ class MetricsSys:
                 sampler.windows_rotated,
                 help_="Profile windows closed into the ring since start.",
             )
+        from .profiler import GC_WATCH
+
+        if GC_WATCH.installed or any(GC_WATCH.collections):
+            for gen, n in enumerate(GC_WATCH.collections):
+                metric(
+                    "minio_tpu_gc_collections_total", n, {"generation": gen},
+                    help_="Garbage collections by generation; each one's "
+                          "pause is a record of the runtime/gc-pause stage.",
+                )
         hops = GLOBAL_PROFILER.copy.snapshot()["hops"]
         for hop, row in sorted(hops.items()):
             for kind, key in (("copied", "copied_bytes"), ("moved", "moved_bytes")):

@@ -71,10 +71,20 @@ STAGES: frozenset = frozenset({
     ("api", "auth"),
     ("api", "body-read"),
     ("api", "response-write"),
+    # Where a streamed request waits (direct records, one per request or
+    # per window): the body reader's hops to the event loop, the payload
+    # digests, one window's fill; a GET's pull from the read generator and
+    # its socket writes (their sum is response-write).
+    ("api", "body-hop"),
+    ("api", "payload-hash"),
+    ("api", "body-fill"),
+    ("api", "stream-pull"),
+    ("api", "socket-write"),
     # object/erasure.py + object/multipart.py data-path stages
     ("object", "encode"),
     ("object", "shard-fanout"),
     ("object", "commit"),
+    ("object", "window-wait"),
     ("object", "shard-read"),
     ("object", "frame-parse"),
     ("object", "decode"),
@@ -98,6 +108,30 @@ STAGES: frozenset = frozenset({
     ("codec", "encode-batch-small"),
     ("codec", "reconstruct-batch"),
     ("codec", "verify-batch"),
+    # The life of a full-block encode batch on the worker thread, one
+    # record per batch (worker-idle: per wake-up). worker-idle + collect +
+    # pack + h2d + device-wait + d2h + scatter is the worker's wall time;
+    # queue-wait is the oldest request's wait, on the requests' clock.
+    ("codec", "queue-wait"),
+    ("codec", "worker-idle"),
+    ("codec", "collect"),
+    ("codec", "pack"),
+    ("codec", "h2d"),
+    ("codec", "device-wait"),
+    ("codec", "d2h"),
+    ("codec", "scatter"),
+    # The process itself (control/procwatch.py): one record per garbage
+    # collection, per late event-loop heartbeat, per GIL-probe tick.
+    ("runtime", "gc-pause"),
+    ("runtime", "loop-lag"),
+    ("runtime", "gil-wake-late"),
+    # Every thread that wakes inside the serving process on its own clock:
+    # one record per wake-up, wall and cpu.
+    ("background", "scanner-cycle"),
+    ("background", "mrf-drain"),
+    ("background", "heal-monitor"),
+    ("background", "flight-trigger"),
+    ("background", "profiler-sample"),
     # storage/local.py durability barriers (every fdatasync/fsync the
     # MTPU_FSYNC discipline issues; the layer is otherwise dynamic, the
     # entry documents the one literal key bench JSON reports).

@@ -18,6 +18,13 @@ This module carries both profiling surfaces:
     accounting on the PUT/GET data path. Served by
     ``GET /mtpu/admin/v1/profile`` and embedded in loadgen/bench reports.
 
+  * The process watch -- what the interpreter itself did to the serving
+    threads, as rows of the stage ledger's ``runtime`` layer: every tick
+    of the GIL probe records how late it woke (``gil-wake-late``), a
+    ``gc.callbacks`` hook records every collection's pause (``gc-pause``),
+    and a 100 ms heartbeat on the serving event loop records how late it
+    fired (``loop-lag``; ``loop_heartbeat`` is an aiohttp cleanup context).
+
 The three axes answer the questions the stage ledger (control/perf.py)
 cannot: WHERE threads spend their samples (stacks by role), whether wall
 time is GIL wait or real work (gil_load + the ledger's cpu_seconds
@@ -27,12 +34,17 @@ data path (the scorecard for the zero-copy pipeline work).
 
 from __future__ import annotations
 
+import asyncio
+import contextlib
+import gc
 import os
 import sys
 import threading
 import time
 from collections import Counter, deque
 
+from . import tracing
+from .perf import GLOBAL_PERF
 from .sanitizer import san_lock
 
 # -- thread roles -------------------------------------------------------------
@@ -289,19 +301,21 @@ class ContinuousProfiler:
         with self._lock:
             self.windows_rotated += self._rotate_locked(time.monotonic())
         while not self._stop.is_set():
-            t0 = time.perf_counter()
-            now = time.monotonic()
-            roles = {
-                t.ident: thread_role(t.name)
-                for t in threading.enumerate()
-                if t.ident is not None
-            }
-            sampled: list[tuple[str, str]] = []
-            for tid, frame in sys._current_frames().items():
-                if tid == me:
-                    continue
-                sampled.append((roles.get(tid, "other"), _collapse(frame)))
-            cost = time.perf_counter() - t0
+            # The sampler's own wake-up is a background stage like any
+            # other waker's: its wall is the window's overhead_s.
+            with tracing.stage("profiler-sample", "background") as st:
+                now = time.monotonic()
+                roles = {
+                    t.ident: thread_role(t.name)
+                    for t in threading.enumerate()
+                    if t.ident is not None
+                }
+                sampled: list[tuple[str, str]] = []
+                for tid, frame in sys._current_frames().items():
+                    if tid == me:
+                        continue
+                    sampled.append((roles.get(tid, "other"), _collapse(frame)))
+            cost = st.wall
             with self._lock:
                 win = self._cur
                 if win is None or now - win.start_mono >= self.window_s:
@@ -410,13 +424,21 @@ class GilLoadProbe:
             delay = max(0.0, time.perf_counter() - t0 - self.interval_s)
             with self._lock:
                 self.ticks += 1
-                if self._floor is None:
+                floor = self._floor
+                if floor is None:
                     self._calib.append(delay)
                     if len(self._calib) >= self._CALIB_TICKS:
                         self._floor = min(self._calib)
                         self._calib.clear()
                 else:
                     self._delays.append(delay)
+            if floor is not None:
+                # Every tick's excess, not only the ring's mean: the ledger
+                # row sums to the seconds this thread waited for the GIL.
+                GLOBAL_PERF.ledger.record(
+                    "runtime", "gil-wake-late", max(0.0, delay - floor)
+                )
+            GC_WATCH.flush()
 
     def value(self) -> float:
         """Current GIL-load estimate in [0, 1]; 0.0 until calibrated."""
@@ -428,6 +450,79 @@ class GilLoadProbe:
         excess = sum(max(0.0, d - floor) for d in delays) / len(delays)
         switch = max(sys.getswitchinterval(), 1e-4)
         return min(1.0, excess / switch)
+
+
+# -- process watch: GC pauses and event-loop lag -------------------------------
+
+
+class GcWatch:
+    """Every garbage collection's pause, as the ledger row runtime/gc-pause.
+
+    The ``gc.callbacks`` hook runs on whichever thread tripped the
+    collection, between two of its bytecodes -- possibly inside the stage
+    ledger's own lock -- so it takes no lock: it appends the pause to a
+    deque, and ``flush`` (the GIL probe's tick) moves the pauses into the
+    ledger, one record per collection. ``collections`` counts them by
+    generation."""
+
+    def __init__(self):
+        self._t0 = 0.0
+        self._pending: deque[float] = deque(maxlen=4096)
+        self.collections = [0, 0, 0]
+        self.installed = False
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self._t0 = time.perf_counter()
+        elif self._t0:
+            self._pending.append(time.perf_counter() - self._t0)
+            self._t0 = 0.0
+            self.collections[min(int(info.get("generation", 0)), 2)] += 1
+
+    def install(self) -> None:
+        if not self.installed:
+            gc.callbacks.append(self._on_gc)
+            self.installed = True
+
+    def remove(self) -> None:
+        if self.installed:
+            with contextlib.suppress(ValueError):
+                gc.callbacks.remove(self._on_gc)
+            self.installed = False
+            self.flush()
+
+    def flush(self) -> None:
+        while self._pending:
+            try:
+                pause = self._pending.popleft()
+            except IndexError:
+                return
+            GLOBAL_PERF.ledger.record("runtime", "gc-pause", pause)
+
+
+GC_WATCH = GcWatch()
+
+LOOP_BEAT_S = 0.1
+
+
+async def loop_heartbeat(_app=None):
+    """aiohttp cleanup context (``app.cleanup_ctx.append(loop_heartbeat)``):
+    while the app serves, a task sleeps LOOP_BEAT_S at a time on its event
+    loop and records how late each wake-up was as runtime/loop-lag -- the
+    time a ready callback waited behind whatever held the loop."""
+
+    async def beat() -> None:
+        while True:
+            t0 = time.perf_counter()
+            await asyncio.sleep(LOOP_BEAT_S)
+            late = time.perf_counter() - t0 - LOOP_BEAT_S
+            GLOBAL_PERF.ledger.record("runtime", "loop-lag", max(0.0, late))
+
+    task = asyncio.get_running_loop().create_task(beat())
+    yield
+    task.cancel()
+    with contextlib.suppress(asyncio.CancelledError):
+        await task
 
 
 # -- copy ledger ---------------------------------------------------------------
@@ -545,6 +640,7 @@ class ProfilerSys:
                 self.gil = GilLoadProbe()
             self.sampler.start()
             self.gil.start()
+            GC_WATCH.install()
         return True
 
     def stop(self) -> None:
@@ -555,6 +651,7 @@ class ProfilerSys:
                 self.sampler.stop()
             if self.gil is not None:
                 self.gil.stop()
+            GC_WATCH.remove()
 
     def gil_load(self) -> float:
         g = self.gil

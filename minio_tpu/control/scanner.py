@@ -16,6 +16,7 @@ import time
 
 from ..storage.xlmeta import XLMeta
 from ..utils import errors
+from . import tracing
 from .lifecycle import Lifecycle
 from .sanitizer import san_lock
 from .usage import DataUsageCache
@@ -89,7 +90,8 @@ class DataScanner:
                     writer=True, timeout=1.0
                 ):
                     try:
-                        self.scan_cycle()
+                        with tracing.stage("scanner-cycle", "background"):
+                            self.scan_cycle()
                     finally:
                         if self.leader_lock is not None:
                             self.leader_lock.release()

@@ -24,6 +24,17 @@ span also feeds the stage ledger (control/perf.py) -- a bucket increment
 that stays armed with zero subscribers, so the server can attribute where
 request time went without a live trace watcher. Hub publishing remains
 subscriber-gated.
+
+Two additions put worker threads and the device on the same timeline:
+  * `stage(name, layer)` times a block OUTSIDE any request (batch workers,
+    drive fan-out threads, background wakers): wall + thread_time into the
+    ledger, no ids, no hub -- what a span would be if it had a parent.
+  * `set_annotator(factory)` late-binds a host-trace annotation (runtime.py
+    installs jax.profiler.TraceAnnotation beside the device codec; this
+    package imports no jax). While set, every context-managed span and every
+    stage also opens `factory("<layer>/<name>")`, so a profiler trace holds
+    the host stages in the same nanoseconds as the device ops. Unset -- the
+    host codec, the tier-1 tests -- the cost is one `is None` test.
 """
 
 from __future__ import annotations
@@ -93,6 +104,19 @@ def _new_id() -> str:
     return secrets.token_hex(8).upper()
 
 
+# -- host-trace annotator (late-bound, the pattern of PerfSys.flight) ---------
+
+_annotator = None
+
+
+def set_annotator(factory) -> None:
+    """Install (or, with None, remove) the annotation factory: a callable
+    `factory(label, **kw)` returning a context manager. Spans pass
+    `trace=<trace id>`; stages pass nothing."""
+    global _annotator
+    _annotator = factory
+
+
 class Span:
     """One timed unit of work. Publishes itself to the hub on close.
 
@@ -114,6 +138,7 @@ class Span:
         "sampled",
         "_token",
         "_closed",
+        "_ann",
     )
 
     def __init__(
@@ -142,6 +167,7 @@ class Span:
         self.sampled = sampled
         self._token = None
         self._closed = False
+        self._ann = None
 
     def set(self, **tags) -> None:
         self.tags.update(tags)
@@ -152,6 +178,13 @@ class Span:
 
     def __enter__(self) -> "Span":
         self._token = _current.set(self)
+        # Only context-managed spans are annotated: they open and close on
+        # one thread, nested. A span finished by hand (response-write) may
+        # close on another thread, which a host-trace annotation cannot.
+        ann = _annotator
+        if ann is not None:
+            self._ann = ann(f"{self.layer}/{self.name}", trace=self.trace_id)
+            self._ann.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
@@ -159,6 +192,9 @@ class Span:
             _current.reset(self._token)
             self._token = None
         self.finish(error=exc_type.__name__ if exc_type is not None else None)
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+            self._ann = None
         return False
 
     def finish(self, error: str | None = None) -> None:
@@ -221,6 +257,43 @@ class _NoopSpan:
 
 
 NOOP = _NoopSpan()
+
+
+class stage:
+    """One timed stage outside a request, as a context manager.
+
+    Worker threads (the batcher, drive fan-out, background wakers) run in no
+    request's context, so a span there is a no-op; this is the always-on
+    counterpart: wall and thread_time into the stage ledger, no ids, no hub.
+    After the block, `.wall` and `.cpu` hold what was recorded, for counters
+    fed by the same measurement."""
+
+    __slots__ = ("name", "layer", "wall", "cpu", "_t0", "_c0", "_ann")
+
+    def __init__(self, name: str, layer: str):
+        self.name = name
+        self.layer = layer
+        self.wall = 0.0
+        self.cpu = 0.0
+        self._ann = None
+
+    def __enter__(self) -> "stage":
+        ann = _annotator
+        if ann is not None:
+            self._ann = ann(f"{self.layer}/{self.name}")
+            self._ann.__enter__()
+        self._c0 = time.thread_time()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        self.wall = time.perf_counter() - self._t0
+        self.cpu = time.thread_time() - self._c0
+        GLOBAL_PERF.ledger.record(self.layer, self.name, self.wall, self.cpu)
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+            self._ann = None
+        return False
 
 
 def current() -> Span | None:

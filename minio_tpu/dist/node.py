@@ -752,6 +752,10 @@ class Node:
         dist routers before waitForFormatErasure too, server-main.go:495-521).
         """
         app = web.Application(client_max_size=1 << 31)
+        # The serving loop's heartbeat: ledger row runtime/loop-lag.
+        from ..control.profiler import loop_heartbeat
+
+        app.cleanup_ctx.append(loop_heartbeat)
         app.add_subapp(STORAGE_PREFIX, make_storage_app(self.local_drives, self.token))
         app.add_subapp(LOCK_PREFIX, make_lock_app(self.locker, self.token))
         app.add_subapp(PEER_PREFIX, make_peer_app(self, self.token))

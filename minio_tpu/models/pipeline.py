@@ -298,10 +298,19 @@ class ErasurePipeline:
         # Parity-only step for the small-object coalescing path: those
         # batches are padded on the shard-byte axis, so their digests are
         # host-computed at true lengths and the device only owes parity.
-        self._parity_fn = jax.jit(dev_codec.encode)
+        tag = f"k{geom.data}m{geom.parity}"  # the programs' names carry the geometry
+
+        def parity_step(data_shards: jax.Array):
+            with jax.named_scope("mtpu.rs_parity_small"):
+                return dev_codec.encode(data_shards)
+
+        parity_step.__name__ = parity_step.__qualname__ = f"mtpu_parity_{tag}"
+        self._parity_fn = jax.jit(parity_step)
 
         if mesh is None:
-            return jax.jit(fused_ops.make_step(dev_codec.encode_all, hash_fn))
+            return jax.jit(
+                fused_ops.make_step(dev_codec.encode_all, hash_fn, f"mtpu_encode_hash_{tag}")
+            )
 
         # Mesh path: explicit SPMD. The erasure matmul is pointwise in the
         # byte axis so it runs sp-sharded with no communication; the
@@ -329,10 +338,11 @@ class ErasurePipeline:
             # choice rides into the shard_map body: the XOR-bitmatrix Pallas
             # kernel is pointwise in the byte axis exactly like the matmul,
             # so it runs sp-sharded with no extra communication.
-            if self.rs_impl == "pallas":
-                parity = dev_codec.encode(data_local)
-            else:
-                parity = rs.gf_matmul(data_local, jnp.asarray(w_parity))
+            with jax.named_scope("mtpu.rs_encode"):
+                if self.rs_impl == "pallas":
+                    parity = dev_codec.encode(data_local)
+                else:
+                    parity = rs.gf_matmul(data_local, jnp.asarray(w_parity))
             all_local = jnp.concatenate([data_local, parity], axis=1)
             # Barrier: without it XLA keeps the parameter-aliasing data rows
             # and the freshly computed parity rows in different layouts, and
@@ -344,9 +354,10 @@ class ErasurePipeline:
             t_loc = x.shape[1] // tp
             ti = jax.lax.axis_index("tp")
             x = jax.lax.dynamic_slice_in_dim(x, ti * t_loc, t_loc, axis=1)
-            digests = hash_fn(x.reshape(-1, x.shape[-1])).reshape(
-                x.shape[0], t_loc, 32
-            )
+            with jax.named_scope("mtpu.hh256"):
+                digests = hash_fn(x.reshape(-1, x.shape[-1])).reshape(
+                    x.shape[0], t_loc, 32
+                )
             return all_local, digests
 
         # check_vma off: the encode->hash all-to-all mixes parameter-aliasing
@@ -358,7 +369,12 @@ class ErasurePipeline:
             out_specs=(mesh_lib.shard_output_spec(), mesh_lib.digest_spec()),
             check_vma=False,
         )
-        return jax.jit(mapped)
+
+        def mesh_step(data_shards: jax.Array):
+            return mapped(data_shards)
+
+        mesh_step.__name__ = mesh_step.__qualname__ = f"mtpu_encode_hash_{tag}"
+        return jax.jit(mesh_step)
 
     def encode(self, data_shards) -> tuple[jax.Array, jax.Array]:
         return self._encode_fn(data_shards)
@@ -417,21 +433,27 @@ class ErasurePipeline:
         return hash_batch_fn()(shards.reshape(b * t, s)).reshape(b, t, 32)
 
 
-@functools.partial(jax.jit, static_argnums=(2,))
-def _reconstruct_step(survivors: jax.Array, w_bits: jax.Array, hash_fn):
-    rebuilt = rs.gf_matmul(survivors, w_bits)
+def _rebuilt_digests(rebuilt: jax.Array, hash_fn):
     if hash_fn is None:
         return rebuilt, None
     b, r, s = rebuilt.shape
-    digests = hash_fn(rebuilt.reshape(b * r, s)).reshape(b, r, 32)
+    with jax.named_scope("mtpu.hh256"):
+        digests = hash_fn(rebuilt.reshape(b * r, s)).reshape(b, r, 32)
     return rebuilt, digests
 
 
-@functools.partial(jax.jit, static_argnums=(1, 2))
-def _reconstruct_sched_step(survivors: jax.Array, sched, hash_fn):
-    rebuilt = rs_pallas._apply_sched(jnp.asarray(survivors), sched)
-    if hash_fn is None:
-        return rebuilt, None
-    b, r, s = rebuilt.shape
-    digests = hash_fn(rebuilt.reshape(b * r, s)).reshape(b, r, 32)
-    return rebuilt, digests
+def mtpu_reconstruct(survivors: jax.Array, w_bits: jax.Array, hash_fn):
+    with jax.named_scope("mtpu.rs_reconstruct"):
+        rebuilt = rs.gf_matmul(survivors, w_bits)
+    return _rebuilt_digests(rebuilt, hash_fn)
+
+
+def mtpu_reconstruct_sched(survivors: jax.Array, sched, hash_fn):
+    with jax.named_scope("mtpu.rs_reconstruct"):
+        rebuilt = rs_pallas._apply_sched(jnp.asarray(survivors), sched)
+    return _rebuilt_digests(rebuilt, hash_fn)
+
+
+# The functions' own names are what a trace's module line reads.
+_reconstruct_step = jax.jit(mtpu_reconstruct, static_argnums=(2,))
+_reconstruct_sched_step = jax.jit(mtpu_reconstruct_sched, static_argnums=(1, 2))

@@ -187,24 +187,27 @@ def _fill_window(reader, view: memoryview) -> int:
     n = len(view)
     pos = 0
     ri = getattr(reader, "readinto", None)
-    if ri is not None:
+    # One stage per window, on whichever thread fills it (the put-stager,
+    # outside the request's context, for every window but the first).
+    with tracing.stage("body-fill", "api"):
+        if ri is not None:
+            while pos < n:
+                got = ri(view[pos:])
+                if not got:
+                    break
+                pos += got
+            if pos:
+                GLOBAL_PROFILER.copy.record("erasure-stage", MOVED, pos)
+            return pos
         while pos < n:
-            got = ri(view[pos:])
-            if not got:
+            chunk = reader.read(n - pos)
+            if not chunk:
                 break
-            pos += got
+            view[pos : pos + len(chunk)] = chunk
+            pos += len(chunk)
         if pos:
-            GLOBAL_PROFILER.copy.record("erasure-stage", MOVED, pos)
+            GLOBAL_PROFILER.copy.record("erasure-stage", COPIED, pos)
         return pos
-    while pos < n:
-        chunk = reader.read(n - pos)
-        if not chunk:
-            break
-        view[pos : pos + len(chunk)] = chunk
-        pos += len(chunk)
-    if pos:
-        GLOBAL_PROFILER.copy.record("erasure-stage", COPIED, pos)
-    return pos
 
 
 def _buffer_windows(data) -> Iterator[_Window]:
@@ -287,7 +290,10 @@ class _ReadaheadWindows:
         return self
 
     def __next__(self) -> _Window:
-        kind, val = self._q.get()
+        # The request thread's wait for the stager: inside the request's
+        # tree, so a PUT's time with no window to encode has a name.
+        with tracing.span("window-wait", "object"):
+            kind, val = self._q.get()
         if kind == "win":
             return val
         if kind == "err":

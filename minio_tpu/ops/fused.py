@@ -28,20 +28,26 @@ from . import highwayhash_jax as hhj
 from . import rs, rs_pallas
 
 
-def make_step(encode_all_fn, hash_fn):
+def make_step(encode_all_fn, hash_fn, name: str = "mtpu_encode_hash"):
     """Compose an encode-all fn and a digest fn into one fused step.
 
     Returns the *unjitted* step so callers (models/pipeline) control the jit
-    boundary; jit it once per (geometry, batch shape).
+    boundary; jit it once per (geometry, batch shape). `name` becomes the
+    jitted module's name (`jit_<name>` on a profiler trace's module line);
+    the two halves carry `mtpu.rs_encode` / `mtpu.hh256` scopes in their
+    ops' metadata. Names only: the program computes what it computed.
     """
 
     def step(data_shards: jax.Array):
         """[B, K, S] -> ([B, K+M, S] shards, [B, K+M, 32] digests)."""
-        all_shards = encode_all_fn(data_shards)
+        with jax.named_scope("mtpu.rs_encode"):
+            all_shards = encode_all_fn(data_shards)
         b, t, s = all_shards.shape
-        digests = hash_fn(all_shards.reshape(b * t, s)).reshape(b, t, 32)
+        with jax.named_scope("mtpu.hh256"):
+            digests = hash_fn(all_shards.reshape(b * t, s)).reshape(b, t, 32)
         return all_shards, digests
 
+    step.__name__ = step.__qualname__ = name
     return step
 
 
@@ -57,7 +63,7 @@ def _fused_cached(k: int, m: int, rs_impl: str, hash_impl: str):
         hash_fn = hhp.hash256_batch
     else:
         hash_fn = hhj.hash256_batch
-    return jax.jit(make_step(codec.encode_all, hash_fn))
+    return jax.jit(make_step(codec.encode_all, hash_fn, f"mtpu_encode_hash_k{k}m{m}"))
 
 
 def fused_encode_hash(data_shards, k: int, m: int,

@@ -36,12 +36,14 @@ import queue
 import threading
 import time as _time
 from concurrent.futures import Future
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 
+import jax
 import numpy as np
 
 from ..control import tracing
 from ..control.perf import GLOBAL_PERF
+from ..control.profiler import COPIED, GLOBAL_PROFILER
 from ..models.pipeline import ErasurePipeline, Geometry
 from ..object.codec import BlockCodec, HostCodec
 from ..ops import rs_matrix
@@ -94,6 +96,9 @@ def _small_wait_s() -> float | None:
 class _Request:
     shards: np.ndarray  # [K, S] split data block
     future: Future
+    # When the request was queued (perf_counter): codec/queue-wait is the
+    # dispatch start less this, for the oldest request of a batch.
+    enqueued: float = field(default_factory=_time.perf_counter)
 
 
 @dataclass
@@ -158,10 +163,24 @@ class BatchingDeviceCodec(BlockCodec):
         # blocks the dp-group g carried; with no mesh both stay trivial.
         self.mesh_devices = 1
         self.chip_blocks: list[int] = []
-        # Wall time inside device kernels, per kernel class (seconds).
+        # Round-trip seconds per kernel class by the HOST clock: launch to
+        # the bytes' arrival on the host, queueing behind the batch before
+        # included. Not device time (control/devtrace.py reads that from a
+        # profiler trace).
         self.device_encode_seconds = 0.0
         self.device_recon_seconds = 0.0
         self.device_verify_seconds = 0.0
+        # The life of a full-block batch, from the measurements that feed
+        # the codec/* ledger rows: how long blocks sat queued (summed over
+        # blocks), how long the workers sat on an empty queue out of their
+        # whole loop time (idle + the stages ~= wall), and the bytes that
+        # crossed to the device and back for the user bytes encoded.
+        self.queue_wait_block_seconds = 0.0
+        self.worker_idle_seconds = 0.0
+        self.worker_wall_seconds = 0.0
+        self.h2d_bytes = 0
+        self.d2h_bytes = 0
+        self.encoded_user_bytes = 0
         # Chunk lengths the device verify path has compiled for. Tail chunks
         # are effectively unique per object size; without a cap every
         # distinct length would pay a fresh XLA compile.
@@ -342,27 +361,37 @@ class BatchingDeviceCodec(BlockCodec):
         # dispatched (JAX queues transfer+compute asynchronously) before
         # batch i's np.asarray blocks, so D2H of i overlaps compute of i+1.
         pending = None
+        mark = _time.perf_counter()
         while not self._stop.is_set():
-            try:
-                first = q.get(timeout=0.1)
-            except queue.Empty:
+            with tracing.stage("worker-idle", "codec") as idle:
+                try:
+                    first = q.get(timeout=0.1)
+                except queue.Empty:
+                    first = None
+            if first is None:
                 if pending is not None:
                     self._resolve_batch(pending)
                     pending = None
-                continue
-            batch = self._collect(q, first, self.batch_timeout_s)
-            dispatched = self._dispatch_batch(pipe, k, m, batch)
-            if pending is not None:
-                self._resolve_batch(pending)
-                if dispatched is not None:
-                    with self._stats_lock:
-                        self.double_buffered_batches += 1
-            pending = dispatched
-            if pending is not None and q.empty():
-                # No follow-on work queued: resolve now, don't buy overlap
-                # with latency the SLO pays for.
-                self._resolve_batch(pending)
-                pending = None
+            else:
+                with tracing.stage("collect", "codec"):
+                    batch = self._collect(q, first, self.batch_timeout_s)
+                dispatched = self._dispatch_batch(pipe, k, m, batch)
+                if pending is not None:
+                    self._resolve_batch(pending)
+                    if dispatched is not None:
+                        with self._stats_lock:
+                            self.double_buffered_batches += 1
+                pending = dispatched
+                if pending is not None and q.empty():
+                    # No follow-on work queued: resolve now, don't buy overlap
+                    # with latency the SLO pays for.
+                    self._resolve_batch(pending)
+                    pending = None
+            now = _time.perf_counter()
+            with self._stats_lock:
+                self.worker_idle_seconds += idle.wall
+                self.worker_wall_seconds += now - mark
+            mark = now
         if pending is not None:
             self._resolve_batch(pending)
 
@@ -370,19 +399,35 @@ class BatchingDeviceCodec(BlockCodec):
         """Marshal + launch one encode batch; returns the pending record to
         resolve later, or None if dispatch itself failed."""
         try:
-            s = batch[0].shards.shape[1]
-            b_real = len(batch)
-            b_pad = _bucket(b_real)
-            if pipe.mesh is not None:
-                dp = pipe.mesh.shape["dp"]
-                b_pad = -(-b_pad // dp) * dp  # dp must divide the batch axis
-            arr = np.zeros((b_pad, k, s), dtype=np.uint8)
-            for i, req in enumerate(batch):
-                arr[i] = req.shards
-            t0 = _time.perf_counter()
-            c0 = _time.thread_time()
-            shards, digests = pipe.encode(arr)
-            return (batch, shards, digests, k, m, b_real, b_pad, t0, c0, pipe)
+            # Each stage takes its own bookkeeping inside, so that the stages
+            # leave nothing of the worker's time between them.
+            with tracing.stage("pack", "codec"):
+                t_dispatch = _time.perf_counter()
+                waits = [t_dispatch - req.enqueued for req in batch]
+                GLOBAL_PERF.ledger.record("codec", "queue-wait", max(waits))
+                with self._stats_lock:
+                    self.queue_wait_block_seconds += sum(waits)
+                s = batch[0].shards.shape[1]
+                b_real = len(batch)
+                b_pad = _bucket(b_real)
+                if pipe.mesh is not None:
+                    dp = pipe.mesh.shape["dp"]
+                    b_pad = -(-b_pad // dp) * dp  # dp must divide the batch axis
+                arr = np.zeros((b_pad, k, s), dtype=np.uint8)
+                for i, req in enumerate(batch):
+                    arr[i] = req.shards
+            # encode-batch runs from the launch to the bytes' arrival on the
+            # host (_resolve_batch closes it): the round trip, by the host's
+            # clock. Under double-buffering the next batch's dispatch falls
+            # inside it.
+            enc = tracing.stage("encode-batch", "codec")
+            enc.__enter__()
+            with tracing.stage("h2d", "codec"):
+                shards, digests = pipe.encode(arr)
+                GLOBAL_PROFILER.copy.record("device-h2d", COPIED, arr.nbytes)
+                with self._stats_lock:
+                    self.h2d_bytes += arr.nbytes
+            return (batch, shards, digests, k, m, b_real, b_pad, enc, pipe)
         except Exception as e:  # noqa: BLE001
             for req in batch:
                 if not req.future.done():
@@ -390,37 +435,39 @@ class BatchingDeviceCodec(BlockCodec):
             return None
 
     def _resolve_batch(self, rec) -> None:
-        batch, shards, digests, k, m, b_real, b_pad, t0, c0, pipe = rec
+        batch, shards, digests, k, m, b_real, b_pad, enc, pipe = rec
         try:
-            # Blocks until the device batch materializes host-side. Under
-            # double-buffering the next batch is already in flight.
-            shards_np = np.asarray(shards)
-            digests_np = np.asarray(digests)
-            dt = _time.perf_counter() - t0
-            # Ledger record, not a span: worker threads run outside any
-            # request context, so a span here would be a silent no-op. The
-            # cpu delta separates device wait (wall >> cpu) from host-side
-            # marshalling burning the core.
-            GLOBAL_PERF.ledger.record(
-                "codec", "encode-batch", dt, _time.thread_time() - c0
-            )
-            with self._stats_lock:
-                self.device_encode_seconds += dt
-                self.batches_run += 1
-                self.blocks_encoded += b_real
-                self.blocks_padded += b_pad
-                if pipe.mesh is not None:
-                    dp = pipe.mesh.shape["dp"]
-                    per = b_pad // dp
-                    for g in range(min(dp, len(self.chip_blocks))):
-                        self.chip_blocks[g] += min(max(b_real - g * per, 0), per)
-            for i, req in enumerate(batch):
-                req.future.set_result(
-                    (
-                        [shards_np[i, j].tobytes() for j in range(k + m)],
-                        [digests_np[i, j].tobytes() for j in range(k + m)],
+            # What the worker pays waiting for the device (not device time:
+            # under double-buffering the next batch is already in flight),
+            # then the bytes' way back to the host.
+            with tracing.stage("device-wait", "codec"):
+                jax.block_until_ready((shards, digests))
+            with tracing.stage("d2h", "codec"):
+                shards_np = np.asarray(shards)
+                digests_np = np.asarray(digests)
+            with tracing.stage("scatter", "codec"):
+                enc.__exit__(None, None, None)
+                d2h = shards_np.nbytes + digests_np.nbytes
+                GLOBAL_PROFILER.copy.record("device-d2h", COPIED, d2h)
+                with self._stats_lock:
+                    self.device_encode_seconds += enc.wall
+                    self.batches_run += 1
+                    self.blocks_encoded += b_real
+                    self.blocks_padded += b_pad
+                    self.d2h_bytes += d2h
+                    self.encoded_user_bytes += b_real * self.block_size
+                    if pipe.mesh is not None:
+                        dp = pipe.mesh.shape["dp"]
+                        per = b_pad // dp
+                        for g in range(min(dp, len(self.chip_blocks))):
+                            self.chip_blocks[g] += min(max(b_real - g * per, 0), per)
+                for i, req in enumerate(batch):
+                    req.future.set_result(
+                        (
+                            [shards_np[i, j].tobytes() for j in range(k + m)],
+                            [digests_np[i, j].tobytes() for j in range(k + m)],
+                        )
                     )
-                )
         except Exception as e:  # noqa: BLE001
             for req in batch:
                 if not req.future.done():
@@ -452,15 +499,10 @@ class BatchingDeviceCodec(BlockCodec):
             arr = np.zeros((b_pad, k, s_pad), dtype=np.uint8)
             for i, d in enumerate(datas):
                 arr[i, :, : shard_lens[i]] = rs_matrix.split(d, k)
-            t0 = _time.perf_counter()
-            c0 = _time.thread_time()
-            parity = np.asarray(pipe.encode_parity(arr))  # [b_pad, M, s_pad]
-            dt = _time.perf_counter() - t0
-            GLOBAL_PERF.ledger.record(
-                "codec", "encode-batch-small", dt, _time.thread_time() - c0
-            )
+            with tracing.stage("encode-batch-small", "codec") as st:
+                parity = np.asarray(pipe.encode_parity(arr))  # [b_pad, M, s_pad]
             with self._stats_lock:
-                self.device_encode_seconds += dt
+                self.device_encode_seconds += st.wall
                 self.small_batches_run += 1
                 self.small_blocks_encoded += b_real
                 self.small_blocks_padded += b_pad
@@ -550,17 +592,12 @@ class BatchingDeviceCodec(BlockCodec):
                 return self._host.reconstruct_batch(rows_batch, k, m, want, with_digests)
             _, surv, s = plan
             self._ensure_worker(k, m)
-            t0 = _time.perf_counter()
-            c0 = _time.thread_time()
-            out = run_device_reconstruct(
-                self._pipelines[(k, m)], rows_batch, k, tuple(want), surv, s, with_digests
-            )
-            dt = _time.perf_counter() - t0
-            GLOBAL_PERF.ledger.record(
-                "codec", "reconstruct-batch", dt, _time.thread_time() - c0
-            )
+            with tracing.stage("reconstruct-batch", "codec") as st:
+                out = run_device_reconstruct(
+                    self._pipelines[(k, m)], rows_batch, k, tuple(want), surv, s, with_digests
+                )
             with self._stats_lock:
-                self.device_recon_seconds += dt
+                self.device_recon_seconds += st.wall
                 self.recon_batches_run += 1
                 self.blocks_reconstructed += len(rows_batch)
             return out
@@ -616,15 +653,10 @@ class BatchingDeviceCodec(BlockCodec):
             arr = np.zeros((n_pad, 1, len(sub[0])), dtype=np.uint8)
             for i, c in enumerate(sub):
                 arr[i, 0] = np.frombuffer(c, dtype=np.uint8)
-            t0 = _time.perf_counter()
-            c0 = _time.thread_time()
-            digs = np.asarray(pipe.verify_digests(arr))  # [n_pad, 1, 32]
-            dt = _time.perf_counter() - t0
-            GLOBAL_PERF.ledger.record(
-                "codec", "verify-batch", dt, _time.thread_time() - c0
-            )
+            with tracing.stage("verify-batch", "codec") as st:
+                digs = np.asarray(pipe.verify_digests(arr))  # [n_pad, 1, 32]
             with self._stats_lock:
-                self.device_verify_seconds += dt
+                self.device_verify_seconds += st.wall
                 self.verify_batches_run += 1
                 self.digests_verified += len(sub)
             out.extend(digs[i, 0].tobytes() for i in range(len(sub)))
@@ -666,6 +698,12 @@ class BatchingDeviceCodec(BlockCodec):
                 "device_encode_seconds": self.device_encode_seconds,
                 "device_recon_seconds": self.device_recon_seconds,
                 "device_verify_seconds": self.device_verify_seconds,
+                "queue_wait_block_seconds": self.queue_wait_block_seconds,
+                "worker_idle_seconds": self.worker_idle_seconds,
+                "worker_wall_seconds": self.worker_wall_seconds,
+                "h2d_bytes": self.h2d_bytes,
+                "d2h_bytes": self.d2h_bytes,
+                "encoded_user_bytes": self.encoded_user_bytes,
                 "compiled_verify_lens": len(self._verify_lens),
             }
 

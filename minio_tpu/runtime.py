@@ -55,6 +55,7 @@ from dataclasses import dataclass, field
 
 
 from .object import codec as codec_mod
+from .control import tracing
 from .control.logging import GLOBAL_LOGGER
 from .control.sanitizer import san_lock, san_rlock
 
@@ -482,6 +483,12 @@ def _make_batching(expect: str, geometry: tuple[int, int] | None):
 def _announce(report: dict) -> None:
     global _install
     _install = {"state": "serving", **report}
+    # The device codec serves, so jax is open in this process: from here on
+    # every span and stage is also a host event of any profiler trace, on
+    # the clock of the device's ops (control/tracing.py set_annotator).
+    import jax
+
+    tracing.set_annotator(jax.profiler.TraceAnnotation)
     kern = report["kernels"]
     warm = report["warm"] or {"programs": 0, "seconds": 0.0}
     GLOBAL_LOGGER.info(
@@ -728,10 +735,42 @@ def _no_device_reason(probe: ProbeResult) -> str:
     return f"device probe failed: {probe.error}"
 
 
+def device_trace_start(log_dir: str) -> None:
+    """Start a profiler trace of this process (the admin profile's
+    `device=1`): device ops plus the program's own annotations; the Python
+    call tracer stays off (it would log every call of a busy server)."""
+    if _install.get("state") != "serving":
+        raise RuntimeError("no device codec serves on this node: nothing to trace")
+    import jax
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(log_dir, profiler_options=opts)
+
+
+def device_trace_stop(log_dir: str) -> tuple[str, dict]:
+    """Stop the trace: (path of the .xplane.pb, its control/devtrace.py
+    reduction -- busy/idle, seconds per program, the longest idle gaps with
+    the host stages that overlap each)."""
+    import glob
+
+    import jax
+
+    from .control import devtrace
+
+    jax.profiler.stop_trace()
+    found = sorted(glob.glob(os.path.join(log_dir, "plugins", "profile", "*", "*.xplane.pb")))
+    if not found:
+        raise RuntimeError(f"the profiler wrote no .xplane.pb under {log_dir}")
+    return found[-1], devtrace.summarize(devtrace.load(found[-1]))
+
+
 def shutdown_data_plane(codec: codec_mod.BlockCodec | None = None) -> None:
     """Close the batching codec (if installed); safe to call many times."""
     global _closed
     _reprobe_stop.set()
+    tracing.set_annotator(None)
     with _state_lock:
         _closed = True
         targets = {id(codec): codec, id(codec_mod._default): codec_mod._default}

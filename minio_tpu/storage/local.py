@@ -42,11 +42,10 @@ from __future__ import annotations
 import os
 import shutil
 import threading
-import time
 from dataclasses import dataclass
 
 from ..chaos import crash
-from ..control.perf import GLOBAL_PERF
+from ..control import tracing
 from ..utils import errors
 from .format import SYS_DIR, DriveFormat
 from .interface import StorageAPI
@@ -72,9 +71,8 @@ def fsync_mode() -> str:
 def _sync_fd(fd: int, *, datasync: bool = True) -> None:
     """Metered sync barrier: every fdatasync/fsync the durability discipline
     issues lands in the ("storage", "drive-sync") ledger stage."""
-    t0 = time.perf_counter()
-    (os.fdatasync if datasync else os.fsync)(fd)
-    GLOBAL_PERF.ledger.record("storage", "drive-sync", time.perf_counter() - t0)
+    with tracing.stage("drive-sync", "storage"):
+        (os.fdatasync if datasync else os.fsync)(fd)
 
 
 def _sync_path(p: str, *, datasync: bool = True) -> None:

@@ -11,9 +11,8 @@ from __future__ import annotations
 
 import inspect
 import threading
-import time
 
-from ..control.perf import GLOBAL_PERF
+from ..control import tracing
 from ..control.profiler import COPIED, GLOBAL_PROFILER, MOVED
 from ..control.sanitizer import san_lock, san_rlock
 
@@ -55,16 +54,12 @@ class MeteredDrive:
         if name not in _METERED or not callable(attr):
             return attr
 
-        def record(t0: float, c0: float, failed: bool) -> None:
-            dt = time.perf_counter() - t0
-            ms = dt * 1e3
-            # Always-on attribution: storage calls feed the stage ledger
-            # directly (one bucket increment) -- drive fan-out pool threads
-            # have no span context, so Span.finish can't cover them. The
-            # thread_time delta is valid because record runs on the calling
-            # thread: wall >> cpu here means the drive (or page cache) is
-            # the wait, not the interpreter.
-            GLOBAL_PERF.ledger.record("storage", name, dt, time.thread_time() - c0)
+        # Always-on attribution: every storage call is a tracing.stage --
+        # drive fan-out pool threads have no span context, so Span.finish
+        # can't cover them. Wall >> cpu on a row means the drive (or page
+        # cache) is the wait, not the interpreter.
+        def record(st: tracing.stage, failed: bool) -> None:
+            ms = st.wall * 1e3
             with self._lock:
                 if failed:
                     self._errors[name] = self._errors.get(name, 0) + 1
@@ -75,8 +70,6 @@ class MeteredDrive:
                 self._counts[name] = self._counts.get(name, 0) + 1
             trace = self.trace
             if trace is not None and trace.enabled():
-                from ..control import tracing
-
                 # When a request trace is active, the storage call is a span
                 # in its tree (per-drive children of the object-layer span);
                 # otherwise it stays a flat storage record.
@@ -106,26 +99,26 @@ class MeteredDrive:
             # Generators (walk_dir): time the FULL iteration and count errors
             # raised mid-stream — timing creation alone would always read 0.
             def timed_gen(*args, **kwargs):
-                t0 = time.perf_counter()
-                c0 = time.thread_time()
+                st = tracing.stage(name, "storage")
                 try:
-                    yield from attr(*args, **kwargs)
+                    with st:
+                        yield from attr(*args, **kwargs)
                 except Exception:
-                    record(t0, c0, failed=True)
+                    record(st, failed=True)
                     raise
-                record(t0, c0, failed=False)
+                record(st, failed=False)
 
             return timed_gen
 
         def timed(*args, **kwargs):
-            t0 = time.perf_counter()
-            c0 = time.thread_time()
+            st = tracing.stage(name, "storage")
             try:
-                out = attr(*args, **kwargs)
+                with st:
+                    out = attr(*args, **kwargs)
             except Exception:
-                record(t0, c0, failed=True)
+                record(st, failed=True)
                 raise
-            record(t0, c0, failed=False)
+            record(st, failed=False)
             if name == "append_iov":
                 iovecs = kwargs.get("iovecs") if len(args) < 3 else args[2]
                 if iovecs:

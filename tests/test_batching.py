@@ -256,3 +256,143 @@ def test_scanner_deep_scan_runs_device_verify(tmp_path):
         assert batcher.digests_verified >= 16  # at least one full row set
     finally:
         batcher.close()
+
+
+# -- the life of a batch: ledger rows and counters ----------------------------
+
+_BATCH_STAGES = ("worker-idle", "collect", "pack", "h2d", "device-wait", "d2h", "scatter")
+
+
+def _codec_rows():
+    from minio_tpu.control.perf import GLOBAL_PERF
+
+    return GLOBAL_PERF.ledger.snapshot()["stages"].get("codec", {})
+
+
+def _encode_some(codec, k, m, n_blocks, n_threads=3):
+    rng = np.random.default_rng(42)
+    blocks = [rng.integers(0, 256, BLOCK).astype(np.uint8).tobytes() for _ in range(n_blocks)]
+    threads = [
+        threading.Thread(target=codec.encode, args=(blocks[i::n_threads], k, m))
+        for i in range(n_threads)
+    ]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+
+
+def test_batch_life_rows_exist_after_an_encode():
+    """Every stage of a full-block batch is a declared ledger row with at
+    least one observation, and encode-batch stays beside them."""
+    from minio_tpu.control.perf import STAGES
+
+    before = {s: sum(h["counts"]) for s, h in _codec_rows().items()}
+    codec = BatchingDeviceCodec(block_size=BLOCK, max_batch=8, batch_timeout_s=0.002)
+    try:
+        _encode_some(codec, 4, 2, 6)
+    finally:
+        codec.close()
+    rows = _codec_rows()
+    for stage in _BATCH_STAGES + ("queue-wait", "encode-batch"):
+        assert ("codec", stage) in STAGES, stage
+        assert sum(rows[stage]["counts"]) > before.get(stage, 0), stage
+    # h2d and scatter burn the worker's core; their cpu is recorded.
+    assert rows["pack"]["cpu"] > 0.0
+
+
+_CLOSURE_SCRIPT = """
+import json, threading
+import numpy as np
+from minio_tpu.control.perf import GLOBAL_PERF
+from minio_tpu.parallel.batching import BatchingDeviceCodec
+
+BLOCK = 1 << 20
+STAGES = ("worker-idle", "collect", "pack", "h2d", "device-wait", "d2h", "scatter")
+
+def stage_sum():
+    rows = GLOBAL_PERF.ledger.snapshot()["stages"].get("codec", {})
+    return sum(rows[s]["sum"] for s in STAGES if s in rows)
+
+codec = BatchingDeviceCodec(block_size=BLOCK, max_batch=8, batch_timeout_s=0.002)
+codec.encode([bytes(BLOCK)], 4, 2)  # compile outside the measured stretch
+rng = np.random.default_rng(42)
+blocks = [rng.integers(0, 256, BLOCK).astype(np.uint8).tobytes() for _ in range(9)]
+threads = [threading.Thread(target=codec.encode, args=(blocks[i::3], 4, 2)) for i in range(3)]
+for t in threads: t.start()
+for t in threads: t.join()
+codec.close()  # the worker's last iteration lands in the counters
+st = codec.stats()
+print(json.dumps({"stages": stage_sum(), "wall": st["worker_wall_seconds"],
+                  "idle": st["worker_idle_seconds"],
+                  "queue_wait": st["queue_wait_block_seconds"]}))
+"""
+
+
+def test_batch_stages_close_on_worker_wall():
+    """idle + collect + pack + h2d + device-wait + d2h + scatter accounts
+    for the worker loop's whole time: what is left is the bookkeeping
+    between the stages. Run in a process of its own: the ledger is the
+    process's, and any other live codec's worker idles into the same rows."""
+    import json
+    import os
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = {**os.environ, "JAX_PLATFORMS": "cpu", "PYTHONPATH": root}
+    done = subprocess.run([sys.executable, "-c", _CLOSURE_SCRIPT], env=env, cwd=root,
+                          capture_output=True, text=True, timeout=300)
+    assert done.returncode == 0, done.stderr[-2000:]
+    got = json.loads(done.stdout.strip().splitlines()[-1])
+    assert got["wall"] > 0 and 0 <= got["idle"] <= got["wall"]
+    assert got["stages"] == pytest.approx(got["wall"], rel=0.05, abs=0.005)
+    assert got["queue_wait"] > 0
+
+
+def test_transfer_bytes_equal_the_shapes():
+    """h2d_bytes / d2h_bytes are the padded arrays' bytes, the same counts
+    land in the copy ledger as hops, and encoded_user_bytes counts only real
+    blocks."""
+    from minio_tpu.control.profiler import GLOBAL_PROFILER
+    from minio_tpu.ops import rs_matrix
+
+    k, m = 4, 2
+    s = rs_matrix.shard_size(BLOCK, k)
+    hops0 = GLOBAL_PROFILER.copy.snapshot()["hops"]
+    codec = BatchingDeviceCodec(block_size=BLOCK, max_batch=8, batch_timeout_s=0.05)
+    try:
+        _encode_some(codec, k, m, 5)
+        st = codec.stats()
+    finally:
+        codec.close()
+    assert st["blocks_encoded"] == 5
+    slots = st["blocks_padded"]
+    assert st["h2d_bytes"] == slots * k * s
+    assert st["d2h_bytes"] == slots * (k + m) * (s + 32)
+    assert st["encoded_user_bytes"] == 5 * BLOCK
+    hops1 = GLOBAL_PROFILER.copy.snapshot()["hops"]
+    for hop, key in (("device-h2d", "h2d_bytes"), ("device-d2h", "d2h_bytes")):
+        was = hops0.get(hop, {}).get("copied_bytes", 0)
+        assert hops1[hop]["copied_bytes"] - was == st[key]
+        assert hops1[hop]["moved_bytes"] == hops0.get(hop, {}).get("moved_bytes", 0)
+
+
+def test_codec_programs_carry_names():
+    """The jitted callables' names carry the geometry (a profiler trace's
+    module line reads `jit_<name>`), and the halves of the programs carry
+    `mtpu.*` scopes in their ops' metadata, down to the compiled HLO."""
+    from minio_tpu.models import pipeline
+    from minio_tpu.models.pipeline import ErasurePipeline, Geometry
+
+    pipe = ErasurePipeline(Geometry(4, 2, BLOCK))
+    x = np.zeros((2, 4, BLOCK // 4), np.uint8)
+    enc = pipe._encode_fn.lower(x)
+    assert "module @jit_mtpu_encode_hash_k4m2 " in enc.as_text()
+    compiled = enc.compile().as_text()
+    assert "mtpu.rs_encode" in compiled and "mtpu.hh256" in compiled
+    par = pipe._parity_fn.lower(x).as_text(debug_info=True)
+    assert "module @jit_mtpu_parity_k4m2 " in par and "mtpu.rs_parity_small" in par
+    w = np.zeros((4 * 8, 2 * 8), np.int8)
+    rec = pipeline._reconstruct_step.lower(x, w, None).as_text(debug_info=True)
+    assert "module @jit_mtpu_reconstruct " in rec and "mtpu.rs_reconstruct" in rec

@@ -533,7 +533,16 @@ class TestFlightGateEndToEnd:
                                               "read_file_into"],
                                       "probability": 1.0, "seed": 7}}]},
             ],
-            "flight": {"phase": "faulted", "max_wait_s": 10},
+            # The gate polls for the condition asserted below (a bundle on
+            # every node, window inside the faulted phase); max_wait_s only
+            # bounds a run in which no trigger ever fires. The trigger must
+            # not hang on the drive breakers happening to trip (5 errors in
+            # a row per drive, which a slow machine's interleaved metadata
+            # reads prevent): every failed GET -- refused by an open breaker
+            # or aborted mid-stream after a 200 -- is an error in the ops/s
+            # ring, so the error-spike trigger fires on the first closed
+            # second of the fault whatever the machine's speed.
+            "flight": {"phase": "faulted", "max_wait_s": 30},
         })
         # Env must be live BEFORE the cluster builds: Node.build() arms the
         # trigger engine (ensure_started re-reads every MTPU_FLIGHT_* knob).

@@ -293,6 +293,25 @@ def test_stage_key_quiet_on_registered_and_dynamic(tmp_path):
     assert findings == []
 
 
+def test_stage_key_covers_worker_thread_stages(tmp_path):
+    """tracing.stage(name, layer) -- the worker-thread helper -- is held to
+    the registry like a span: one typo fires, a registered key is quiet."""
+    findings = run_rule(tmp_path, {
+        "minio_tpu/control/perf.py": _PERF_FIXTURE,
+        "minio_tpu/object/x.py": """
+            def f(name):
+                with tracing.stage("encode", "object"):
+                    pass
+                with tracing.stage(name, "rpc"):
+                    pass
+                with tracing.stage("pakc", "object"):
+                    pass
+        """,
+    }, StageKeyRule())
+    assert len(findings) == 1
+    assert "('object', 'pakc')" in findings[0].message
+
+
 def test_stage_key_reports_missing_registry(tmp_path):
     findings = run_rule(tmp_path, {
         "minio_tpu/control/perf.py": "X = 1\n",

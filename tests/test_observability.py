@@ -328,3 +328,283 @@ class TestIAMCascade:
         assert sa.access_key not in iam.users, "service account survived cascade"
         with pytest.raises(errors.StorageError):
             iam.remove_user("alice")
+
+
+# -- one timeline: stages outside a request, the annotator, request waits -----
+
+
+class _FakeAnnotation:
+    def __init__(self, log, label, kw):
+        self.log, self.label, self.kw = log, label, kw
+
+    def __enter__(self):
+        self.log.append(("enter", self.label, self.kw))
+        return self
+
+    def __exit__(self, *exc):
+        self.log.append(("exit", self.label, self.kw))
+        return False
+
+
+@pytest.fixture
+def annotations(monkeypatch):
+    """A fake annotator installed for the test, the log of what it saw."""
+    log: list[tuple] = []
+    monkeypatch.setattr(
+        tracing, "_annotator", lambda label, **kw: _FakeAnnotation(log, label, kw))
+    return log
+
+
+def _ledger_row(layer: str, stage: str) -> dict:
+    from minio_tpu.control.perf import GLOBAL_PERF
+
+    row = GLOBAL_PERF.ledger.snapshot()["stages"].get(layer, {}).get(stage)
+    return {"count": sum(row["counts"]), "sum": row["sum"], "cpu": row["cpu"]} if row else {
+        "count": 0, "sum": 0.0, "cpu": 0.0}
+
+
+class TestStageAndAnnotator:
+    def test_stage_records_wall_and_cpu_without_a_request(self):
+        """stage() is what a span would be on a worker thread: outside any
+        request and with nobody on the hub it still feeds the ledger."""
+        assert tracing.current() is None and not GLOBAL_TRACE.enabled()
+        before = _ledger_row("codec", "pack")
+        with tracing.stage("pack", "codec") as st:
+            sum(range(20000))
+        after = _ledger_row("codec", "pack")
+        assert after["count"] == before["count"] + 1
+        assert st.wall > 0 and st.cpu > 0
+        assert after["sum"] - before["sum"] == pytest.approx(st.wall)
+        assert after["cpu"] - before["cpu"] == pytest.approx(st.cpu)
+
+    def test_annotator_sees_every_context_managed_span_and_stage_in_order(self, annotations):
+        with tracing.root_span("PutObject", "api", "TRACE9"):
+            with tracing.span("encode", "object"):
+                with tracing.stage("pack", "codec"):
+                    pass
+            with tracing.span("commit", "object"):
+                pass
+        assert [(e, label) for e, label, _ in annotations] == [
+            ("enter", "api/PutObject"),
+            ("enter", "object/encode"),
+            ("enter", "codec/pack"),
+            ("exit", "codec/pack"),
+            ("exit", "object/encode"),
+            ("enter", "object/commit"),
+            ("exit", "object/commit"),
+            ("exit", "api/PutObject"),
+        ]
+        # Spans carry the request's trace id; a stage has no ids.
+        for _, label, kw in annotations:
+            assert kw == ({} if label == "codec/pack" else {"trace": "TRACE9"})
+
+    def test_hand_finished_span_is_not_annotated(self, annotations):
+        """response-write is opened and finished by hand, possibly on two
+        threads: it feeds the ledger and stays off the host timeline."""
+        before = _ledger_row("api", "response-write")
+        with tracing.root_span("GetObject", "api", "TRACE10"):
+            wr = tracing.span("response-write", "api")
+            wr.finish()
+        assert _ledger_row("api", "response-write")["count"] == before["count"] + 1
+        assert [label for _, label, _ in annotations] == ["api/GetObject"] * 2
+
+    def test_nothing_is_annotated_when_unset(self, annotations):
+        tracing.set_annotator(None)
+        with tracing.root_span("PutObject", "api", "TRACE11"):
+            with tracing.stage("pack", "codec"):
+                pass
+        assert annotations == []
+
+
+class TestRequestWaitStages:
+    """Where a streamed request waits: every named stage of a PUT and the
+    two halves of a GET's response-write land in the ledger."""
+
+    BODY = bytes(range(256)) * (3 << 10)  # 768 KiB: the streaming path
+
+    def test_streamed_put_names_its_waits(self, cluster):
+        client = cluster["clients"][0]
+        rows = [("object", "window-wait"), ("api", "body-hop"),
+                ("api", "payload-hash"), ("api", "body-fill")]
+        before = {r: _ledger_row(*r) for r in rows}
+        assert client.put_object("obs", "waits.bin", self.BODY).status_code == 200
+        after = {r: _ledger_row(*r) for r in rows}
+        # One record per request for the accumulated rows, however many
+        # body chunks there were; a window-wait per window asked for.
+        assert after[("api", "body-hop")]["count"] == before[("api", "body-hop")]["count"] + 1
+        assert after[("api", "payload-hash")]["count"] == (
+            before[("api", "payload-hash")]["count"] + 1)
+        assert after[("api", "payload-hash")]["cpu"] > before[("api", "payload-hash")]["cpu"]
+        assert after[("object", "window-wait")]["count"] > before[("object", "window-wait")]["count"]
+        assert after[("api", "body-fill")]["count"] > before[("api", "body-fill")]["count"]
+        for r in rows:
+            assert after[r]["sum"] > before[r]["sum"], r
+
+    def test_stream_pull_and_socket_write_sum_to_response_write(self, cluster):
+        client = cluster["clients"][0]
+        assert client.put_object("obs", "halves.bin", self.BODY).status_code == 200
+        rows = [("api", "response-write"), ("api", "stream-pull"), ("api", "socket-write")]
+        before = {r: _ledger_row(*r) for r in rows}
+        got = client.get_object("obs", "halves.bin")
+        assert got.status_code == 200 and got.content == self.BODY
+        # The client has its last byte before the server closes the span.
+        import time
+
+        deadline = time.monotonic() + 5
+        while (_ledger_row("api", "socket-write")["count"] == before[rows[2]]["count"]
+               and time.monotonic() < deadline):
+            time.sleep(0.01)
+        d = {r[1]: {k: _ledger_row(*r)[k] - before[r][k] for k in ("count", "sum")}
+             for r in rows}
+        assert d["stream-pull"]["count"] == d["socket-write"]["count"] == 1
+        assert d["response-write"]["count"] == 1
+        assert d["stream-pull"]["sum"] + d["socket-write"]["sum"] == pytest.approx(
+            d["response-write"]["sum"], rel=0.1, abs=0.005)
+
+
+class TestProcessWatch:
+    def test_gc_pause_is_recorded_outside_the_callback(self):
+        """The gc hook only queues the pause (it may run inside the
+        ledger's own lock); flush() -- the GIL probe's tick -- records it."""
+        import gc
+
+        from minio_tpu.control.profiler import GcWatch
+
+        watch = GcWatch()
+        watch.install()
+        try:
+            before = _ledger_row("runtime", "gc-pause")
+            gc.collect()
+            assert _ledger_row("runtime", "gc-pause")["count"] == before["count"]
+            watch.flush()
+            after = _ledger_row("runtime", "gc-pause")
+        finally:
+            watch.remove()
+        assert after["count"] >= before["count"] + 1
+        assert watch.collections[2] >= 1
+        assert watch._on_gc not in gc.callbacks
+
+    def test_gil_probe_ticks_feed_the_ledger(self):
+        import time
+
+        from minio_tpu.control.profiler import GilLoadProbe
+
+        before = _ledger_row("runtime", "gil-wake-late")
+        probe = GilLoadProbe(interval_s=0.002)
+        probe.start()
+        try:
+            deadline = time.monotonic() + 5
+            while probe.ticks < GilLoadProbe._CALIB_TICKS + 3 and time.monotonic() < deadline:
+                time.sleep(0.01)
+        finally:
+            probe.stop()
+        # Calibration ticks set the floor and record nothing; later ones do.
+        assert _ledger_row("runtime", "gil-wake-late")["count"] > before["count"]
+
+    def test_serving_loop_heartbeat_records_lag(self, cluster):
+        import time
+
+        before = _ledger_row("runtime", "loop-lag")
+        deadline = time.monotonic() + 5
+        while (_ledger_row("runtime", "loop-lag")["count"] < before["count"] + 2
+               and time.monotonic() < deadline):
+            time.sleep(0.05)
+        assert _ledger_row("runtime", "loop-lag")["count"] >= before["count"] + 2
+
+    def test_background_wakers_record_their_wake_ups(self, cluster):
+        node = cluster["nodes"][0]
+        before = _ledger_row("background", "scanner-cycle")
+        node.scanner._stop.clear()
+        node.scanner.cycle_seconds = 3600
+        node.scanner.start()
+        try:
+            import time
+
+            deadline = time.monotonic() + 20
+            while (_ledger_row("background", "scanner-cycle")["count"] == before["count"]
+                   and time.monotonic() < deadline):
+                time.sleep(0.05)
+        finally:
+            node.scanner.stop()
+        assert _ledger_row("background", "scanner-cycle")["count"] == before["count"] + 1
+
+
+class TestDeviceProfile:
+    def test_profile_with_device_returns_xplane_and_devtrace(self, cluster, monkeypatch):
+        """The operator's path: profile/start?device=1 ... profile/stop gives
+        a zip with the .xplane.pb and devtrace.json beside profile.txt. On
+        the CPU backend there is no device plane; the host annotations of
+        the PUT made meanwhile must be in the trace."""
+        import io
+        import json
+        import zipfile
+
+        import jax
+
+        from minio_tpu import runtime
+        from minio_tpu.control import devtrace
+
+        client = cluster["clients"][0]
+        r = client.request("POST", "/mtpu/admin/v1/profile/start", query=[("device", "1")])
+        assert r.status_code == 400, "no device codec serves: device=1 must be refused"
+
+        monkeypatch.setitem(runtime._install, "state", "serving")
+        monkeypatch.setattr(tracing, "_annotator", jax.profiler.TraceAnnotation)
+        r = client.request("POST", "/mtpu/admin/v1/profile/start", query=[("device", "1")])
+        assert r.status_code == 200, r.text
+        assert client.put_object("obs", "devtrace.bin", b"d" * (512 << 10)).status_code == 200
+        r = client.request("POST", "/mtpu/admin/v1/profile/stop")
+        assert r.status_code == 200, r.text
+        z = zipfile.ZipFile(io.BytesIO(r.content))
+        names = z.namelist()
+        assert "local/profile.txt" in names and "local/devtrace.json" in names
+        xplanes = [n for n in names if n.endswith(".xplane.pb")]
+        assert len(xplanes) == 1
+        reduced = json.loads(z.read("local/devtrace.json"))
+        assert reduced["host_annotations"] > 0 and reduced["devices"] == {}
+        import tempfile
+
+        with tempfile.TemporaryDirectory() as tmp:
+            z.extract(xplanes[0], tmp)
+            host = {name for name, _, _ in devtrace.load(str(Path(tmp) / xplanes[0]))["host"]}
+        assert {"api/PutObject", "object/object.PutObject", "object/encode",
+                "object/window-wait", "api/body-fill"} <= host, sorted(host)
+
+
+class TestAbortedStreamIsAnError:
+    def test_get_that_dies_mid_stream_is_an_error_in_the_ops_ring(self, cluster):
+        """A GET whose shard reads fail under the lazy body stream answered
+        200 and then lost its connection: the client saw an error, so the
+        ops/s ring (what the flight recorder's error-spike trigger reads)
+        must count one -- not an ok because the status line said 200."""
+        import time
+
+        import requests
+
+        from minio_tpu.chaos.faults import REGISTRY, FaultSpec
+        from minio_tpu.control.perf import GLOBAL_PERF
+
+        def get_errors() -> int:
+            return sum(e["classes"].get("get", {}).get("errors", 0)
+                       for e in GLOBAL_PERF.timeseries.snapshot()["series"])
+
+        client = cluster["clients"][0]
+        body = bytes(range(256)) * (3 << 10)
+        assert client.put_object("obs", "dies.bin", body).status_code == 200
+        before = get_errors()
+        fid = REGISTRY.arm(FaultSpec.from_dict({
+            "kind": "drive-error", "ops": ["read_file", "read_file_into"],
+            "probability": 1.0, "seed": 1}))
+        try:
+            try:
+                r = client.get_object("obs", "dies.bin")
+                failed = r.status_code >= 400 or r.content != body
+            except requests.exceptions.RequestException:
+                failed = True  # the connection was closed under the body
+        finally:
+            REGISTRY.disarm(fid)
+        assert failed, "every shard read failed, the GET cannot have succeeded"
+        deadline = time.monotonic() + 5
+        while get_errors() == before and time.monotonic() < deadline:
+            time.sleep(0.02)
+        assert get_errors() == before + 1

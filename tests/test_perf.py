@@ -384,10 +384,24 @@ class TestCodecObservatory:
             "minio_tpu_codec_batch_occupancy",
             "minio_tpu_codec_host_fallback_total",
             "minio_tpu_codec_compiled_verify_lengths",
-            "minio_tpu_codec_device_seconds_total",
+            "minio_tpu_codec_roundtrip_seconds_total",
+            "minio_tpu_codec_worker_seconds_total",
+            "minio_tpu_codec_transfer_bytes_total",
             "minio_tpu_native_codec_available",
         ):
             assert series in text, series
+        # The device-codec section (absent from the CPU cluster tests, which
+        # serve the host codec) is a valid exposition too.
+        import importlib.util
+        from pathlib import Path
+
+        spec = importlib.util.spec_from_file_location(
+            "metrics_lint", Path(__file__).resolve().parent.parent / "tools" / "metrics_lint.py"
+        )
+        lint = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(lint)
+        assert lint.validate_exposition(text) == []
+        assert lint.lint_exposition(text) == []
 
     def test_batch_latencies_feed_ledger(self):
         """Host-fallback-eligible work still routes through digests_batch's

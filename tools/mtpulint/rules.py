@@ -509,7 +509,7 @@ class StageKeyRule(Rule):
                 if not isinstance(node, ast.Call):
                     continue
                 name = _call_name(node)
-                if name.endswith("tracing.span") or name.endswith("tracing.root_span"):
+                if name.endswith(("tracing.span", "tracing.root_span", "tracing.stage")):
                     if len(node.args) < 2:
                         continue
                     stage_arg, layer_arg = node.args[0], node.args[1]
