@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import asyncio
 import base64
+import collections
 import contextlib
 import datetime
 import hashlib
@@ -117,39 +118,111 @@ def _read_all(reader, chunk: int = 1 << 20) -> bytes:
         out += b
 
 
+# A streamed PUT body waits in its reader's queue, between the event loop
+# that receives it and the worker thread that lands it, up to this many bytes.
+_BODY_QUEUE_BYTES = 4 << 20
+
+
 class _RequestBodyReader:
     """Sync .read(n) / .readinto(buf) over an aiohttp request body.
 
-    The object layer streams from a worker thread; each refill hops to the
-    event loop for the next body chunk (readahead pipelining: the socket
-    fills while the previous block encodes). ``readany()`` hands back
-    aiohttp's buffered chunk as-is -- ``content.read(n)`` would re-slice
-    and re-join it -- and ``readinto`` lands it straight into the caller's
-    pooled buffer: one landing, no intermediate bytes staging (the
-    recv_into fix for the socket-read double copy)."""
+    The object layer streams from a worker thread. A pump task on the event
+    loop takes each body chunk as aiohttp has it -- ``readany()`` hands back
+    the buffered chunk as-is, where ``content.read(n)`` would re-slice and
+    re-join it -- and queues it; the worker takes chunks off the queue and
+    never crosses to the loop for one, so the socket fills while the
+    previous block encodes and no chunk waits for a round trip through the
+    loop. The queue is bounded by bytes (_BODY_QUEUE_BYTES): a full queue
+    parks the pump, which stops aiohttp reading the socket, and the worker
+    wakes it once when half has been taken. ``readinto`` lands a chunk
+    straight into the caller's pooled buffer: one landing, no intermediate
+    bytes staging (the recv_into fix for the socket-read double copy).
+
+    Made on the event loop; ``close()`` (on the loop too) stops the pump."""
 
     def __init__(self, request: web.Request, loop: asyncio.AbstractEventLoop):
         self._content = request.content
         self._loop = loop
         self._chunk: bytes = b""
         self._pos = 0
-        self._hop_s = 0.0  # seconds in the hops to the event loop, so far
+        self._wait_s = 0.0  # seconds the worker waited for the pump, so far
+        self._cond = threading.Condition()
+        self._queue: collections.deque[bytes] = collections.deque()
+        self._queued = 0  # bytes in _queue
+        self._done = False  # the pump has ended: EOF, an error, or close()
+        self._error: BaseException | None = None
+        self._parked = False  # the pump waits on _room for the worker
+        self._room = asyncio.Event()
+        # asyncio's socket transports recv() at most `max_size` bytes (256
+        # KiB) a wake-up. A body that arrives faster than the loop comes
+        # round is taken in fewer, larger pieces: every recv() gives up the
+        # GIL, and the loop waits its turn to have it back.
+        self._transport = request.transport
+        self._recv_size = getattr(self._transport, "max_size", None)
+        if self._recv_size is not None and self._recv_size < _BODY_QUEUE_BYTES:
+            self._transport.max_size = _BODY_QUEUE_BYTES
+        self._task = loop.create_task(self._pump())
+
+    async def _pump(self) -> None:
+        error: BaseException | None = None
+        try:
+            while True:
+                chunk = await self._content.readany()
+                if not chunk:
+                    return
+                with self._cond:
+                    self._queue.append(chunk)
+                    self._queued += len(chunk)
+                    self._cond.notify()
+                    if self._queued >= _BODY_QUEUE_BYTES:
+                        self._parked = True
+                        self._room.clear()
+                if self._parked:
+                    await self._room.wait()
+        except asyncio.CancelledError:
+            error = ConnectionError("request body reader closed")
+            raise
+        # mtpulint: disable=swallowed-except -- stored, re-raised in _refill
+        except Exception as e:  # noqa: BLE001 - handed to the worker, which raises it
+            error = e
+        finally:
+            with self._cond:
+                self._done, self._error = True, error
+                self._cond.notify_all()
 
     def _refill(self) -> bool:
         t0 = _time.perf_counter()
-        fut = asyncio.run_coroutine_threadsafe(self._content.readany(), self._loop)
-        self._chunk = fut.result(timeout=600)
-        self._hop_s += _time.perf_counter() - t0
+        wake = False
+        with self._cond:
+            while not self._queue and not self._done:
+                if not self._cond.wait(timeout=600):
+                    raise TimeoutError("no request body chunk in 600 s")
+            if self._queue:
+                self._chunk = self._queue.popleft()
+                self._queued -= len(self._chunk)
+                if self._parked and self._queued <= _BODY_QUEUE_BYTES // 2:
+                    self._parked, wake = False, True
+            else:
+                self._chunk = b""
+                if self._error is not None:
+                    raise self._error
+        if wake:
+            self._loop.call_soon_threadsafe(self._room.set)
+        self._wait_s += _time.perf_counter() - t0
         self._pos = 0
         return bool(self._chunk)
 
     def close(self) -> None:
-        """Record the body's hops as ONE api/body-hop observation (the
-        streaming PUT entry calls this when the request is done with the
-        body; a second call records nothing)."""
-        if self._hop_s:
-            GLOBAL_PERF.ledger.record("api", "body-hop", self._hop_s)
-            self._hop_s = 0.0
+        """Stop the pump and record the worker's waits for it as ONE
+        api/body-hop observation (the streaming PUT entry calls this, on the
+        loop, when the request is done with the body; a second call records
+        nothing)."""
+        self._task.cancel()
+        if self._recv_size is not None:
+            self._transport.max_size = self._recv_size
+        if self._wait_s:
+            GLOBAL_PERF.ledger.record("api", "body-hop", self._wait_s)
+            self._wait_s = 0.0
 
     def read(self, n: int) -> bytes:
         if n <= 0:
@@ -263,6 +336,55 @@ class _HashVerifyReader:
         if self._md5 is None or not self._checked:
             return None
         return self._md5.hexdigest()
+
+
+# A GET stream without next_batch() (a hot-tier hit, a legacy whole-file
+# part, the FS backend) is drained this many bytes per thread hop.
+_PULL_BATCH_BYTES = 4 << 20
+
+
+def _pull_batch(it) -> list:
+    """One thread hop's worth of a GET stream: every chunk of the read
+    window that is ready, where the stream can say (next_batch()); else
+    chunks up to _PULL_BATCH_BYTES. [] at the end of the stream. The
+    stream's buffers behind the previous batch recycle here, so the caller
+    holds none of its views by now.
+
+    A plain iterator gives no window boundary to stop at: a batch taken
+    from a generator that wraps a windowed stream may straddle two windows,
+    and the first one's buffers, still viewed here when it closes, are
+    discarded instead of pooled (release_or_discard). A layer that wraps
+    such a stream forwards next_batch() and close() to keep the pooling."""
+    take = getattr(it, "next_batch", None)
+    if take is not None:
+        return take()
+    batch, size = [], 0
+    for chunk in it:
+        batch.append(chunk)
+        size += len(chunk)
+        if size >= _PULL_BATCH_BYTES:
+            break
+    return batch
+
+
+async def _write_batch(request: web.Request, resp: web.StreamResponse, batch: list) -> None:
+    """One read window onto the socket, by reference. aiohttp writes the
+    first chunk (it puts the headers before it); the rest go to the transport
+    as one gathered write (sendmsg), not a send() -- and a wait for the GIL
+    after it -- per chunk: the response carries a Content-Length and no
+    content coding, so aiohttp has nothing to frame. Returns when the
+    transport holds none of it any more (its write buffer limits are 0
+    here), so a slow client holds one window and the caller may let the
+    stream recycle the buffers behind it."""
+    await resp.write(batch[0])
+    if len(batch) > 1:
+        transport = request.transport
+        if transport is None or transport.is_closing():
+            raise ConnectionResetError("Cannot write to closing transport")
+        transport.writelines(batch[1:])
+    # aiohttp's stream writer (StreamResponse.drain() is deprecated in favour
+    # of write(), which drains only past 64 KiB of its own writes).
+    await resp._payload_writer.drain()
 
 
 class _StreamPlan:
@@ -655,40 +777,44 @@ class S3Server:
         if clen is not None and clen > MAX_OBJECT_SIZE + (1 << 20):
             raise S3Error("EntityTooLarge")
         base = _RequestBodyReader(request, asyncio.get_running_loop())
-        with tracing.span("auth", "api"):
-            access_key, reader = await asyncio.to_thread(
-                self._authenticate_streaming, request, base
-            )
-        request["access_key"] = access_key
-        q = request.rel_url.query
-        action = policy_mod.s3_action("PUT", bucket, key, q)
-        await asyncio.to_thread(self._authorize, access_key, action, bucket, key, request)
-        # Quota for streaming bodies. aws-chunked requests declare the
-        # payload size in x-amz-decoded-content-length (a SIGNED header --
-        # Content-Length includes chunk framing); the header is honored only
-        # for actually-streaming-signed requests so a plain PUT cannot
-        # smuggle a small declared size past the check. Chunked transfers
-        # without a usable size check with 0, like the reference's
-        # unknown-size path.
-        from . import streaming as streaming_mod
-
-        decoded = request.headers.get("x-amz-decoded-content-length", "")
-        if streaming_mod.is_streaming_request(dict(request.headers)) and decoded.isdigit():
-            size = int(decoded)
-        else:
-            size = request.content_length or 0
-        await asyncio.to_thread(self._check_quota, bucket, size)
+        reader = None
         try:
+            with tracing.span("auth", "api"):
+                access_key, reader = await asyncio.to_thread(
+                    self._authenticate_streaming, request, base
+                )
+            request["access_key"] = access_key
+            q = request.rel_url.query
+            action = policy_mod.s3_action("PUT", bucket, key, q)
+            await asyncio.to_thread(self._authorize, access_key, action, bucket, key, request)
+            # Quota for streaming bodies. aws-chunked requests declare the
+            # payload size in x-amz-decoded-content-length (a SIGNED header --
+            # Content-Length includes chunk framing); the header is honored only
+            # for actually-streaming-signed requests so a plain PUT cannot
+            # smuggle a small declared size past the check. Chunked transfers
+            # without a usable size check with 0, like the reference's
+            # unknown-size path.
+            from . import streaming as streaming_mod
+
+            decoded = request.headers.get("x-amz-decoded-content-length", "")
+            if streaming_mod.is_streaming_request(dict(request.headers)) and decoded.isdigit():
+                size = int(decoded)
+            else:
+                size = request.content_length or 0
+            await asyncio.to_thread(self._check_quota, bucket, size)
             if "uploadId" in q and "partNumber" in q:
                 return await asyncio.to_thread(
                     self._upload_part, bucket, key, q["uploadId"], int(q["partNumber"]), reader
                 )
             return await asyncio.to_thread(self._put_object, bucket, key, reader, request)
         finally:
-            # One api/body-hop and one api/payload-hash record per request,
-            # whether the body reached EOF or the PUT failed half way.
+            # The body's pump stops here however the request ended (a PUT
+            # refused before it read a byte too). One api/body-hop and one
+            # api/payload-hash record per request, whether the body reached
+            # EOF or the PUT failed half way.
             base.close()
-            reader.close()
+            if reader is not None:
+                reader.close()
 
     @staticmethod
     def _policy_context(request: web.Request | None) -> dict:
@@ -2482,33 +2608,61 @@ class S3Server:
         resp.content_length = plan.content_length
         await resp.prepare(request)
         it = plan.iterator
+        # drain() returns when the transport's buffer is empty, not merely
+        # under a low-water mark: the transport keeps what the socket did
+        # not take at once as views, and those must be gone before the
+        # stream recycles the pooled buffers under them.
+        if request.transport is not None:
+            request.transport.set_write_buffer_limits(high=0)
         # One span over the whole body stream: covers both pulling chunks
         # out of the (lazy) erasure read generator and pushing them onto
         # the socket -- the time a GET spends after headers.
         wr = tracing.span("response-write", "api", bytes=plan.content_length)
         sent = 0
-        # The two halves of response-write, accumulated per chunk and
-        # recorded once per response: waiting for the read generator on a
-        # worker thread, and handing the chunk to the socket.
-        pull_s = write_s = 0.0
+        # The two halves of response-write, accumulated per batch and
+        # recorded once per response: waiting on a worker thread for the
+        # read stream's next window, and handing its chunks to the socket.
+        # pull-start is the part of stream-pull before the worker thread
+        # has the pull in hand: the executor's queue and the thread's wake.
+        pull_s = write_s = start_s = 0.0
+        hops = chunks = 0
+
+        def pull() -> list:
+            nonlocal start_s
+            start_s += _time.perf_counter() - t0
+            return _pull_batch(it)
 
         def finish(error: str | None = None) -> None:
             wr.finish(error=error)
             GLOBAL_PERF.ledger.record("api", "stream-pull", pull_s)
+            GLOBAL_PERF.ledger.record("api", "pull-start", start_s)
             GLOBAL_PERF.ledger.record("api", "socket-write", write_s)
+            if self.metrics is not None:
+                self.metrics.record_get_stream(hops, chunks)
 
         try:
             while True:
                 t0 = _time.perf_counter()
-                chunk = await asyncio.to_thread(next, it, None)
+                # One crossing per read window: the loop gets every chunk
+                # that is ready and hands them to the socket in one
+                # gathered write, then waits until the socket has taken
+                # them, so a slow client holds one window (+ the stager's
+                # read-ahead), never the object.
+                batch = await asyncio.to_thread(pull)
                 t1 = _time.perf_counter()
                 pull_s += t1 - t0
-                if chunk is None:
+                hops += 1
+                if not batch:
                     break
-                sent += len(chunk)
-                await resp.write(chunk)
+                chunks += len(batch)
+                await _write_batch(request, resp, batch)
+                sent += sum(len(chunk) for chunk in batch)
                 write_s += _time.perf_counter() - t1
+                # The next pull recycles this window's pooled buffers:
+                # no view of it may still be held here.
+                batch = None
         except Exception as e:
+            batch = None
             # Headers (and a Content-Length promise) are already on the
             # wire: substituting an error response here would interleave
             # a second set of headers into the half-sent body and leave
@@ -2525,15 +2679,23 @@ class S3Server:
             # The status line said 200 before the read failed: _entry counts
             # the request as the error the client saw.
             request["stream_aborted"] = True
+            # What the transport still holds of this window goes with the
+            # connection (abort), before the stream recycles its buffers.
+            transport = request.transport
+            if transport is not None:
+                if transport.get_write_buffer_size():
+                    transport.abort()
+                else:
+                    transport.close()
             with contextlib.suppress(Exception):
                 it.close()
-            if request.transport is not None:
-                request.transport.close()
         else:
             finish()
             GLOBAL_PROFILER.copy.record("response-write", MOVED, sent)
             with contextlib.suppress(Exception):
                 await resp.write_eof()
+            if request.transport is not None:
+                request.transport.set_write_buffer_limits()
         return resp
 
     # -- object tagging / object lock ----------------------------------------

@@ -63,6 +63,10 @@ class MetricsSys:
         self.api_hist_sum: dict[str, float] = defaultdict(float)
         self.bytes_received = 0
         self.bytes_sent = 0
+        # Streamed GET responses: thread hops taken for the read stream's
+        # batches, and chunks written (api/server.py _send_stream).
+        self.get_stream_hops = 0
+        self.get_stream_chunks = 0
         self.start_time = time.time()
         self.layer = None  # set by the server for storage gauges
         self.replication = None  # ReplicationSys for replication gauges
@@ -100,6 +104,11 @@ class MetricsSys:
             self.api_hist_sum[api] += seconds
         self.api_latency[api].add(seconds)
 
+    def record_get_stream(self, hops: int, chunks: int) -> None:
+        with self._lock:
+            self.get_stream_hops += hops
+            self.get_stream_chunks += chunks
+
     # -- exposition ----------------------------------------------------------
 
     def render(self) -> str:
@@ -133,11 +142,16 @@ class MetricsSys:
             calls = dict(self.api_calls)
             errs = dict(self.api_errors)
             rx, tx = self.bytes_received, self.bytes_sent
+            hops, chunks = self.get_stream_hops, self.get_stream_chunks
 
         metric("minio_tpu_uptime_seconds", round(time.time() - self.start_time, 1),
                help_="Server uptime.", type_="gauge")
         metric("minio_tpu_s3_traffic_received_bytes", rx, help_="Total S3 bytes received.")
         metric("minio_tpu_s3_traffic_sent_bytes", tx, help_="Total S3 bytes sent.")
+        metric("minio_tpu_s3_get_stream_hops_total", hops,
+               help_="Thread hops streamed GET responses took to pull their read windows.")
+        metric("minio_tpu_s3_get_stream_chunks_total", chunks,
+               help_="Chunks streamed GET responses wrote to their sockets.")
         lines.append("# HELP minio_tpu_http_requests_total HTTP requests by method/status.")
         lines.append("# TYPE minio_tpu_http_requests_total counter")
         helped.add("minio_tpu_http_requests_total")

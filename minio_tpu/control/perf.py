@@ -72,13 +72,16 @@ STAGES: frozenset = frozenset({
     ("api", "body-read"),
     ("api", "response-write"),
     # Where a streamed request waits (direct records, one per request or
-    # per window): the body reader's hops to the event loop, the payload
-    # digests, one window's fill; a GET's pull from the read generator and
-    # its socket writes (their sum is response-write).
+    # per window): the body reader's waits for the next body chunk, the
+    # payload digests, one window's fill; a GET's pulls from the read stream
+    # (pull-start: their part before the worker thread has the pull in
+    # hand) and its socket writes (stream-pull + socket-write is
+    # response-write).
     ("api", "body-hop"),
     ("api", "payload-hash"),
     ("api", "body-fill"),
     ("api", "stream-pull"),
+    ("api", "pull-start"),
     ("api", "socket-write"),
     # object/erasure.py + object/multipart.py data-path stages
     ("object", "encode"),

@@ -28,7 +28,7 @@ import threading
 import time
 import uuid
 from collections import deque
-from typing import Iterator
+from typing import Callable, Iterator
 
 from ..chaos import crash
 from ..control import tracing
@@ -301,8 +301,12 @@ class _ReadaheadWindows:
         raise StopIteration
 
     def close(self) -> None:
-        """Stop the stager, recycle queued windows, join the thread."""
+        """Stop the stager, join the thread, recycle queued windows. The
+        join comes first: a put waiting for room sees the stop within its
+        50 ms poll and recycles its own window, so nothing is queued behind
+        the drain."""
         self._stop.set()
+        self._t.join(timeout=10)
         try:
             while True:
                 kind, val = self._q.get_nowait()
@@ -310,7 +314,6 @@ class _ReadaheadWindows:
                     val.release()
         except _queue.Empty:
             pass
-        self._t.join(timeout=10)
         closer = getattr(self._src, "close", None)
         if closer is not None:
             closer()
@@ -427,8 +430,10 @@ class _GetStager:
         raise StopIteration
 
     def close(self) -> None:
-        """Stop the stager, recycle queued windows, join the thread."""
+        """Stop the stager, join the thread, recycle queued windows (in
+        that order, as _ReadaheadWindows.close() says)."""
         self._stop.set()
+        self._t.join(timeout=10)
         try:
             while True:
                 kind, val = self._q.get_nowait()
@@ -436,10 +441,67 @@ class _GetStager:
                     val[1]()
         except _queue.Empty:
             pass
-        self._t.join(timeout=10)
         closer = getattr(self._src, "close", None)
         if closer is not None:
             closer()
+
+
+def _window_batches(units) -> "Iterator[list]":
+    """(chunks, close) units -> one list of chunks per window. The window's
+    close() runs when the consumer asks past it, drops the stream, or the
+    read fails: exactly once, whichever comes first. The yielded list is a
+    copy the frame does not keep, so only the consumer's own references
+    keep a chunk's storage exported when close() probes it."""
+    try:
+        for chunks, close in units:
+            try:
+                yield chunks[:]
+            finally:
+                close()
+    finally:
+        units.close()
+
+
+class _WindowStream:
+    """The chunk iterator get_object_stream returns for a windowed layout.
+
+    Iterating yields single chunks, as the generator it replaces did.
+    next_batch() hands over every chunk of the next read window in one call
+    ([] at the end of the stream) for a consumer that pays per call -- the
+    S3 front crosses to a thread once per batch; a consumer takes one way
+    or the other, not both. Either way, asking past a window's last chunk
+    recycles that window's pooled buffers: the consumer must be done with
+    the views it was handed before it asks for more. close() (or dropping
+    the stream) recycles the window in hand and stops the read-ahead. A
+    layer that wraps the stream forwards next_batch() and close(): behind a
+    plain generator the front falls back to byte-bounded batches, which may
+    straddle two windows, and a window closed while its views are held has
+    its buffers discarded, not pooled."""
+
+    __slots__ = ("_batches", "_cur")
+
+    def __init__(self, units):
+        self._batches = _window_batches(units)
+        self._cur: deque = deque()  # what single-chunk iteration has left
+
+    def __iter__(self) -> "_WindowStream":
+        return self
+
+    def __next__(self):
+        while not self._cur:
+            self._cur.extend(next(self._batches))
+        return self._cur.popleft()
+
+    def next_batch(self) -> list:
+        assert not self._cur, "a window is half iterated: next() and next_batch() do not mix"
+        for batch in self._batches:
+            if batch:
+                return batch
+        return []
+
+    def close(self) -> None:
+        self._cur.clear()
+        self._batches.close()
 
 
 def data_windows(data) -> "Iterator[_Window]":
@@ -1543,13 +1605,14 @@ class ErasureObjects:
             m is not None and m.inline_data for m in metas_by_shard
         )
 
+        whole = _whole_layout(metas)
         stream_range = (
-            self._stream_part_range_whole
-            if _whole_layout(metas)
-            else self._stream_part_range
+            self._stream_part_range_whole if whole else self._stream_part_range
         )
 
-        def gen() -> Iterator[bytes]:
+        def gen() -> Iterator:
+            """Per part: chunks of a legacy whole-file part, (chunks, close)
+            window units of the framed layout."""
             abs_pos = 0
             for part in fi.parts:
                 p_lo = max(offset - abs_pos, 0)
@@ -1563,7 +1626,7 @@ class ErasureObjects:
                 if abs_pos >= end:
                     return
 
-        return oi, gen()
+        return oi, gen() if whole else _WindowStream(gen())
 
     def _stream_part_range(
         self,
@@ -1576,8 +1639,10 @@ class ErasureObjects:
         inline: bool,
         lo: int,
         hi: int,
-    ) -> Iterator[bytes]:
-        """Decode part-local byte range [lo, hi), group by group."""
+    ) -> "Iterator[tuple[list, Callable[[], None]]]":
+        """Decode part-local byte range [lo, hi), group by group: one
+        (chunks, close) unit per read window, close() owed by whoever takes
+        the unit (_WindowStream, for get_object_stream's callers)."""
         k = fi.erasure.data_blocks
         mth = fi.erasure.parity_blocks
         chunk_full = -(-BLOCK_SIZE // k)
@@ -1809,17 +1874,8 @@ class ErasureObjects:
         depth = int(os.environ.get("MTPU_GET_READAHEAD", "1"))
         it = _GetStager(windows(), depth) if depth > 0 else windows()
         try:
-            for chunks, close in it:
-                try:
-                    # pop() so this frame never pins a yielded view: by the
-                    # time close() runs, only the consumer's own references
-                    # (if any) keep a chunk's storage exported.
-                    while chunks:
-                        yield chunks.pop(0)
-                finally:
-                    # Runs when the consumer asks past the window's last
-                    # chunk or tears down mid-window.
-                    close()
+            for unit in it:
+                yield unit
         finally:
             closer = getattr(it, "close", None)
             if closer is not None:

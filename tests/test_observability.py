@@ -443,8 +443,11 @@ class TestRequestWaitStages:
     def test_stream_pull_and_socket_write_sum_to_response_write(self, cluster):
         client = cluster["clients"][0]
         assert client.put_object("obs", "halves.bin", self.BODY).status_code == 200
-        rows = [("api", "response-write"), ("api", "stream-pull"), ("api", "socket-write")]
+        rows = [("api", "response-write"), ("api", "stream-pull"), ("api", "socket-write"),
+                ("api", "pull-start")]
         before = {r: _ledger_row(*r) for r in rows}
+        metrics = cluster["nodes"][0].metrics
+        hops0, chunks0 = metrics.get_stream_hops, metrics.get_stream_chunks
         got = client.get_object("obs", "halves.bin")
         assert got.status_code == 200 and got.content == self.BODY
         # The client has its last byte before the server closes the span.
@@ -458,8 +461,19 @@ class TestRequestWaitStages:
              for r in rows}
         assert d["stream-pull"]["count"] == d["socket-write"]["count"] == 1
         assert d["response-write"]["count"] == 1
+        # pull-start is the part of the pulls before a worker thread had them.
+        assert d["pull-start"]["count"] == 1
+        assert 0 < d["pull-start"]["sum"] <= d["stream-pull"]["sum"]
         assert d["stream-pull"]["sum"] + d["socket-write"]["sum"] == pytest.approx(
             d["response-write"]["sum"], rel=0.1, abs=0.005)
+        # stream-pull is one wait per read window, not per chunk: the one
+        # window's hop and the hop that finds the end, for the one block's
+        # K row views.
+        assert metrics.get_stream_hops - hops0 == 2
+        assert metrics.get_stream_chunks - chunks0 == 4
+        text = metrics.render_node()
+        assert f"minio_tpu_s3_get_stream_hops_total {metrics.get_stream_hops}" in text
+        assert f"minio_tpu_s3_get_stream_chunks_total {metrics.get_stream_chunks}" in text
 
 
 class TestProcessWatch:

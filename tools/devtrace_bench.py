@@ -10,8 +10,9 @@ under OUT_DIR and named `<workload>.s<seed>`:
   .devtrace.json  control/devtrace.py's reduction of the traced slice: the
                   device's busy/idle, seconds per named program, and the
                   longest idle gaps with the host stages overlapping each
-  .ledger.json    every stage-ledger row and codec counter differenced over
-                  the window, the window's facts and its end-to-end numbers
+  .ledger.json    every stage-ledger row, codec counter and S3-front counter
+                  (get_stream_hops, get_stream_chunks) differenced over the
+                  window, the window's facts and its end-to-end numbers
   .xplane.pb      the raw trace, with --keep-xplane (tens of MB)
 
 On the chip: `chiprun -- python3 tools/devtrace_bench.py chiprun_out/dt --workload ...`.
@@ -28,8 +29,13 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 
+# Counters of minio_tpu.control.metrics.MetricsSys kept beside the codec's.
+FRONT_COUNTERS = ("get_stream_hops", "get_stream_chunks")
+
+
 def main(argv: list[str]) -> int:
     from benchmark.harness import run as bench_run
+    from benchmark.harness import server as bench_server
     from benchmark.harness import trace as bench_trace
     from minio_tpu.control import devtrace
 
@@ -50,6 +56,15 @@ def main(argv: list[str]) -> int:
             shutil.copy(path, base + ".xplane.pb")
         return path
 
+    snapshot = bench_server.Deployment.snapshot
+
+    def snapshot_with_front(dep) -> dict:
+        snap = snapshot(dep)
+        metrics = getattr(dep.node, "metrics", None)
+        snap["front"] = {k: getattr(metrics, k) for k in FRONT_COUNTERS
+                         if hasattr(metrics, k)}
+        return snap
+
     result_line = bench_run.result_line
 
     def result_line_and_keep(cell, out, *a, **kw):
@@ -60,11 +75,14 @@ def main(argv: list[str]) -> int:
             rows[row] = {k: h[k] - was.get(k, 0) for k in ("count", "wall_s", "cpu_s")}
         counters = {k: v - before["codec"].get(k, 0) for k, v in after["codec"].items()
                     if isinstance(v, (int, float))}
+        front = {k: v - before["front"].get(k, 0) for k, v in after["front"].items()}
         with open(base + ".ledger.json", "w") as f:
             json.dump({"window_s": after["t"] - before["t"], "facts": out["src"]["facts"],
-                       "e2e": out["e2e"], "ledger": rows, "codec": counters}, f, indent=1)
+                       "e2e": out["e2e"], "ledger": rows, "codec": counters,
+                       "front": front}, f, indent=1)
         return result_line(cell, out, *a, **kw)
 
+    bench_server.Deployment.snapshot = snapshot_with_front
     bench_trace.find_xplane = find_and_keep
     bench_run.result_line = result_line_and_keep
     return bench_run.main(rest + ["--trace", "1"])
