@@ -14,21 +14,36 @@ window itself wrote:
                           one acknowledged always, is read back whole and
                           compared (sha256; 404 for a deleted key)
     degraded_mismatch     a sample, drawn from the seed, of the objects PUT in
-                          the window, the last one acknowledged in it, has as
-                          many data shards removed from its drives as the
+                          the window, the last one acknowledged in it, is
+                          brought to as many missing data shards as the
                           configuration says may be lost, and is read back:
                           every byte then comes through the parity the device
-                          wrote and the device's reconstruct program
+                          wrote and the device's reconstruct program. A run
+                          that wrote nothing (no PUT or DELETE sent from ramp
+                          to drain) over a traffic file with a `populate` step
+                          draws the sample the same way from the populated
+                          pool: each client holds the sha256 of what
+                          `populate` put. The run's log says which pool a
+                          sample was drawn from
     degraded_short        objects the sample should have held (the size asked
-                          for, or every object PUT in the window where there
-                          are fewer, and never under one) less objects checked
+                          for, or every object of the pool it was drawn from
+                          where there are fewer, and never under one) less
+                          objects checked. A run with neither a PUT in the
+                          window nor a populated pool reads 1
     device_blocks_missing full blocks the clients' acknowledged PUTs held less
-                          the blocks the device codec counted as encoded (never
-                          below 0): the device, not the host codec, did the work
+                          the blocks the device codec counted as encoded, plus
+                          full blocks of the acknowledged GETs, ramp to drain,
+                          of keys the `prepare` step degraded less the blocks
+                          the codec counted as reconstructed (each difference
+                          never below 0): the device, not the host codec, did
+                          the work. Nothing is added where `prepare` lost no
+                          shards
 
 Every comparison is exact, so every limit is 0. The control (one parity shard
 fewer than the configuration states, which a run cannot tell from the outside
-until drives are lost) reads ``degraded_mismatch`` = the sample size.
+until drives are lost) reads ``degraded_mismatch`` = the sample size; where the
+`prepare` step lost as many data shards as the configuration tolerates, every
+GET of the window fails too (``ops_failed``).
 """
 
 from __future__ import annotations
@@ -79,9 +94,36 @@ def degraded_sample(ops: list, t0: float, t1: float, seed: int, n: int) -> list[
     return _draw([op[window.KEY] for op in live], seed, n)
 
 
+def wrote_nothing(ops: list) -> bool:
+    """No PUT or DELETE was sent from ramp to drain, acknowledged or not:
+    every key of a populated pool still holds what `populate` put."""
+    return not any(op[window.KIND] in ("PUT", "DELETE") for op in ops)
+
+
+def pool_sample(pool_keys: list[str], seed: int, n: int) -> list[str]:
+    """The degraded sample of a window that wrote nothing: drawn from the keys
+    `populate` put (sorted, so the seed alone decides)."""
+    return _draw(sorted(pool_keys), seed, n)
+
+
 def full_blocks_put(ops: list, block_bytes: int) -> int:
     return sum(op[window.NBYTES] // block_bytes for op in ops
                if op[window.OK] and op[window.KIND] == "PUT")
+
+
+def degraded_blocks_got(ops: list, degraded: set[str], block_bytes: int) -> int:
+    """Full blocks of the acknowledged GETs of keys the prepare step degraded,
+    ramp to drain. A key counts until its client first sends a PUT or DELETE
+    of it (a key's ops are in order: one client owns it): what is written
+    again is whole again."""
+    never = float("inf")
+    rewritten: dict[str, float] = {}
+    for op in ops:
+        if op[window.KIND] in ("PUT", "DELETE") and op[window.KEY] in degraded:
+            rewritten[op[window.KEY]] = min(rewritten.get(op[window.KEY], never), op[window.START])
+    return sum(op[window.NBYTES] // block_bytes for op in ops
+               if op[window.OK] and op[window.KIND] == "GET" and op[window.KEY] in degraded
+               and op[window.END] <= rewritten.get(op[window.KEY], never))
 
 
 def decide(numbers: dict[str, float]) -> tuple[bool, dict]:

@@ -14,14 +14,19 @@ beyond its own counters and ledger:
     src["trace"]   = trace.reduce(...) of the traced sub-window, or {}
     src["facts"]   = numbers of the window the harness counted itself
     src["geometry"] = (K, M, shard length); src["block_bytes"]; src["device_kind"]
+    src["lost_data"] = data shards the traffic file's `prepare` removed per object
 
 kinds:
     ledger   rows (layer/stage) summed, field wall_s|cpu_s|count, times scale,
              over a fact (per) or 1
-    counter  numerator / denominator, each a list of codec counters or
-             snapshot counters summed and differenced over the window; a
-             missing denominator is 1
-    trace    value: idle_share | codec_ms_per_GiB | codec_roofline
+    counter  numerator / denominator, each a list of codec counters, S3-front
+             counters or snapshot counters summed and differenced over the
+             window; a missing denominator is 1
+    trace    value: idle_share | codec_ms_per_GiB | codec_roofline (the codec
+             programs' device time over the blocks encoded in the traced
+             slice) | recon_ms_per_GiB | recon_roofline (the same over the
+             blocks reconstructed there: in a cell whose window writes nothing
+             every program found by the shard length is the reconstruct program)
     process  value: a fact by name (client_cpu, stored_per_user_byte, warmup_s)
 """
 
@@ -58,10 +63,11 @@ def _delta(pair: tuple[dict, dict], group: str, name: str, field: str | None = N
 
 
 def _counter(pair: tuple[dict, dict], name: str) -> float | None:
-    """A codec counter, or a counter of the snapshot itself (compiles,
-    cache_entries), differenced."""
-    if name in pair[1]["codec"]:
-        return _delta(pair, "codec", name)
+    """A codec counter, a counter of the S3 front, or a counter of the
+    snapshot itself (compiles, cache_entries), differenced."""
+    for group in ("codec", "front"):
+        if name in pair[1].get(group, {}):
+            return _delta(pair, group, name)
     if name in pair[1] and isinstance(pair[1][name], (int, float)):
         return pair[1][name] - pair[0][name]
     return None
@@ -102,17 +108,24 @@ def read_trace(reader: dict, src: dict) -> float | None:
     value = reader["value"]
     if value == "idle_share":
         return 100.0 * (1.0 - tr["busy_s"] / tr["span_s"])
-    blocks = _counter(src["traced"], "blocks_encoded")
+    if value not in ("codec_ms_per_GiB", "codec_roofline", "recon_ms_per_GiB", "recon_roofline"):
+        raise ValueError(f"unknown trace value {value!r}")
+    recon = value.startswith("recon")
+    blocks = _counter(src["traced"], "blocks_reconstructed" if recon else "blocks_encoded")
     if not blocks or not tr.get("codec_s"):
-        return None  # no full block was encoded, or no codec program was found
+        return None  # no full block went through, or no codec program was found
     k, m, shard_len = src["geometry"]
-    if value == "codec_ms_per_GiB":
+    if value.endswith("_ms_per_GiB"):
         user_gib = blocks * src["block_bytes"] / GIB
         return tr["codec_s"] * 1e3 / user_gib
-    if value == "codec_roofline":
+    if recon:
+        if not src.get("lost_data"):
+            return None  # the traffic file lost no shards: nothing says how many rows a block rebuilt
+        least, _bound = roofline.recon_least_seconds(
+            int(blocks), k, int(src["lost_data"]), shard_len, src["device_kind"])
+    else:
         least, _bound = roofline.least_seconds(int(blocks), k, m, shard_len, src["device_kind"])
-        return 100.0 * least / tr["codec_s"]
-    raise ValueError(f"unknown trace value {value!r}")
+    return 100.0 * least / tr["codec_s"]
 
 
 def read_process(reader: dict, src: dict) -> float | None:

@@ -43,6 +43,13 @@ def block_bytes_moved(data: int, parity: int, shard_len: int, chunks: int = 1) -
     return read + written
 
 
+def recon_bytes_moved(data: int, rebuilt: int, shard_len: int, digests: bool) -> int:
+    """HBM bytes the reconstruct of one full block needs at the least: K
+    surviving shards read once, `rebuilt` shards written, and one digest per
+    rebuilt shard where the caller asks for them (heal does, a GET does not)."""
+    return data * shard_len + rebuilt * shard_len + (DIGEST_BYTES * rebuilt if digests else 0)
+
+
 def block_gf_ops(data: int, parity: int, shard_len: int) -> int:
     """GF(2^8) multiply-adds of the parity rows: M x K per shard byte. Not the
     bound on a v5e (see least_seconds): kept so that the figure is on record."""
@@ -59,4 +66,15 @@ def least_seconds(blocks: int, data: int, parity: int, shard_len: int,
     p = peaks(device_kind)
     t_hbm = blocks * block_bytes_moved(data, parity, shard_len) / p["hbm_bytes_per_s"]
     t_ops = blocks * block_gf_ops(data, parity, shard_len) / p["int8_ops_per_s"]
+    return (t_hbm, "hbm") if t_hbm >= t_ops else (t_ops, "int8")
+
+
+def recon_least_seconds(blocks: int, data: int, rebuilt: int, shard_len: int,
+                        device_kind: str) -> tuple[float, str]:
+    """The same for a GET's reconstruct (no digests) of `rebuilt` rows of each
+    of `blocks` full blocks: rebuilt x K multiply-adds per shard byte against
+    the bytes of recon_bytes_moved."""
+    p = peaks(device_kind)
+    t_hbm = blocks * recon_bytes_moved(data, rebuilt, shard_len, False) / p["hbm_bytes_per_s"]
+    t_ops = blocks * block_gf_ops(data, rebuilt, shard_len) / p["int8_ops_per_s"]
     return (t_hbm, "hbm") if t_hbm >= t_ops else (t_ops, "int8")

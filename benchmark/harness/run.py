@@ -119,6 +119,7 @@ def run_window(cell: Cell, dep: Deployment, clients: Clients, seed: int, seconds
     while the codec warms up) and are stopped here. Returns the numbers; prints
     the log lines."""
     t = cell.traffic
+    degraded: set[str] = set()  # keys the prepare step left short of data shards
     try:
         ready = clients.gather()
         say(f"{len(ready)} clients ready; slowest body preparation "
@@ -132,12 +133,11 @@ def run_window(cell: Cell, dep: Deployment, clients: Clients, seed: int, seconds
                                      f"{[e for d in done for e in d['errors']][:3]}")
                 say(f"populated {sum(d['populated'] for d in done)} objects")
             elif "lose_shards" in step:
-                n = 0
-                for i in range(cell.clients):
-                    for key in client_keys(t["keys"], i, cell.clients):
-                        dep.lose_shards(key, int(step["lose_shards"]["data"]))
-                        n += 1
-                say(f"removed {step['lose_shards']['data']} data shards of {n} objects")
+                for key in pool_keys(cell):
+                    dep.lose_shards(key, int(step["lose_shards"]["data"]))
+                    degraded.add(key)
+                say(f"removed {step['lose_shards']['data']} data shards of "
+                    f"{len(degraded)} objects")
 
         before = dep.snapshot()
         go = time.monotonic() + 0.25
@@ -183,7 +183,8 @@ def run_window(cell: Cell, dep: Deployment, clients: Clients, seed: int, seconds
         say(f"server process used {(cpu_b - cpu_a) / (b - a):.2f} cores over the window "
             f"({len(os.sched_getaffinity(0))} cpus to run on)")
 
-        numbers, stored, live = run_check(cell, dep, clients, ops, (a, b), seed, before, after)
+        numbers, stored, live = run_check(cell, dep, clients, ops, (a, b), seed, before, after,
+                                          degraded)
     finally:
         clients.close()
 
@@ -192,11 +193,14 @@ def run_window(cell: Cell, dep: Deployment, clients: Clients, seed: int, seconds
     e2e["setup_s"] = setup_s
     inside = window.ended_inside(ops, a, b)
     puts = [op for op in ops if op[window.KIND] == "PUT"]
+    gets = [op for op in ops if op[window.KIND] == "GET"]
     user_live = live * int(t["object_bytes"])
     facts = {
         "ops_ended": len(inside),
         "puts_ended": len([op for op in inside if op[window.KIND] == "PUT"]),
         "put_MiB": window.prorated_rate(puts, a, b, by_bytes=True) * (b - a) / window.MIB,
+        "gets_ended": len([op for op in inside if op[window.KIND] == "GET"]),
+        "get_MiB": window.prorated_rate(gets, a, b, by_bytes=True) * (b - a) / window.MIB,
         "client_cpu": (sum(r["cpu_s"] for r in results)
                        / (sum(r["cpu_wall_s"] for r in results) / len(results))),
         "stored_per_user_byte": stored / user_live if user_live else None,
@@ -204,13 +208,15 @@ def run_window(cell: Cell, dep: Deployment, clients: Clients, seed: int, seconds
     }
     src = {"window": (snap_a, snap_b), "traced": traced_pair, "trace": {}, "facts": facts,
            "geometry": cell.geometry, "block_bytes": cell.config["block_bytes"],
-           "device_kind": device["kind"]}
+           "device_kind": device["kind"], "lost_data": cell.lost_data}
     if traced:
         xplane = trace.find_xplane(TRACE_DIR)
         if describe_to:
             with open(describe_to, "w") as f:
                 f.write("\n".join(trace.describe(xplane)) + "\n")
-        src["trace"] = reduced = trace.reduce(trace.load(xplane), cell.geometry[2])
+        src["trace"] = reduced = trace.reduce(
+            trace.load(xplane), cell.geometry[2], wall_s=trace_wall,
+            idle_chips=device["count"] if device["platform"] != "cpu" else 0)
         if reduced:
             reduced["span_s"] = trace_wall  # the host's clock frames the traced window
             device["busy_s"], device["window_s"] = reduced["busy_s"], trace_wall
@@ -224,10 +230,18 @@ def run_window(cell: Cell, dep: Deployment, clients: Clients, seed: int, seconds
     }
 
 
+def pool_keys(cell: Cell) -> list[str]:
+    """Every key of the cell's ring or pool, client by client."""
+    return [key for i in range(cell.clients)
+            for key in client_keys(cell.traffic["keys"], i, cell.clients)]
+
+
 def run_check(cell: Cell, dep: Deployment, clients: Clients, ops: list,
-              win: tuple[float, float], seed: int, before: dict, after: dict):
+              win: tuple[float, float], seed: int, before: dict, after: dict,
+              degraded: set[str]):
     """The comparison that decides `correct` (harness/check.py), once the window
-    has closed: (numbers compared, bytes on the drives, live objects)."""
+    has closed: (numbers compared, bytes on the drives, live objects).
+    `degraded` are the keys the prepare step left short of data shards."""
     t, (a, b) = cell.traffic, win
     t_check = time.monotonic()
     numbers: dict[str, float] = {"ops_failed": sum(1 for op in ops if not op[window.OK])}
@@ -238,22 +252,36 @@ def run_check(cell: Cell, dep: Deployment, clients: Clients, ops: list,
         ops, seed, int(chk.get("readback_sample", 12))))
     numbers["readback_mismatch"] = sum(v["mismatches"] for v in back)
     read_back = sum(v["verified"] for v in back)
-    sample = check.degraded_sample(ops, a, b, seed, int(chk.get("degraded_sample", 4)))
+    n_degraded = int(chk.get("degraded_sample", 4))
+    sample, drawn_from = check.degraded_sample(ops, a, b, seed, n_degraded), "PUT in the window"
+    if check.wrote_nothing(ops) and cell.populates:
+        sample, drawn_from = check.pool_sample(pool_keys(cell), seed, n_degraded), "populated pool"
     lost_all = int(cell.config["guarantees"]["drives_lost_tolerated"])
     for key in sample:
         dep.lose_shards(key, lost_all)
     deg = verify_keys(clients, sample)
     numbers["degraded_mismatch"] = sum(v["mismatches"] for v in deg)
     numbers["degraded_short"] = max(1, len(sample)) - sum(v["verified"] for v in deg)
-    sent_blocks = check.full_blocks_put(ops, cell.config["block_bytes"])
-    encoded = after["codec"].get("blocks_encoded", 0) - before["codec"].get("blocks_encoded", 0)
-    numbers["device_blocks_missing"] = max(0, sent_blocks - encoded)
+    block = cell.config["block_bytes"]
+    codec = {name: after["codec"].get(name, 0) - before["codec"].get(name, 0)
+             for name in ("blocks_encoded", "blocks_reconstructed", "host_fallback_recon_blocks")}
+    sent_blocks = check.full_blocks_put(ops, block)
+    got_blocks = check.degraded_blocks_got(ops, degraded, block)
+    numbers["device_blocks_missing"] = (
+        max(0, sent_blocks - codec["blocks_encoded"])
+        + max(0, got_blocks - codec["blocks_reconstructed"]))
     for v in back + deg:
         for e in v["mismatch"]:
             say(f"check: {e}")
     say(f"check took {time.monotonic() - t_check:.2f}s: read back {read_back} keys, "
-        f"{len(sample)} more with {lost_all} data shards removed; device encoded "
-        f"{encoded} blocks, clients' acknowledged PUTs held {sent_blocks}")
+        f"{len(sample)} more ({drawn_from}) with {lost_all} data shards removed; device "
+        f"encoded {codec['blocks_encoded']} blocks, clients' acknowledged PUTs held "
+        f"{sent_blocks}")
+    if degraded:
+        say(f"device reconstructed {codec['blocks_reconstructed']} blocks ramp to drain "
+            f"(blocks_reconstructed), acknowledged GETs of the {len(degraded)} keys the prepare "
+            f"step degraded held {got_blocks}; host_fallback_recon_blocks moved by "
+            f"{codec['host_fallback_recon_blocks']}")
     say(f"drives hold {stored} bytes under {dep.root} for {live} live objects; "
         f"{shutil.disk_usage(SHM).free} bytes free on {SHM}")
     return numbers, stored, live

@@ -25,6 +25,8 @@ ACCESS, SECRET, REGION = "benchadmin", "bench-secret-key-0001", "us-east-1"
 BUCKET = "bench"
 SHM = "/dev/shm"
 PREFIX = "mtpu-bench-"
+# Counters of the S3 front (minio_tpu.control.metrics.MetricsSys) a snapshot carries.
+FRONT_COUNTERS = ("get_stream_hops", "get_stream_chunks")
 # Env that would move the server off the deployment a configuration pins
 # (chip_smoke.py's list); a configuration's own `env` is applied after.
 PINNED_ENV = (
@@ -169,8 +171,9 @@ class Deployment:
     # -- sources the per-layer readers take ----------------------------------------
 
     def snapshot(self) -> dict:
-        """Ledger, codec counters, compile-cache entries and the clock, at one
-        moment. Two of these are differenced into a window's per-layer numbers."""
+        """Ledger, codec counters, the S3 front's counters, compile-cache
+        entries and the clock, at one moment. Two of these are differenced
+        into a window's per-layer numbers."""
         from minio_tpu import jaxenv
         from minio_tpu.control.perf import GLOBAL_PERF
         from minio_tpu.object import codec as codec_mod
@@ -189,6 +192,8 @@ class Deployment:
             "t": time.monotonic(),
             "ledger": ledger,
             "codec": dict(stats_fn()) if stats_fn else {},
+            "front": {name: getattr(self.node.metrics, name) for name in FRONT_COUNTERS
+                      if hasattr(self.node.metrics, name)},
             "cache_entries": cache,
             "compiles": len(self.compile_events),
         }
@@ -213,15 +218,20 @@ class Deployment:
                 for i in range(self.drives)]
 
     def lose_shards(self, key: str, data: int) -> list[str]:
-        """Remove `data` data shards of an object from its drives. A directory
-        that is not there is an error: the object was not stored where the
-        placement says."""
+        """Bring an object to `data` missing data shards: the victims are the
+        first `data` drives, in drive order, that hold a data row, so a second
+        call finds what the first removed and removes nothing more. Any other
+        directory of the object that is not there is an error: the object was
+        not stored where the placement says. Returns the victims."""
         k = self.drives - self.parity
-        victims = [d for row, d in self.shard_dirs(key) if row < k][:data]
-        for d in victims:
-            if not os.path.isdir(d):
+        dirs = self.shard_dirs(key)
+        victims = [d for row, d in dirs if row < k][:data]
+        for _, d in dirs:
+            if d not in victims and not os.path.isdir(d):
                 raise FileNotFoundError(f"expected shard directory {d}")
-            shutil.rmtree(d)
+        for d in victims:
+            if os.path.isdir(d):
+                shutil.rmtree(d)
         return victims
 
     def stored_bytes(self) -> int:

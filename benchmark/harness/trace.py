@@ -174,17 +174,27 @@ def top_ops(events: list, w0: int, w1: int, n: int = 10) -> list[list]:
 
 
 def reduce(devices: dict[str, dict[str, list]], shard_len: int,
-           window_ns: tuple[int, int] | None = None) -> dict:
+           window_ns: tuple[int, int] | None = None, idle_chips: int = 0,
+           wall_s: float = 0.0) -> dict:
     """The whole reduction, averaged over the devices used. `window_ns` is the
     traced window on the trace's clock; when the caller cannot place it there
     (host and trace clocks differ), the window is from the first device event
-    to the last, and `window_s` of the caller's own clock is kept beside it."""
-    if not devices:
-        return {}
+    to the last, and `window_s` of the caller's own clock is kept beside it.
+
+    A chip that ran nothing in the slice has no plane in the trace at all (a
+    healthy GET verifies on the host), so the trace cannot tell an idle chip
+    from no trace: the caller can. Where it says the process held
+    `idle_chips` accelerator chips while it traced `wall_s` seconds, a trace
+    with no device op reads busy 0 over that time and one gap, the whole
+    slice; otherwise (0: no accelerator, as in a rehearsal) it reads {}."""
     starts = [s for d in devices.values() for _, s, _, _ in d["ops"]]
     ends = [s + n for d in devices.values() for _, s, n, _ in d["ops"]]
     if not starts:
-        return {}
+        if not idle_chips:
+            return {}
+        return {"devices": idle_chips, "span_s": wall_s, "busy_s": 0.0, "codec_s": 0.0,
+                "codec_runs": 0, "device_ops": [],
+                "idle_gaps": [["window-start--window-end", wall_s]]}
     w0, w1 = window_ns or (min(starts), max(ends))
     busy_ns, codec_ns, codec_runs, gaps, all_ops = [], [], 0, [], []
     for dev in devices.values():

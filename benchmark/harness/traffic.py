@@ -150,6 +150,16 @@ class Cell:
     def clients(self) -> int:
         return int(self.traffic["clients"])
 
+    @property
+    def populates(self) -> bool:
+        return any("populate" in step for step in self.traffic.get("prepare", []))
+
+    @property
+    def lost_data(self) -> int:
+        """Data shards the `prepare` step removes from every object, or 0."""
+        return max((int(step["lose_shards"]["data"]) for step in self.traffic.get("prepare", [])
+                    if "lose_shards" in step), default=0)
+
     def footprint_bytes(self) -> int:
         """Room the drives need under /dev/shm: the traffic file states it, for
         the wider geometry of its cells (the file says why); the rehearsal's

@@ -68,3 +68,15 @@ def test_nothing_to_read_returns_nothing():
     assert trace.reduce({"d": {"ops": [], "modules": []}}, S) == {}
     out = trace.reduce({"d": {"ops": [ev("x", 0, 10)], "modules": [ev("m", 0, 10)]}}, S)
     assert out["codec_s"] == 0 and out["codec_runs"] == 0
+
+
+def test_no_device_op_on_a_chip_the_process_holds_is_an_idle_chip_not_a_missing_reading():
+    """A healthy GET verifies on the host: the trace of such a slice has no
+    device plane at all (my chip run, PR 28), and the caller says a chip was held."""
+    out = trace.reduce({}, S, idle_chips=1, wall_s=5.0)
+    assert out["busy_s"] == 0.0 and out["span_s"] == 5.0 and out["devices"] == 1
+    assert out["device_ops"] == [] and out["idle_gaps"] == [["window-start--window-end", 5.0]]
+    assert out["codec_s"] == 0 and out["codec_runs"] == 0
+    # the hint changes nothing for a slice that has ops
+    one = {"d": {"ops": OPS, "modules": MODULES}}
+    assert trace.reduce(one, S, (0, 750), idle_chips=1, wall_s=5.0) == trace.reduce(one, S, (0, 750))
