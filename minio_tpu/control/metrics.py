@@ -426,7 +426,9 @@ class MetricsSys:
                 {"kernel": kernel},
                 help_="Host-clock seconds from a batch's launch to its bytes' "
                       "arrival on the host, per kernel class; not device time "
-                      "(an admin profile with device=1 reads that from a trace).",
+                      "(an admin profile with device=1 reads that from a trace). "
+                      "kernel=encode holds full-block batches only: small "
+                      "batches are minio_tpu_codec_small_roundtrip_seconds_total.",
             )
         # The life of a full-block batch (the same measurements feed the
         # codec/* ledger rows): queueing, the workers' idle share, and the
@@ -468,6 +470,25 @@ class MetricsSys:
                    help_="Sub-block objects encoded via the coalesced small-object path.")
             metric("minio_tpu_codec_small_batches_total", st["small_batches_run"],
                    help_="Coalesced small-object device batches launched.")
+            metric("minio_tpu_codec_small_blocks_padded_total", st["small_blocks_padded"],
+                   help_="Padded slots of the small-object batches "
+                         "(small blocks encoded / this = their occupancy).")
+            metric("minio_tpu_codec_small_roundtrip_seconds_total",
+                   round(st["small_encode_seconds"], 6),
+                   help_="Host-clock seconds from a small batch's launch to its "
+                         "parity bytes' arrival on the host; not device time.")
+            metric("minio_tpu_codec_small_queue_wait_block_seconds_total",
+                   round(st["small_queue_wait_block_seconds"], 6),
+                   help_="Seconds sub-blocks sat queued before their small batch "
+                         "was packed, the hold included, summed over blocks.")
+            for state, key in (("idle", "small_worker_idle_seconds"),
+                               ("all", "small_worker_wall_seconds")):
+                metric("minio_tpu_codec_small_worker_seconds_total", round(st[key], 6),
+                       {"state": state},
+                       help_="Small-batch worker loop seconds: idle on an empty "
+                             "queue, and all.")
+            metric("minio_tpu_codec_small_user_bytes_total", st["small_user_bytes"],
+                   help_="User bytes of the sub-blocks the small-object path encoded.")
             metric("minio_tpu_codec_double_buffered_batches_total",
                    st["double_buffered_batches"],
                    help_="Encode batches whose dispatch overlapped the previous "

@@ -123,6 +123,16 @@ STAGES: frozenset = frozenset({
     ("codec", "device-wait"),
     ("codec", "d2h"),
     ("codec", "scatter"),
+    # The life of a small (sub-block, parity-only) batch on its own worker
+    # thread, beside encode-batch-small (h2d, program, d2h): idle + collect
+    # + pack + encode-batch-small + digest + scatter is that worker's wall
+    # time; small-queue-wait is the oldest request's wait, hold included.
+    ("codec", "small-queue-wait"),
+    ("codec", "small-worker-idle"),
+    ("codec", "small-collect"),
+    ("codec", "small-pack"),
+    ("codec", "small-digest"),
+    ("codec", "small-scatter"),
     # The process itself (control/procwatch.py): one record per garbage
     # collection, per late event-loop heartbeat, per GIL-probe tick.
     ("runtime", "gc-pause"),

@@ -27,6 +27,8 @@ import jax
 from . import highwayhash_jax as hhj
 from . import rs, rs_pallas
 
+_SUBLANES = 8  # rows of a TPU vector tile
+
 
 def make_step(encode_all_fn, hash_fn, name: str = "mtpu_encode_hash"):
     """Compose an encode-all fn and a digest fn into one fused step.
@@ -43,8 +45,16 @@ def make_step(encode_all_fn, hash_fn, name: str = "mtpu_encode_hash"):
         with jax.named_scope("mtpu.rs_encode"):
             all_shards = encode_all_fn(data_shards)
         b, t, s = all_shards.shape
+        rows = all_shards.reshape(b * t, s)
+        if t < _SUBLANES:
+            # Fewer shard rows than a tile has sublanes (2+2): the TPU
+            # compiler folds this reshape into the hash's packet transform
+            # and pads the [B, T, S] intermediate beyond the chip's memory
+            # ([64, 4, 524288] wants 18 GB, and 100 s to compile). Behind a
+            # barrier the two halves compile as they do alone.
+            rows = jax.lax.optimization_barrier(rows)
         with jax.named_scope("mtpu.hh256"):
-            digests = hash_fn(all_shards.reshape(b * t, s)).reshape(b, t, 32)
+            digests = hash_fn(rows).reshape(b, t, 32)
         return all_shards, digests
 
     step.__name__ = step.__qualname__ = name

@@ -105,6 +105,9 @@ class _Request:
 class _SmallRequest:
     block: bytes  # raw sub-window block (bytes or memoryview)
     future: Future
+    # As _Request.enqueued: codec/small-queue-wait is the pack's start less
+    # this, for the oldest request of a batch (the hold included).
+    enqueued: float = field(default_factory=_time.perf_counter)
 
 
 class BatchingDeviceCodec(BlockCodec):
@@ -156,6 +159,16 @@ class BatchingDeviceCodec(BlockCodec):
         self.small_blocks_encoded = 0
         self.small_batches_run = 0
         self.small_blocks_padded = 0
+        # The life of a small batch, from the measurements that feed the
+        # codec/small-* ledger rows: the round trip of the parity program
+        # (launch to parity bytes on the host), how long sub-blocks sat
+        # queued (summed over blocks, the hold included), the small workers'
+        # idle time out of their whole loop time, and the user bytes.
+        self.small_encode_seconds = 0.0
+        self.small_queue_wait_block_seconds = 0.0
+        self.small_worker_idle_seconds = 0.0
+        self.small_worker_wall_seconds = 0.0
+        self.small_user_bytes = 0
         # Batches whose device->host transfer overlapped the next batch's
         # compute (the worker's one-deep pending slot engaged).
         self.double_buffered_batches = 0
@@ -166,7 +179,9 @@ class BatchingDeviceCodec(BlockCodec):
         # Round-trip seconds per kernel class by the HOST clock: launch to
         # the bytes' arrival on the host, queueing behind the batch before
         # included. Not device time (control/devtrace.py reads that from a
-        # profiler trace).
+        # profiler trace). device_encode_seconds holds full-block batches
+        # only (/ batches_run = a full batch's round trip); small batches
+        # have small_encode_seconds.
         self.device_encode_seconds = 0.0
         self.device_recon_seconds = 0.0
         self.device_verify_seconds = 0.0
@@ -478,49 +493,73 @@ class BatchingDeviceCodec(BlockCodec):
         q = self._queues[key]
         pipe = self._pipelines[(k, m)]
         window = self.small_wait_s or 0.0
+        mark = _time.perf_counter()
         while not self._stop.is_set():
-            try:
-                first = q.get(timeout=0.1)
-            except queue.Empty:
-                continue
-            self._run_small_batch(pipe, k, m, self._collect(q, first, window))
+            with tracing.stage("small-worker-idle", "codec") as idle:
+                try:
+                    first = q.get(timeout=0.1)
+                except queue.Empty:
+                    first = None
+            if first is not None:
+                with tracing.stage("small-collect", "codec"):
+                    batch = self._collect(q, first, window)
+                self._run_small_batch(pipe, k, m, batch)
+            now = _time.perf_counter()
+            with self._stats_lock:
+                self.small_worker_idle_seconds += idle.wall
+                self.small_worker_wall_seconds += now - mark
+            mark = now
 
     def _run_small_batch(self, pipe: ErasurePipeline, k: int, m: int, batch: list[_SmallRequest]) -> None:
+        """One small batch on its worker thread, stage by stage: small-pack,
+        encode-batch-small (h2d, the parity program, d2h), small-digest,
+        small-scatter. With small-worker-idle and small-collect they are the
+        worker's wall time."""
         try:
-            datas = [np.frombuffer(req.block, dtype=np.uint8) for req in batch]
-            shard_lens = [rs_matrix.shard_size(d.size, k) for d in datas]
-            # Pad the shard BYTE axis, not the block: GF(2^8) is per byte
-            # position, so parity[:, :true_len] of the padded batch is
-            # bit-identical to encoding at true length. (Padding the block
-            # itself would change ceil(len/k) and thus the parity bytes.)
-            s_pad = _len_bucket(max(shard_lens))
-            b_real = len(batch)
-            b_pad = _bucket(b_real)
-            arr = np.zeros((b_pad, k, s_pad), dtype=np.uint8)
-            for i, d in enumerate(datas):
-                arr[i, :, : shard_lens[i]] = rs_matrix.split(d, k)
+            with tracing.stage("small-pack", "codec"):
+                t_pack = _time.perf_counter()
+                waits = [t_pack - req.enqueued for req in batch]
+                GLOBAL_PERF.ledger.record("codec", "small-queue-wait", max(waits))
+                datas = [np.frombuffer(req.block, dtype=np.uint8) for req in batch]
+                shard_lens = [rs_matrix.shard_size(d.size, k) for d in datas]
+                # Pad the shard BYTE axis, not the block: GF(2^8) is per byte
+                # position, so parity[:, :true_len] of the padded batch is
+                # bit-identical to encoding at true length. (Padding the block
+                # itself would change ceil(len/k) and thus the parity bytes.)
+                s_pad = _len_bucket(max(shard_lens))
+                b_real = len(batch)
+                b_pad = _bucket(b_real)
+                arr = np.zeros((b_pad, k, s_pad), dtype=np.uint8)
+                for i, d in enumerate(datas):
+                    arr[i, :, : shard_lens[i]] = rs_matrix.split(d, k)
             with tracing.stage("encode-batch-small", "codec") as st:
                 parity = np.asarray(pipe.encode_parity(arr))  # [b_pad, M, s_pad]
-            with self._stats_lock:
-                self.device_encode_seconds += st.wall
-                self.small_batches_run += 1
-                self.small_blocks_encoded += b_real
-                self.small_blocks_padded += b_pad
-            for i, req in enumerate(batch):
-                s_i = shard_lens[i]
-                rows = np.ascontiguousarray(
-                    np.concatenate([arr[i, :, :s_i], parity[i, :, :s_i]], axis=0)
-                )  # [K+M, s_i]
+            with tracing.stage("small-digest", "codec"):
                 # Digests at TRUE length, same host hash HostCodec uses --
                 # padded-row digests would be wrong, and this keeps the
                 # result bit-identical to the host fallback.
-                digs = self._host._digests(rows)
-                req.future.set_result(
-                    (
-                        [rows[j].tobytes() for j in range(k + m)],
-                        [digs[j].tobytes() for j in range(k + m)],
+                rows = [
+                    np.ascontiguousarray(
+                        np.concatenate([arr[i, :, :s_i], parity[i, :, :s_i]], axis=0)
+                    )  # [K+M, s_i]
+                    for i, s_i in enumerate(shard_lens)
+                ]
+                digests = [self._host._digests(r) for r in rows]
+            with tracing.stage("small-scatter", "codec"):
+                with self._stats_lock:
+                    self.small_encode_seconds += st.wall
+                    self.small_queue_wait_block_seconds += sum(waits)
+                    self.small_batches_run += 1
+                    self.small_blocks_encoded += b_real
+                    self.small_blocks_padded += b_pad
+                    self.small_user_bytes += sum(d.size for d in datas)
+                for req, r, digs in zip(batch, rows, digests):
+                    req.future.set_result(
+                        (
+                            [r[j].tobytes() for j in range(k + m)],
+                            [digs[j].tobytes() for j in range(k + m)],
+                        )
                     )
-                )
         except Exception as e:  # noqa: BLE001
             for req in batch:
                 if not req.future.done():
@@ -689,6 +728,11 @@ class BatchingDeviceCodec(BlockCodec):
                 "small_blocks_encoded": self.small_blocks_encoded,
                 "small_batches_run": self.small_batches_run,
                 "small_blocks_padded": self.small_blocks_padded,
+                "small_encode_seconds": self.small_encode_seconds,
+                "small_queue_wait_block_seconds": self.small_queue_wait_block_seconds,
+                "small_worker_idle_seconds": self.small_worker_idle_seconds,
+                "small_worker_wall_seconds": self.small_worker_wall_seconds,
+                "small_user_bytes": self.small_user_bytes,
                 "double_buffered_batches": self.double_buffered_batches,
                 "mesh_devices": self.mesh_devices,
                 "chip_blocks": list(self.chip_blocks),

@@ -348,10 +348,15 @@ class _FakeAnnotation:
 
 @pytest.fixture
 def annotations(monkeypatch):
-    """A fake annotator installed for the test, the log of what it saw."""
+    """A fake annotator installed for the test, the log of what it saw on the
+    test's own thread: an earlier test's codec worker or background waker may
+    still be staging on its own (codec/worker-idle, background/*), and the
+    annotator is the process's."""
     log: list[tuple] = []
+    me = threading.get_ident()
     monkeypatch.setattr(
-        tracing, "_annotator", lambda label, **kw: _FakeAnnotation(log, label, kw))
+        tracing, "_annotator",
+        lambda label, **kw: _FakeAnnotation(log if threading.get_ident() == me else [], label, kw))
     return log
 
 
