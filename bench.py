@@ -524,6 +524,7 @@ def device_metrics() -> dict:
     surv = jnp.asarray(full[:, [j for j in range(K + M) if PRESENT[j]][:K], :])
     dec_gibs = gibs(jax.jit(lambda s: codec.apply(s, w)), surv, BATCH * BLOCK, ITERS)
 
+    # The fused program hashes all K+M rows and returns parity + digests.
     fdev = jax.device_put(jnp.asarray(data[:FUSED_BATCH]))
     fused_gibs = gibs(
         lambda x: fused_ops.fused_encode_hash(x, K, M, "xla", best_hash),

@@ -309,7 +309,7 @@ class ErasurePipeline:
 
         if mesh is None:
             return jax.jit(
-                fused_ops.make_step(dev_codec.encode_all, hash_fn, f"mtpu_encode_hash_{tag}")
+                fused_ops.make_step(dev_codec.encode, hash_fn, f"mtpu_encode_hash_{tag}")
             )
 
         # Mesh path: explicit SPMD. The erasure matmul is pointwise in the
@@ -358,7 +358,7 @@ class ErasurePipeline:
                 digests = hash_fn(x.reshape(-1, x.shape[-1])).reshape(
                     x.shape[0], t_loc, 32
                 )
-            return all_local, digests
+            return parity, digests
 
         # check_vma off: the encode->hash all-to-all mixes parameter-aliasing
         # and computed rows, which the replication checker rejects.
@@ -366,7 +366,7 @@ class ErasurePipeline:
             encode_local,
             mesh=mesh,
             in_specs=mesh_lib.data_spec(),
-            out_specs=(mesh_lib.shard_output_spec(), mesh_lib.digest_spec()),
+            out_specs=(mesh_lib.parity_spec(), mesh_lib.digest_spec()),
             check_vma=False,
         )
 
@@ -377,6 +377,13 @@ class ErasurePipeline:
         return jax.jit(mesh_step)
 
     def encode(self, data_shards) -> tuple[jax.Array, jax.Array]:
+        """[B, K, S] -> ([B, M, S] parity, [B, K+M, 32] digests).
+
+        One program encodes and hashes all K+M rows (data rows first, then
+        parity) and returns what the caller lacks: the parity rows and every
+        row's digest. The data rows are the caller's own input, bit for bit,
+        and do not come back.
+        """
         return self._encode_fn(data_shards)
 
     def encode_parity(self, data_shards) -> jax.Array:

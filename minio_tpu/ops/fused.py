@@ -1,9 +1,11 @@
 """Fused erasure-encode + bitrot-hash device program.
 
-One host dispatch turns [B, K, S] data shards into all [B, K+M, S] shards
-plus per-shard HighwayHash-256 digests. "Fused" here means one *jitted XLA
-program* containing two Pallas kernels back to back -- the XOR-bitmatrix
-encode (ops/rs_pallas) and the VMEM-resident HighwayHash chain
+One host dispatch turns [B, K, S] data shards into the [B, M, S] parity
+shards plus the HighwayHash-256 digests of all K+M shards (data rows first).
+The data rows are hashed on the device and stay there: the host holds them
+already, so the program returns only what the host lacks. "Fused" here means
+one *jitted XLA program* containing two Pallas kernels back to back -- the
+XOR-bitmatrix encode (ops/rs_pallas) and the VMEM-resident HighwayHash chain
 (ops/highwayhash_pallas) -- with the packet-layout transform between them
 staying device-resident. It is deliberately NOT a single pallas_call:
 encode combines *across* shard rows while the hash wants independent
@@ -23,6 +25,7 @@ from __future__ import annotations
 import functools
 
 import jax
+import jax.numpy as jnp
 
 from . import highwayhash_jax as hhj
 from . import rs, rs_pallas
@@ -30,8 +33,9 @@ from . import rs, rs_pallas
 _SUBLANES = 8  # rows of a TPU vector tile
 
 
-def make_step(encode_all_fn, hash_fn, name: str = "mtpu_encode_hash"):
-    """Compose an encode-all fn and a digest fn into one fused step.
+def make_step(encode_fn, hash_fn, name: str = "mtpu_encode_hash"):
+    """Compose a parity-encode fn ([B, K, S] -> [B, M, S]) and a digest fn
+    into one fused step.
 
     Returns the *unjitted* step so callers (models/pipeline) control the jit
     boundary; jit it once per (geometry, batch shape). `name` becomes the
@@ -41,9 +45,11 @@ def make_step(encode_all_fn, hash_fn, name: str = "mtpu_encode_hash"):
     """
 
     def step(data_shards: jax.Array):
-        """[B, K, S] -> ([B, K+M, S] shards, [B, K+M, 32] digests)."""
+        """[B, K, S] -> ([B, M, S] parity, [B, K+M, 32] digests of data
+        then parity rows)."""
         with jax.named_scope("mtpu.rs_encode"):
-            all_shards = encode_all_fn(data_shards)
+            parity = encode_fn(data_shards)
+            all_shards = jnp.concatenate([data_shards, parity], axis=1)
         b, t, s = all_shards.shape
         rows = all_shards.reshape(b * t, s)
         if t < _SUBLANES:
@@ -55,7 +61,7 @@ def make_step(encode_all_fn, hash_fn, name: str = "mtpu_encode_hash"):
             rows = jax.lax.optimization_barrier(rows)
         with jax.named_scope("mtpu.hh256"):
             digests = hash_fn(rows).reshape(b, t, 32)
-        return all_shards, digests
+        return parity, digests
 
     step.__name__ = step.__qualname__ = name
     return step
@@ -73,12 +79,13 @@ def _fused_cached(k: int, m: int, rs_impl: str, hash_impl: str):
         hash_fn = hhp.hash256_batch
     else:
         hash_fn = hhj.hash256_batch
-    return jax.jit(make_step(codec.encode_all, hash_fn, f"mtpu_encode_hash_k{k}m{m}"))
+    return jax.jit(make_step(codec.encode, hash_fn, f"mtpu_encode_hash_k{k}m{m}"))
 
 
 def fused_encode_hash(data_shards, k: int, m: int,
                       rs_impl: str = "pallas", hash_impl: str = "pallas"):
-    """One-launch fused encode+hash with explicit kernel choices.
+    """One-launch fused encode+hash with explicit kernel choices:
+    [B, K, S] -> ([B, M, S] parity, [B, K+M, 32] digests).
 
     bench.py times this directly (`pallas_fused_gibs`); serving goes through
     models/pipeline.ErasurePipeline, which picks impls by measured probe.

@@ -94,7 +94,9 @@ def _small_wait_s() -> float | None:
 
 @dataclass
 class _Request:
-    shards: np.ndarray  # [K, S] split data block
+    # [K, S] split data block: packed and uploaded, and handed back as the
+    # result's K data chunks (the device returns parity and digests only).
+    shards: np.ndarray
     future: Future
     # When the request was queued (perf_counter): codec/queue-wait is the
     # dispatch start less this, for the oldest request of a batch.
@@ -323,22 +325,22 @@ class BatchingDeviceCodec(BlockCodec):
             rng = np.random.default_rng(b * 1_000_003 + n)
             data = rng.integers(0, 256, (b, k, n), dtype=np.uint8)
             want = np.stack([self._host._encode_one(data[i], m) for i in range(b)])
-            if kind == "encode":
+            if kind == "encode":  # parity rows back, all k+m rows hashed
                 got, digests = pipe.encode(data)
-                want_rows = want
+                want_rows, hashed = want[:, k:], want
             elif kind == "parity":
                 got, digests = pipe.encode_parity(data), None
-                want_rows = want[:, k:]
+                want_rows = hashed = want[:, k:]
             else:  # the first m data rows lost, rebuilt from the next k rows
                 present = (False,) * m + (True,) * k
                 got, digests = pipe.reconstruct(
                     want[:, m : m + k], present, tuple(range(m)),
                     with_digests=kind.endswith("digests"),
                 )
-                want_rows = want[:, :m]
+                want_rows = hashed = want[:, :m]
             ok = np.array_equal(np.asarray(got), want_rows) and (
                 digests is None
-                or np.array_equal(np.asarray(digests), host_digests(want_rows))
+                or np.array_equal(np.asarray(digests), host_digests(hashed))
             )
             if not ok:
                 raise RuntimeError(
@@ -438,11 +440,11 @@ class BatchingDeviceCodec(BlockCodec):
             enc = tracing.stage("encode-batch", "codec")
             enc.__enter__()
             with tracing.stage("h2d", "codec"):
-                shards, digests = pipe.encode(arr)
+                parity, digests = pipe.encode(arr)
                 GLOBAL_PROFILER.copy.record("device-h2d", COPIED, arr.nbytes)
                 with self._stats_lock:
                     self.h2d_bytes += arr.nbytes
-            return (batch, shards, digests, k, m, b_real, b_pad, enc, pipe)
+            return (batch, parity, digests, k, m, b_real, b_pad, enc, pipe)
         except Exception as e:  # noqa: BLE001
             for req in batch:
                 if not req.future.done():
@@ -450,19 +452,20 @@ class BatchingDeviceCodec(BlockCodec):
             return None
 
     def _resolve_batch(self, rec) -> None:
-        batch, shards, digests, k, m, b_real, b_pad, enc, pipe = rec
+        batch, parity, digests, k, m, b_real, b_pad, enc, pipe = rec
         try:
             # What the worker pays waiting for the device (not device time:
             # under double-buffering the next batch is already in flight),
-            # then the bytes' way back to the host.
+            # then the way back to the host of what the host lacks: the
+            # parity rows and the digests. The data rows are req.shards.
             with tracing.stage("device-wait", "codec"):
-                jax.block_until_ready((shards, digests))
+                jax.block_until_ready((parity, digests))
             with tracing.stage("d2h", "codec"):
-                shards_np = np.asarray(shards)
-                digests_np = np.asarray(digests)
+                parity_np = np.asarray(parity)  # [b_pad, M, S]
+                digests_np = np.asarray(digests)  # [b_pad, K+M, 32]
             with tracing.stage("scatter", "codec"):
                 enc.__exit__(None, None, None)
-                d2h = shards_np.nbytes + digests_np.nbytes
+                d2h = parity_np.nbytes + digests_np.nbytes
                 GLOBAL_PROFILER.copy.record("device-d2h", COPIED, d2h)
                 with self._stats_lock:
                     self.device_encode_seconds += enc.wall
@@ -479,7 +482,8 @@ class BatchingDeviceCodec(BlockCodec):
                 for i, req in enumerate(batch):
                     req.future.set_result(
                         (
-                            [shards_np[i, j].tobytes() for j in range(k + m)],
+                            [req.shards[j].tobytes() for j in range(k)]
+                            + [parity_np[i, j].tobytes() for j in range(m)],
                             [digests_np[i, j].tobytes() for j in range(k + m)],
                         )
                     )

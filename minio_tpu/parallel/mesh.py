@@ -116,8 +116,8 @@ def digest_spec() -> P:
     return P("dp", ("sp", "tp"), None)
 
 
-def shard_output_spec() -> P:
-    """[B, K+M, S] encoded shards leaving the device: match data layout."""
+def parity_spec() -> P:
+    """[B, M, S] parity shards leaving the device: match data layout."""
     return P("dp", None, "sp")
 
 

@@ -351,9 +351,10 @@ def test_batch_stages_close_on_worker_wall():
 
 
 def test_transfer_bytes_equal_the_shapes():
-    """h2d_bytes / d2h_bytes are the padded arrays' bytes, the same counts
-    land in the copy ledger as hops, and encoded_user_bytes counts only real
-    blocks."""
+    """h2d_bytes / d2h_bytes are the padded arrays' bytes (up: the K data
+    rows; back: the M parity rows and the K+M digests, not the data rows),
+    the same counts land in the copy ledger as hops, and encoded_user_bytes
+    counts only real blocks."""
     from minio_tpu.control.profiler import GLOBAL_PROFILER
     from minio_tpu.ops import rs_matrix
 
@@ -369,7 +370,7 @@ def test_transfer_bytes_equal_the_shapes():
     assert st["blocks_encoded"] == 5
     slots = st["blocks_padded"]
     assert st["h2d_bytes"] == slots * k * s
-    assert st["d2h_bytes"] == slots * (k + m) * (s + 32)
+    assert st["d2h_bytes"] == slots * (m * s + 32 * (k + m))
     assert st["encoded_user_bytes"] == 5 * BLOCK
     hops1 = GLOBAL_PROFILER.copy.snapshot()["hops"]
     for hop, key in (("device-h2d", "h2d_bytes"), ("device-d2h", "d2h_bytes")):

@@ -147,12 +147,13 @@ def test_fused_digests_match_hash_batch():
     k, m, s = 4, 2, 2048
     rng = np.random.default_rng(5)
     data = rng.integers(0, 256, (3, k, s), dtype=np.uint8)
-    shards, digests = fused_ops.fused_encode_hash(data, k, m, "pallas", "xla")
-    shards, digests = np.asarray(shards), np.asarray(digests)
-    assert shards.shape == (3, k + m, s) and digests.shape == (3, k + m, 32)
+    parity, digests = fused_ops.fused_encode_hash(data, k, m, "pallas", "xla")
+    parity, digests = np.asarray(parity), np.asarray(digests)
+    assert parity.shape == (3, m, s) and digests.shape == (3, k + m, 32)
     for b in range(3):
-        np.testing.assert_array_equal(shards[b], rs_ref.encode(data[b], m))
-        want = np.asarray(hhj.hash256_batch(shards[b]))
+        shards = rs_ref.encode(data[b], m)  # data rows, then parity
+        np.testing.assert_array_equal(parity[b], shards[k:])
+        want = np.asarray(hhj.hash256_batch(shards))
         np.testing.assert_array_equal(digests[b], want)
 
 
@@ -162,7 +163,8 @@ def test_fused_xla_and_pallas_rs_agree():
     k, m, s = 6, 3, 1024
     rng = np.random.default_rng(6)
     data = rng.integers(0, 256, (2, k, s), dtype=np.uint8)
-    sp, dp = fused_ops.fused_encode_hash(data, k, m, "pallas", "xla")
-    sx, dx = fused_ops.fused_encode_hash(data, k, m, "xla", "xla")
-    np.testing.assert_array_equal(np.asarray(sp), np.asarray(sx))
+    pp, dp = fused_ops.fused_encode_hash(data, k, m, "pallas", "xla")
+    px, dx = fused_ops.fused_encode_hash(data, k, m, "xla", "xla")
+    assert np.asarray(pp).shape == (2, m, s)
+    np.testing.assert_array_equal(np.asarray(pp), np.asarray(px))
     np.testing.assert_array_equal(np.asarray(dp), np.asarray(dx))

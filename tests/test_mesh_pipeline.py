@@ -20,7 +20,7 @@ K, M = 12, 4
 
 
 def _host_oracle(data):
-    """[B, K, S] -> (shards, digests) via the numpy reference."""
+    """[B, K, S] -> (parity, digests of all K+M rows) via the numpy reference."""
     shards = np.stack([rs_ref.encode(data[i], M) for i in range(data.shape[0])])
     digests = np.stack(
         [
@@ -33,7 +33,7 @@ def _host_oracle(data):
             for i in range(data.shape[0])
         ]
     )
-    return shards, digests
+    return shards[:, K:], digests
 
 
 @pytest.mark.parametrize("shape", [(2, 2, 2), (4, 2, 1), (8, 1, 1), (1, 2, 4)])
@@ -48,9 +48,10 @@ def test_mesh_encode_matches_host(shape):
     data = rng.integers(0, 256, (2 * dp, K, geom.shard_size), dtype=np.uint8)
     arr = jax.device_put(data, mesh_lib.data_sharding(mesh))
 
-    shards, digests = pipe.encode(arr)
-    want_shards, want_digests = _host_oracle(data)
-    assert np.array_equal(np.asarray(shards), want_shards)
+    parity, digests = pipe.encode(arr)
+    want_parity, want_digests = _host_oracle(data)
+    assert parity.shape == (2 * dp, M, geom.shard_size)
+    assert np.array_equal(np.asarray(parity), want_parity)
     assert np.array_equal(np.asarray(digests), want_digests)
 
 
@@ -136,7 +137,7 @@ def test_default_mesh_dryrun():
     data = rng.integers(
         0, 256, (2 * mesh.shape["dp"], K, geom.shard_size), dtype=np.uint8
     )
-    shards, digests = pipe.encode(jax.device_put(data, mesh_lib.data_sharding(mesh)))
-    want_shards, want_digests = _host_oracle(data)
-    assert np.array_equal(np.asarray(shards), want_shards)
+    parity, digests = pipe.encode(jax.device_put(data, mesh_lib.data_sharding(mesh)))
+    want_parity, want_digests = _host_oracle(data)
+    assert np.array_equal(np.asarray(parity), want_parity)
     assert np.array_equal(np.asarray(digests), want_digests)

@@ -345,14 +345,15 @@ def test_full_block_encode_and_reconstruct_at_2p2_equal_the_oracles(batch):
     blocks = [_body(200 + i, BLOCK) for i in range(batch)]
     data = np.stack([ref.split_block(b, K) for b in blocks])  # [B, 2, 524288]
     assert data.shape == (batch, K, BLOCK // K)
-    shards, digests = pipe.encode(data)
-    shards, digests = np.asarray(shards), np.asarray(digests)
+    parity, digests = pipe.encode(data)  # parity rows only; all K+M digests
+    parity, digests = np.asarray(parity), np.asarray(digests)
+    assert parity.shape == (batch, M, BLOCK // K)
     for i, block in enumerate(blocks):
         want_rows, want_digests = ref.encode_block(block, K, M)
-        assert [shards[i, j].tobytes() for j in range(K + M)] == want_rows
+        assert [parity[i, j].tobytes() for j in range(M)] == want_rows[K:]
         assert [digests[i, j].tobytes() for j in range(K + M)] == want_digests
     # Both data rows lost: rebuilt from the two parity rows alone.
     present = (False, False, True, True)
-    rebuilt, rebuilt_digests = pipe.reconstruct(shards[:, K:], present, (0, 1))
+    rebuilt, rebuilt_digests = pipe.reconstruct(parity, present, (0, 1))
     assert np.array_equal(np.asarray(rebuilt), data)
     assert np.array_equal(np.asarray(rebuilt_digests), digests[:, :K])

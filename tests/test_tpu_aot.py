@@ -5,7 +5,9 @@ CPU-only host (jax.experimental.topologies). That catches what interpret mode
 on the CPU cannot: a Mosaic lowering refusal, a VMEM overflow, a shard_map the
 TPU compiler rejects -- at the production shape (12+4, 1 MiB blocks -> 87,382 B
 shards, one 16-block codec group), for the kernel pairs the boot-time selection can
-serve with. It proves nothing about execution or bit-exactness
+serve with, and the three served geometries' encode + hash program at the
+warm-up's largest batch (64), whose outputs are parity + digests only. Each
+program's temporaries and outputs are printed. It proves nothing about execution or bit-exactness
 on silicon: that is chip_smoke.py's job.
 
 Runs in a subprocess: the kernels pick interpret mode and unroll depth from
@@ -113,6 +115,14 @@ programs = {
     "fused xla+pallas 2+2 x64": lambda: fused._fused_cached(2, 2, "xla", "pallas").lower(
         sds((64, 2, 524288))
     ),
+    # The accepted cells' geometries at the warm-up's largest batch, as the
+    # boot-time selection serves them.
+    "fused xla+pallas 12+4 x64": lambda: fused._fused_cached(12, 4, "xla", "pallas").lower(
+        sds((64, 12, S))
+    ),
+    "fused xla+pallas 4+4 x64": lambda: fused._fused_cached(4, 4, "xla", "pallas").lower(
+        sds((64, 4, 262144))
+    ),
     # The small-object queue's parity-only program for 64 KiB objects at 2+2.
     "parity xla 2+2 x64": lambda: jax.jit(rs.RSCodec(2, 2).encode).lower(sds((64, 2, 32768))),
 }
@@ -127,12 +137,22 @@ def build(item):
     assert compiled is not None
     if "pallas" in name:
         assert "tpu_custom_call" in text, f"{name}: no Mosaic kernel in the lowering"
-    return name, time.time() - t0
+    mem = compiled.memory_analysis()
+    if name.startswith("fused"):
+        # The encode + hash program hands back what the host lacks: M parity
+        # rows and K+M digests a block, not the K data rows it was given.
+        (b, k, s), = (a.shape for a in lowered.in_avals[0])
+        parity, digests = lowered.out_info
+        m = parity.shape[1]
+        assert parity.shape == (b, m, s) and digests.shape == (b, k + m, 32), name
+        assert mem.output_size_in_bytes <= 1.02 * b * (m * s + 32 * (k + m)), (
+            name, mem.output_size_in_bytes)
+    return name, time.time() - t0, mem.temp_size_in_bytes, mem.output_size_in_bytes
 
 
 with ThreadPoolExecutor(len(programs)) as pool:
-    for name, dt in pool.map(build, programs.items()):
-        print(f"AOT_OK {name} {dt:.1f}s", flush=True)
+    for name, dt, temp, out in pool.map(build, programs.items()):
+        print(f"AOT_OK {name} {dt:.1f}s temporaries {temp} B outputs {out} B", flush=True)
 print("AOT_DONE", len(programs))
 """
 
@@ -149,4 +169,5 @@ def test_serving_programs_compile_for_v5e():
     if proc.returncode != 0 and "get_topology_desc" in proc.stderr and "AOT_OK" not in proc.stdout:
         pytest.skip("this libtpu cannot describe a v5e topology without a chip")
     assert proc.returncode == 0, proc.stdout[-2000:] + "\n" + proc.stderr[-6000:]
-    assert "AOT_DONE 9" in proc.stdout, proc.stdout
+    assert "AOT_DONE 11" in proc.stdout, proc.stdout
+    print(proc.stdout)  # each program's temporaries and outputs, under -s / on failure
