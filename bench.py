@@ -484,8 +484,8 @@ def device_metrics() -> dict:
     from minio_tpu.ops import fused as fused_ops
     from minio_tpu.ops import highwayhash_jax as hhj
     from minio_tpu.ops import highwayhash_pallas as hhp
+    from minio_tpu.models.pipeline import hash_batch_fn
     from minio_tpu.ops import rs
-    from minio_tpu.ops.rs_pallas import RSPallasCodec
 
     d0 = jax.devices()[0]
     rng = np.random.default_rng(0)
@@ -504,7 +504,7 @@ def device_metrics() -> dict:
     enc_gibs = gibs(jax.jit(codec.encode), dev, BATCH * BLOCK, ITERS)
 
     # Hash-only throughput of both device implementations over the fused
-    # batch's stream shape; the fused number below uses the winner.
+    # batch's stream shape; the fused number below uses the one that serves.
     hdata = jax.device_put(
         jnp.asarray(
             rng.integers(0, 256, (FUSED_BATCH * (K + M), SHARD), dtype=np.uint8)
@@ -516,7 +516,6 @@ def device_metrics() -> dict:
         name: gibs(jax.jit(fn), hdata, hdata.size, hiters)
         for name, fn in hash_impls.items()
     }
-    best_hash = max(hash_gibs, key=hash_gibs.get)
 
     # Reconstruct 4 missing data shards from the 12 surviving rows.
     w = codec.reconstruct_weights(PRESENT, MISSING)
@@ -527,23 +526,13 @@ def device_metrics() -> dict:
     # The fused program hashes all K+M rows and returns parity + digests.
     fdev = jax.device_put(jnp.asarray(data[:FUSED_BATCH]))
     fused_gibs = gibs(
-        lambda x: fused_ops.fused_encode_hash(x, K, M, "xla", best_hash),
-        fdev, FUSED_BATCH * BLOCK, hiters,
-    )
-
-    # XOR-bitmatrix Pallas encode (ops/rs_pallas.py), alone and fused with
-    # the on-device hash in ONE jitted program (ops/fused.py): what a PUT
-    # window pays when the Pallas codec serves.
-    pcodec = RSPallasCodec(K, M)
-    pallas_gibs = gibs(jax.jit(pcodec.encode), dev, BATCH * BLOCK, ITERS)
-    pallas_fused_gibs = gibs(
-        lambda x: fused_ops.fused_encode_hash(x, K, M, "pallas", best_hash),
+        jax.jit(fused_ops.make_step(codec.encode, hash_batch_fn())),
         fdev, FUSED_BATCH * BLOCK, hiters,
     )
 
     # Multi-chip fan-out: data-parallel encode over every local device via
     # shard_map ((n,1,1) mesh). Scaling efficiency is vs n * the single-chip
-    # Pallas number.
+    # encode number.
     multichip_gibs = 0.0
     multichip_eff = 0.0
     n_dev = len(jax.devices())
@@ -555,7 +544,7 @@ def device_metrics() -> dict:
         mesh = mesh_lib.make_mesh(n_dev, (n_dev, 1, 1))
         menc = jax.jit(
             jax.shard_map(
-                pcodec.encode,
+                codec.encode,
                 mesh=mesh,
                 in_specs=P("dp", None, None),
                 out_specs=P("dp", None, None),
@@ -568,7 +557,7 @@ def device_metrics() -> dict:
             mesh_lib.data_sharding(mesh),
         )
         multichip_gibs = gibs(menc, mdata, mb * BLOCK, ITERS)
-        multichip_eff = multichip_gibs / (pallas_gibs * n_dev)
+        multichip_eff = multichip_gibs / (enc_gibs * n_dev)
     return {
         "platform": d0.platform,
         "device_kind": d0.device_kind,
@@ -576,11 +565,8 @@ def device_metrics() -> dict:
         "encode_gibs": enc_gibs,
         "decode_recon4_gibs": dec_gibs,
         "fused_encode_hash_gibs": fused_gibs,
-        "fused_hash_impl": best_hash,
         "hash_xla_gibs": round(hash_gibs["xla"], 3),
         "hash_pallas_gibs": round(hash_gibs["pallas"], 3),
-        "pallas_encode_gibs": pallas_gibs,
-        "pallas_fused_gibs": pallas_fused_gibs,
         "multichip_encode_gibs": multichip_gibs,
         "multichip_devices": n_dev,
         "multichip_scaling_eff": round(multichip_eff, 3),
@@ -603,7 +589,6 @@ def main() -> int:
     from minio_tpu import jaxenv
     from minio_tpu.control.flight import GLOBAL_FLIGHT
     from minio_tpu.models.pipeline import kernel_status
-    from minio_tpu.ops import bitmatrix
 
     jaxenv.enable_compile_cache()
     rng = np.random.default_rng(1)
@@ -631,16 +616,12 @@ def main() -> int:
         "device_count": dm["device_count"],
         "cpu_avx2_gibs": round(cpu_enc, 3),
         "fused_encode_hash_gibs": round(dm["fused_encode_hash_gibs"], 3),
-        "fused_hash_impl": dm["fused_hash_impl"],
         "hash_xla_gibs": dm["hash_xla_gibs"],
         "hash_pallas_gibs": dm["hash_pallas_gibs"],
-        "pallas_encode_gibs": round(dm["pallas_encode_gibs"], 3),
-        "pallas_fused_gibs": round(dm["pallas_fused_gibs"], 3),
         "multichip_encode_gibs": round(dm["multichip_encode_gibs"], 3),
         "multichip_devices": dm["multichip_devices"],
         "multichip_scaling_eff": dm["multichip_scaling_eff"],
-        "xor_schedule": bitmatrix.schedule_stats(K, M),
-        "kernel_status": kernel_status(K, M),
+        "kernel_status": kernel_status(),
         "decode_recon4_gibs": round(dm["decode_recon4_gibs"], 3),
         "cpu_decode_recon4_gibs": round(cpu_dec, 3),
         "decode_vs_baseline": (

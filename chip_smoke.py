@@ -17,7 +17,7 @@ the server opens it).
 Exit 0 and a last stdout line
     {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": N}}
 only when every phase passed on a TPU. Any failed phase, any exception, a
-codec that never took over, a Pallas kernel the selection probe rejected, a
+codec that never took over (a kernel the oracle-compared warm-up refused), a
 failed native build, or no TPU: non-zero exit and no result line. Nothing
 printed here is a throughput: seconds are set-up times, counts are counters.
 
@@ -57,7 +57,7 @@ ADMIN = "/mtpu/admin/v1"
 ACCESS, SECRET = "chipsmokeadmin", "chipsmoke-secret-key"
 # Env that would move the server off the deployment this smoke pins.
 _PINNED_ENV = (
-    "MINIO_TPU_CODEC", "MINIO_TPU_RS", "MINIO_TPU_HASH", "MTPU_WORKERS",
+    "MINIO_TPU_CODEC", "MTPU_WORKERS",
     "MTPU_MESH_SHAPE", "MTPU_BATCH_WAIT_US", "MTPU_FSYNC", "MTPU_PROBE_CACHE",
     "MTPU_MEMCACHE_MB", "MTPU_FAST_ETAG",
 )
@@ -334,9 +334,6 @@ def run(args) -> dict:
         if not args.allow_cpu:
             check(m.get('minio_tpu_device_probe_ok{platform="tpu"}') == 1,
                   "no minio_tpu_device_probe_ok{platform=\"tpu\"} 1 in the server's metrics")
-            for stage in ("rs", "hash"):
-                k = inst["kernels"][stage]
-                check(k["pallas_ok"], f"Pallas {stage} kernel rejected on the chip: {k['detail']}")
         check(m.get(f'minio_tpu_device_codec_serving{{platform="{want}"}}') == 1,
               "minio_tpu_device_codec_serving is not 1")
         check("minio_tpu_codec_blocks_encoded_total" in m, "no minio_tpu_codec_* series")

@@ -5,14 +5,12 @@ plus bitrot digests. Implementations:
 
   * HostCodec  -- numpy GF tables + numpy HighwayHash; the low-latency
     fallback (the reference's always-on CPU SIMD analogue).
-  * DeviceCodec -- single-shot JAX encode+hash on the accelerator; right for
-    large objects / heals where one call carries many blocks.
-  * The cross-upload batching scheduler (parallel/batching.py) wraps
-    DeviceCodec to aggregate blocks from concurrent requests into one device
-    program -- the BASELINE.json north-star design.
+  * parallel/batching.BatchingDeviceCodec -- the cross-upload batching
+    scheduler: blocks from concurrent requests aggregated into one device
+    program (models/pipeline.py) -- the BASELINE.json north-star design.
 
-All implementations produce bit-identical outputs (tests pin this), so the
-object layer can switch freely per call size.
+Both produce bit-identical outputs (tests pin this), so the runtime can
+install either.
 """
 
 from __future__ import annotations
@@ -322,8 +320,8 @@ def run_device_reconstruct(
     with_digests: bool,
 ) -> list[tuple[list[bytes], list[bytes] | None]]:
     """Assemble a uniform rows_batch into one padded [B, K, S] device
-    reconstruct program and unpack per-block results (shared by DeviceCodec
-    and the batching codec -- the served decode/heal path)."""
+    reconstruct program and unpack per-block results (the batching codec's
+    served decode/heal path)."""
     b_real = len(rows_batch)
     b_pad = max(bucket_batch(b_real), b_real)  # never allocate under b_real
     present = tuple(r is not None for r in rows_batch[0])
@@ -369,72 +367,6 @@ def uniform_recon_plan(
         return None
     surv = [i for i, p in enumerate(present) if p][:k]
     return present, surv, sizes.pop()
-
-
-class DeviceCodec(BlockCodec):
-    """JAX device codec: one fused encode+hash program per call.
-
-    Blocks in one call are padded to the longest shard size and batched into
-    a single [B, K, S] tensor, so a large PutObject or heal already amortizes
-    transfer/launch across its own blocks. Cross-request amortization is the
-    batching scheduler's job (parallel/batching.py).
-    """
-
-    def __init__(self):
-        self._host = HostCodec()
-        self._pipelines: dict[tuple[int, int], object] = {}
-
-    def _pipe(self, k: int, m: int):
-        from ..models.pipeline import ErasurePipeline, Geometry
-
-        key = (k, m)
-        if key not in self._pipelines:
-            self._pipelines[key] = ErasurePipeline(Geometry(k, m))
-        return self._pipelines[key]
-
-    def encode(self, blocks, k, m):
-        from ..ops import rs as rs_dev
-
-        if not blocks:
-            return []
-        sizes = [rs_matrix.shard_size(len(b), k) for b in blocks]
-        s_max = max(sizes)
-        batch = np.zeros((len(blocks), k, s_max), dtype=np.uint8)
-        for i, block in enumerate(blocks):
-            batch[i, :, : sizes[i]] = _split_block(block, k)
-        codec = rs_dev.RSCodec(k, m)
-        all_shards = np.asarray(codec.encode_all(batch))  # [B, K+M, S]
-        out = []
-        for i in range(len(blocks)):
-            s = sizes[i]
-            shards_i = all_shards[i, :, :s]
-            # Padded-batch digests are only valid when every block shares the
-            # padded length; hash at true length instead, via the host
-            # codec's kernel (AVX2 when built -- the numpy oracle here would
-            # silently cost ~10x on every mixed-size device batch).
-            digests = self._host._digests(np.ascontiguousarray(shards_i))
-            out.append(
-                (
-                    [shards_i[j].tobytes() for j in range(k + m)],
-                    [digests[j].tobytes() for j in range(k + m)],
-                )
-            )
-        return out
-
-    def reconstruct(self, shards, k, m, want):
-        return self._host.reconstruct(shards, k, m, want)
-
-    def reconstruct_batch(self, rows_batch, k, m, want, with_digests=False):
-        """Uniform multi-block rebuilds run as one device program (the served
-        decode/heal path, cmd/erasure-lowlevel-heal.go:31); singles and
-        irregular batches take the low-latency host codec."""
-        plan = uniform_recon_plan(rows_batch, k) if len(rows_batch) > 1 else None
-        if plan is None:
-            return super().reconstruct_batch(rows_batch, k, m, want, with_digests)
-        _, surv, s = plan
-        return run_device_reconstruct(
-            self._pipe(k, m), rows_batch, k, tuple(want), surv, s, with_digests
-        )
 
 
 _default: BlockCodec | None = None

@@ -4,15 +4,13 @@ One host dispatch turns [B, K, S] data shards into the [B, M, S] parity
 shards plus the HighwayHash-256 digests of all K+M shards (data rows first).
 The data rows are hashed on the device and stay there: the host holds them
 already, so the program returns only what the host lacks. "Fused" here means
-one *jitted XLA program* containing two Pallas kernels back to back -- the
-XOR-bitmatrix encode (ops/rs_pallas) and the VMEM-resident HighwayHash chain
-(ops/highwayhash_pallas) -- with the packet-layout transform between them
-staying device-resident. It is deliberately NOT a single pallas_call:
-encode combines *across* shard rows while the hash wants independent
-streams on lanes, so a single kernel would need an in-kernel lane<->sublane
-transpose that cannot be validated off-hardware; the XLA boundary costs one
-HBM round-trip of the shard bytes and keeps both kernels independently
-oracle-checked.
+one *jitted XLA program* holding both stages -- the GF(2) bit-matmul encode
+(ops/rs) and the device hash (on a TPU the VMEM-resident HighwayHash chain of
+ops/highwayhash_pallas) -- with the packet-layout transform between them
+staying device-resident. It is deliberately NOT a single kernel: encode
+combines *across* shard rows while the hash wants independent streams on
+lanes; the boundary costs one HBM round-trip of the shard bytes and keeps
+both stages independently oracle-checked.
 
 What PUT pays per 16 MiB window: one host->device transfer of the data
 shards, one program launch, one device->host transfer of parity + digests.
@@ -22,13 +20,8 @@ runs as XLA epilogue exactly as ops/highwayhash_pallas already does.
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
-
-from . import highwayhash_jax as hhj
-from . import rs, rs_pallas
 
 _SUBLANES = 8  # rows of a TPU vector tile
 
@@ -65,29 +58,3 @@ def make_step(encode_fn, hash_fn, name: str = "mtpu_encode_hash"):
 
     step.__name__ = step.__qualname__ = name
     return step
-
-
-@functools.lru_cache(maxsize=32)
-def _fused_cached(k: int, m: int, rs_impl: str, hash_impl: str):
-    if rs_impl == "pallas":
-        codec = rs_pallas.RSPallasCodec(k, m)
-    else:
-        codec = rs.RSCodec(k, m)
-    if hash_impl == "pallas":
-        from . import highwayhash_pallas as hhp
-
-        hash_fn = hhp.hash256_batch
-    else:
-        hash_fn = hhj.hash256_batch
-    return jax.jit(make_step(codec.encode, hash_fn, f"mtpu_encode_hash_k{k}m{m}"))
-
-
-def fused_encode_hash(data_shards, k: int, m: int,
-                      rs_impl: str = "pallas", hash_impl: str = "pallas"):
-    """One-launch fused encode+hash with explicit kernel choices:
-    [B, K, S] -> ([B, M, S] parity, [B, K+M, 32] digests).
-
-    bench.py times this directly (`pallas_fused_gibs`); serving goes through
-    models/pipeline.ErasurePipeline, which picks impls by measured probe.
-    """
-    return _fused_cached(k, m, rs_impl, hash_impl)(data_shards)

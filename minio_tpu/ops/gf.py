@@ -8,8 +8,8 @@ with that construction is pinned by the golden self-test vectors re-hosted in
 tests/test_rs_golden.py (reference: cmd/erasure-coding.go:158-216).
 
 Everything here is host-side numpy: table generation, matrix algebra over the
-field (inversion for decode), and scalar helpers. The device kernels in rs.py /
-rs_pallas.py consume the *bit-expanded* GF(2) matrices built in rs_matrix.py.
+field (inversion for decode), and scalar helpers. The device kernel in rs.py
+consumes the *bit-expanded* GF(2) matrices built in rs_matrix.py.
 """
 
 from __future__ import annotations

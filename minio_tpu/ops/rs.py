@@ -12,10 +12,8 @@ decode/reconstruct, and heal all reduce to this one kernel with different
 coefficient matrices (reference equivalents: Encode/ReconstructData/Heal at
 /root/reference/cmd/erasure-coding.go:77-119 and erasure-lowlevel-heal.go:31).
 
-This module is the XLA path; ops/rs_pallas.py is the fused Pallas kernel
-that keeps the 8x bit expansion in VMEM instead of HBM (bit-identical --
-tests/test_rs_pallas.py pins both against the host reference). bench.py
-measures both on the live chip.
+This is the one device RS kernel, on every platform (tests/test_rs.py pins it
+against the host reference and the golden vectors).
 """
 
 from __future__ import annotations

@@ -297,7 +297,7 @@ class BatchingDeviceCodec(BlockCodec):
         from .. import jaxenv
 
         t0 = _time.perf_counter()
-        self._ensure_worker(k, m)  # builds the pipeline: kernel selection runs here
+        self._ensure_worker(k, m)  # builds the pipeline
         pipe = self._pipelines[(k, m)]
         full = jaxenv.on_tpu()
         s = rs_matrix.shard_size(self.block_size, k)

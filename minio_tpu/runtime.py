@@ -419,8 +419,6 @@ def _describe(codec, geometry, warm: dict | None, cache_added: int) -> dict:
 
     devs = jax.devices()
     mesh = codec.mesh if codec.mesh not in (None, "auto") else None
-    k, m = geometry or _DEFAULT_GEOMETRY
-    ks = pipeline.kernel_status(k, m)
     return {
         "codec": type(codec).__name__,
         "platform": devs[0].platform,
@@ -431,10 +429,7 @@ def _describe(codec, geometry, warm: dict | None, cache_added: int) -> dict:
         "libtpu": _libtpu_version(),
         "geometry": list(geometry) if geometry else None,
         "mesh": {a: int(n) for a, n in mesh.shape.items()} if mesh is not None else None,
-        "kernels": {
-            "rs": {"serving": ks["rs_mode"], **ks["rs"]},
-            "hash": {"serving": ks["hash_mode"], **ks["hash"]},
-        },
+        "kernels": pipeline.kernel_status(),
         "warm": warm,
         "compile_cache": {"dir": jaxenv.compile_cache_dir(), "entries_added": cache_added},
     }

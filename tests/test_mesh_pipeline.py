@@ -99,19 +99,19 @@ class TestMeshShapeEnv:
         assert mesh_lib.codec_mesh() is None
 
 
-def test_pallas_rs_under_mesh_matches_host():
-    """The XOR-bitmatrix Pallas codec shard_mapped data-parallel over all 8
-    virtual devices stays bit-identical to the host oracle (the bench's
+def test_rs_under_dp_mesh_matches_host():
+    """The RS codec shard_mapped data-parallel over all 8 virtual devices
+    stays bit-identical to the host oracle (the bench's
     multichip_encode_gibs program)."""
     if jax.device_count() < 8:
         pytest.skip("needs the 8-device virtual platform from conftest")
     from jax.sharding import PartitionSpec as P
 
-    from minio_tpu.ops.rs_pallas import RSPallasCodec
+    from minio_tpu.ops.rs import RSCodec
 
     n = 8
     mesh = mesh_lib.make_mesh(n, (n, 1, 1))
-    codec = RSPallasCodec(K, M)
+    codec = RSCodec(K, M)
     enc = jax.jit(
         jax.shard_map(
             codec.encode, mesh=mesh,

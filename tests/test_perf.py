@@ -464,7 +464,7 @@ class TestCodecFloor:
 
     def test_device_beating_floor_passes(self):
         new = {"device": True, "value": 18.0, "cpu_avx2_gibs": 2.0,
-               "pallas_fused_gibs": 9.0, "pallas_fused_error": ""}
+               "fused_encode_hash_gibs": 9.0, "fused_encode_hash_error": ""}
         assert perf_gate.codec_floor_findings(new) == []
 
     def test_wedged_probe_round_never_gates(self):
@@ -475,14 +475,14 @@ class TestCodecFloor:
 
     def test_fused_below_floor_flags_when_measured(self):
         new = {"device": True, "value": 18.0, "cpu_avx2_gibs": 2.0,
-               "pallas_fused_gibs": 1.5, "pallas_fused_error": ""}
+               "fused_encode_hash_gibs": 1.5, "fused_encode_hash_error": ""}
         findings = perf_gate.codec_floor_findings(new)
-        assert [f["metric"] for f in findings] == ["pallas_fused_gibs"]
+        assert [f["metric"] for f in findings] == ["fused_encode_hash_gibs"]
 
     def test_unmeasured_or_errored_fused_is_not_gated(self):
         # 0.0 = not measured; a recorded error = known-skipped secondary.
-        for extra in ({"pallas_fused_gibs": 0.0},
-                      {"pallas_fused_gibs": 1.0, "pallas_fused_error": "boom"}):
+        for extra in ({"fused_encode_hash_gibs": 0.0},
+                      {"fused_encode_hash_gibs": 1.0, "fused_encode_hash_error": "boom"}):
             new = {"device": True, "value": 18.0, "cpu_avx2_gibs": 2.0, **extra}
             assert perf_gate.codec_floor_findings(new) == []
 

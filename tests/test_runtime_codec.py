@@ -107,6 +107,33 @@ def test_warmup_failure_keeps_host_codec_and_is_exported(_fake_tpu, monkeypatch)
         runtime.install_data_plane_codec(mode="auto")  # synchronous: raises
 
 
+def _wrong_digests(rows):
+    from minio_tpu.ops import highwayhash_jax as hhj
+
+    return hhj.hash256_batch(rows) ^ np.uint8(1)
+
+
+def _refuses_to_lower(rows):
+    raise NotImplementedError("Mosaic: unsupported op")
+
+
+@pytest.mark.parametrize(
+    "broken,reason", [(_wrong_digests, "disagrees with the host codec"),
+                      (_refuses_to_lower, "Mosaic: unsupported op")])
+def test_broken_hash_kernel_fails_the_real_warmup(_fake_tpu, monkeypatch, broken, reason):
+    """No boot-time probe stands between a broken kernel and traffic: the
+    oracle-compared warm-up does, and the host codec keeps serving."""
+    from minio_tpu.models import pipeline
+
+    monkeypatch.setattr(pipeline, "hash_batch_fn", lambda: broken)
+    host = codec_mod.HostCodec()
+    codec_mod.set_default_codec(host)
+    runtime._takeover("tpu", (4, 2))
+    assert codec_mod.default_codec() is host
+    inst = runtime.probe_summary()["install"]
+    assert inst["state"] == "failed" and reason in inst["reason"]
+
+
 def test_prefork_worker_never_opens_the_device(monkeypatch):
     """MTPU_WORKERS: N processes on one chip. Workers install the host codec
     without probing and say why; device mode there is an error."""

@@ -3,12 +3,14 @@
 The installed libtpu can describe a `v5e:2x2` topology and compile for it on a
 CPU-only host (jax.experimental.topologies). That catches what interpret mode
 on the CPU cannot: a Mosaic lowering refusal, a VMEM overflow, a shard_map the
-TPU compiler rejects -- at the production shape (12+4, 1 MiB blocks -> 87,382 B
-shards, one 16-block codec group), for the kernel pairs the boot-time selection can
-serve with, and the three served geometries' encode + hash program at the
-warm-up's largest batch (64), whose outputs are parity + digests only. Each
-program's temporaries and outputs are printed. It proves nothing about execution or bit-exactness
-on silicon: that is chip_smoke.py's job.
+TPU compiler rejects -- for the programs a v5e serves (XLA bit-matmul RS, Pallas
+hash): encode + hash at the production shape (12+4, 1 MiB blocks -> 87,382 B
+shards, one 16-block codec group) and for the three served geometries at the
+warm-up's largest batch (64), whose outputs are parity + digests only; the
+small queue's parity program; the mesh step; and the two reconstruct programs,
+heal (with digests) and degraded GET (without). Each program's temporaries and
+outputs are printed. It proves nothing about execution or bit-exactness on
+silicon: that is chip_smoke.py's job.
 
 Runs in a subprocess: the kernels pick interpret mode and unroll depth from
 jaxenv.on_tpu() at TRACE time, so compiling them for the chip in the test
@@ -26,7 +28,7 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 _SCRIPT = r"""
-import os, sys, time
+import time
 from concurrent.futures import ThreadPoolExecutor
 
 import jax
@@ -40,7 +42,8 @@ from minio_tpu import jaxenv
 jaxenv.on_tpu = lambda: True  # trace the kernels as the chip would
 
 from minio_tpu.models import pipeline
-from minio_tpu.ops import bitmatrix, fused, rs, rs_matrix
+from minio_tpu.ops import fused, rs, rs_matrix
+from minio_tpu.ops import highwayhash_pallas as hhp
 from minio_tpu.parallel import mesh as mesh_lib
 
 K, M, BATCH = 12, 4, 16
@@ -57,75 +60,46 @@ def sds(shape, dtype=jnp.uint8, sharding=one):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def fused_step(rs_impl, hash_impl):
-    return fused._fused_cached(K, M, rs_impl, hash_impl).lower(sds((BATCH, K, S)))
+def fused_step(k, m, batch, s):
+    step = fused.make_step(rs.RSCodec(k, m).encode, hhp.hash256_batch)
+    return lambda: jax.jit(step).lower(sds((batch, k, s)))
 
 
-# Heal of the first four data rows: reconstruct fused with the digest hash.
-lost = (0, 1, 2, 3)
-present = tuple(j not in lost for j in range(K + M))
-coeffs = rs_matrix.reconstruct_rows(K, M, present, lost)
+def recon_step(hash_fn):
+    # The first four data rows lost, rebuilt from the next twelve.
+    w = sds((K * 8, 4 * 8), jnp.int8)
+    return lambda: pipeline._reconstruct_step.lower(sds((BATCH, K, S)), w, hash_fn)
 
 
-def recon_xla():
-    from minio_tpu.ops import highwayhash_jax as hhj
-
-    w = rs_matrix.bit_expand(coeffs)
-    return pipeline._reconstruct_step.lower(
-        sds((BATCH, K, S)), sds(w.shape, jnp.int8), hhj.hash256_batch
-    )
-
-
-def recon_pallas():
-    from minio_tpu.ops import highwayhash_pallas as hhp
-
-    return pipeline._reconstruct_sched_step.lower(
-        sds((BATCH, K, S)), bitmatrix.schedule_for_coeffs(coeffs), hhp.hash256_batch
-    )
-
-
-def mesh_step(rs_impl):
+def mesh_step():
     # What codec_mesh() builds by itself on a four-chip host: factor_mesh(4).
-    # The kernel pair is fixed by the env here, not by the boot-time race
-    # (which needs a device), and resolved now, before the compile threads.
-    os.environ["MINIO_TPU_RS"], os.environ["MINIO_TPU_HASH"] = rs_impl, "pallas"
+    # Built now, before the compile threads.
     shape = mesh_lib.factor_mesh(4)
     assert shape == (2, 2, 1), shape
     mesh = Mesh(np.array(devs).reshape(shape), mesh_lib.AXES)
     pipe = pipeline.ErasurePipeline(pipeline.Geometry(K, M), mesh=mesh)
-    assert pipe.rs_impl == rs_impl
     return lambda: pipe._encode_fn.lower(
         sds((BATCH, K, S), sharding=NamedSharding(mesh, mesh_lib.data_spec()))
     )
 
 
+assert pipeline.hash_batch_fn() is hhp.hash256_batch
 programs = {
-    "fused pallas+pallas": lambda: fused_step("pallas", "pallas"),
-    "fused xla+xla": lambda: fused_step("xla", "xla"),
-    # xla+pallas is the pair the boot-time selection picked on a v5e
-    # (CHANGES.md, PR 21).
-    "fused xla+pallas": lambda: fused_step("xla", "pallas"),
-    "reconstruct xla": recon_xla,
-    "reconstruct pallas": recon_pallas,
-    "mesh (2,2,1) pallas+pallas": mesh_step("pallas"),
-    "mesh (2,2,1) xla+pallas": mesh_step("xla"),
-    # The smallest erasure set, 2+2 (524,288 B shards), at the warm-up's
-    # largest batch: four shard rows are fewer than a tile's sublanes, and
-    # without the barrier in fused.make_step this program wants 18 GB of HBM.
-    "fused xla+pallas 2+2 x64": lambda: fused._fused_cached(2, 2, "xla", "pallas").lower(
-        sds((64, 2, 524288))
-    ),
-    # The accepted cells' geometries at the warm-up's largest batch, as the
-    # boot-time selection serves them.
-    "fused xla+pallas 12+4 x64": lambda: fused._fused_cached(12, 4, "xla", "pallas").lower(
-        sds((64, 12, S))
-    ),
-    "fused xla+pallas 4+4 x64": lambda: fused._fused_cached(4, 4, "xla", "pallas").lower(
-        sds((64, 4, 262144))
-    ),
+    "fused 12+4 x16": fused_step(K, M, BATCH, S),
+    # The accepted cells' geometries at the warm-up's largest batch.
+    "fused 12+4 x64": fused_step(12, 4, 64, S),
+    "fused 4+4 x64": fused_step(4, 4, 64, 262144),
+    # The smallest erasure set, 2+2 (524,288 B shards): four shard rows are
+    # fewer than a tile's sublanes, and without the barrier in
+    # fused.make_step this program wants 18 GB of HBM.
+    "fused 2+2 x64": fused_step(2, 2, 64, 524288),
     # The small-object queue's parity-only program for 64 KiB objects at 2+2.
-    "parity xla 2+2 x64": lambda: jax.jit(rs.RSCodec(2, 2).encode).lower(sds((64, 2, 32768))),
+    "parity 2+2 x64": lambda: jax.jit(rs.RSCodec(2, 2).encode).lower(sds((64, 2, 32768))),
+    "mesh (2,2,1)": mesh_step(),
+    "reconstruct + digests (heal)": recon_step(hhp.hash256_batch),
+    "reconstruct (degraded GET)": recon_step(None),
 }
+UNHASHED = {"parity 2+2 x64", "reconstruct (degraded GET)"}
 
 
 def build(item):
@@ -135,8 +109,8 @@ def build(item):
     text = lowered.as_text()
     compiled = lowered.compile()
     assert compiled is not None
-    if "pallas" in name:
-        assert "tpu_custom_call" in text, f"{name}: no Mosaic kernel in the lowering"
+    # The hash is the one Mosaic kernel; RS is XLA's own fusions.
+    assert ("tpu_custom_call" in text) == (name not in UNHASHED), f"{name}: Mosaic kernels"
     mem = compiled.memory_analysis()
     if name.startswith("fused"):
         # The encode + hash program hands back what the host lacks: M parity
@@ -169,5 +143,5 @@ def test_serving_programs_compile_for_v5e():
     if proc.returncode != 0 and "get_topology_desc" in proc.stderr and "AOT_OK" not in proc.stdout:
         pytest.skip("this libtpu cannot describe a v5e topology without a chip")
     assert proc.returncode == 0, proc.stdout[-2000:] + "\n" + proc.stderr[-6000:]
-    assert "AOT_DONE 11" in proc.stdout, proc.stdout
+    assert "AOT_DONE 8" in proc.stdout, proc.stdout
     print(proc.stdout)  # each program's temporaries and outputs, under -s / on failure

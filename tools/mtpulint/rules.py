@@ -693,9 +693,10 @@ class UnlockedGlobalRule(Rule):
     """Mutable module globals mutated outside a lock.
 
     A module-level dict/list/set written from request or worker threads
-    without a lock is a check-then-act race (the `_HASH_SELECT` class of
-    bug). Either guard every mutation with a module lock, or mark the
-    binding `# mtpulint: immutable` when it is write-once at import time."""
+    without a lock is a check-then-act race (two threads both miss a cache
+    entry and both build it). Either guard every mutation with a module
+    lock, or mark the binding `# mtpulint: immutable` when it is write-once
+    at import time."""
 
     id = "unlocked-global"
     title = "mutable module global mutated without a lock"

@@ -15,11 +15,11 @@ A stage is flagged when BOTH hold:
     got faster, which is an improvement, not a regression.
 
 Codec-floor mode (automatic in stage mode): when the new BENCH line claims
-`device: true`, its headline encode number -- and the fused Pallas number,
-when measured -- must beat the same line's recorded CPU floor
+`device: true`, its headline encode number -- and the fused encode + hash
+number, when measured -- must beat the same line's recorded CPU floor
 (`cpu_avx2_gibs`). A "device" round that encodes slower than the host AVX2
 path means the device codec regressed into net-negative territory; the
-seed shipped exactly that (`pallas_encode_gibs: 0.0`) for five rounds
+seed shipped exactly that (a device encode number of 0.0) for five rounds
 without any gate noticing. bench.py exits non-zero without an accelerator;
 older lines that say `device: false` are never floor-gated.
 
@@ -95,7 +95,7 @@ def codec_floor_findings(new: dict) -> list[dict]:
     makes no device claim or carries no codec keys).
 
     Gated metrics: the headline `value` (device encode GiB/s) always; the
-    fused Pallas number only when it was actually measured (non-zero, no
+    fused encode + hash number only when it was actually measured (non-zero, no
     recorded error) -- a skipped secondary metric is absence of evidence,
     not a regression.
     """
@@ -108,7 +108,7 @@ def codec_floor_findings(new: dict) -> list[dict]:
     if floor <= 0:
         return []
     findings: list[dict] = []
-    for key, err_key in (("value", None), ("pallas_fused_gibs", "pallas_fused_error")):
+    for key, err_key in (("value", None), ("fused_encode_hash_gibs", "fused_encode_hash_error")):
         if key not in new:
             continue
         if err_key and new.get(err_key):
