@@ -111,6 +111,14 @@ STAGES: frozenset = frozenset({
     ("codec", "encode-batch-small"),
     ("codec", "reconstruct-batch"),
     ("codec", "verify-batch"),
+    # The life of a reconstruct batch on the caller's own thread, inside
+    # reconstruct-batch, one record each per batch (object/codec.py
+    # run_device_reconstruct).
+    ("codec", "recon-pack"),
+    ("codec", "recon-h2d"),
+    ("codec", "recon-device-wait"),
+    ("codec", "recon-d2h"),
+    ("codec", "recon-unpack"),
     # The life of a full-block encode batch on the worker thread, one
     # record per batch (worker-idle: per wake-up). worker-idle + collect +
     # pack + h2d + device-wait + d2h + scatter is the worker's wall time;

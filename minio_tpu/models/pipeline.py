@@ -187,8 +187,10 @@ class ErasurePipeline:
     # -- decode / heal -----------------------------------------------------
 
     @functools.lru_cache(maxsize=256)
-    def _recon_weights(self, present: tuple[bool, ...], want: tuple[int, ...]):
-        return np.asarray(
+    def _recon_weights(self, present: tuple[bool, ...], want: tuple[int, ...]) -> jax.Array:
+        """The bit-expanded reconstruct weights of one loss pattern, resident
+        on the device: uploaded on first use, not once a batch."""
+        return jnp.asarray(
             rs_matrix.bit_expand(
                 rs_matrix.reconstruct_rows(self.geom.data, self.geom.parity, present, want)
             ).astype(np.int8)
@@ -210,8 +212,7 @@ class ErasurePipeline:
         # hash_fn is a static arg: a stable module-level function, so the jit
         # cache keys cleanly on it.
         hash_fn = hash_batch_fn() if with_digests else None
-        w = jnp.asarray(self._recon_weights(present, want))
-        return _reconstruct_step(survivors, w, hash_fn)
+        return _reconstruct_step(survivors, self._recon_weights(present, want), hash_fn)
 
     def verify_digests(self, shards) -> jax.Array:
         """[B, T, S] shards -> [B, T, 32] digests (for bitrot deep-scan)."""

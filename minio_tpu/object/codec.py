@@ -20,6 +20,7 @@ import abc
 import numpy as np
 
 from ..control import tracing
+from ..control.sanitizer import san_lock
 from ..ops import highwayhash as hh
 from ..ops import rs_matrix, rs_ref
 
@@ -46,7 +47,7 @@ class BlockCodec(abc.ABC):
         m: int,
         want: tuple[int, ...],
         with_digests: bool = False,
-    ) -> list[tuple[list[bytes], list[bytes] | None]]:
+    ) -> "list[tuple[list[bytes | memoryview], list[bytes] | None]]":
         """Rebuild `want` rows for MANY blocks sharing one present-mask.
 
         The batched analogue of `reconstruct` -- degraded GETs and heal
@@ -55,6 +56,10 @@ class BlockCodec(abc.ABC):
         erasure-lowlevel-heal.go:31), so device codecs override this to run
         one [B, K, S] program instead of B round trips. Returns, per block,
         (rebuilt chunks, their bitrot digests or None when not requested).
+        Input rows and rebuilt chunks are bytes-LIKE (buffer protocol): a
+        device codec hands back read-only memoryviews over the one array
+        its batch came home in, so consumers slice, join, write or frame
+        them and must not assume `bytes`. The 32-byte digests are `bytes`.
         """
         from ..ops import bitrot
 
@@ -310,39 +315,142 @@ def bucket_batch(n: int) -> int:
     return _RECON_BUCKETS[-1]
 
 
+class ReconStaging:
+    """Bounded free list of host staging arrays for the device reconstruct,
+    one list per [b_pad, K, S] shape (the utils/bufpool.py idiom): acquire
+    never blocks -- past `per_shape` free arrays a release drops its array,
+    and an empty list allocates. At 12+4 a window of 16 blocks stages
+    16.8 MB, so the default keeps at most 134 MB per shape. Arrays come
+    back dirty: the pack overwrites every real slot and zeroes the pad."""
+
+    def __init__(self, per_shape: int = 8):
+        self.per_shape = per_shape
+        self._lock = san_lock("ReconStaging._lock")
+        self._free: dict[tuple[int, ...], list[np.ndarray]] = {}
+        self.outstanding = 0
+
+    def acquire(self, shape: tuple[int, ...]) -> np.ndarray:
+        with self._lock:
+            self.outstanding += 1
+            free = self._free.get(shape)
+            if free:
+                return free.pop()
+        return np.empty(shape, dtype=np.uint8)
+
+    def release(self, arr: np.ndarray) -> None:
+        """Recycle: only once the program that read `arr` has finished."""
+        with self._lock:
+            self.outstanding -= 1
+            free = self._free.setdefault(arr.shape, [])
+            if len(free) < self.per_shape:
+                free.append(arr)
+
+    def discard(self, arr: np.ndarray) -> None:
+        """Give up `arr` without recycling it (a failed batch: the runtime
+        may still be reading the array)."""
+        with self._lock:
+            self.outstanding -= 1
+
+    def free_count(self) -> int:
+        with self._lock:
+            return sum(len(v) for v in self._free.values())
+
+
+def _address(buf) -> int:
+    return np.frombuffer(buf, dtype=np.uint8).__array_interface__["data"][0]
+
+
+def _shard_window(rows_batch: list, j: int, s: int) -> np.ndarray | None:
+    """Shard j's rows as ONE [B, S] strided array, when they are views at a
+    constant stride inside one exporter -- a read window's frames (stride
+    DIGEST_LEN + S over the shard's blob) or slices of a raw shard file
+    (stride S). None when the input is anything else (rows in buffers of
+    their own, a row moved elsewhere): the caller packs those row by row."""
+    rows = [r[j] for r in rows_batch]
+    if not all(isinstance(r, memoryview) for r in rows):
+        return None  # a bytes row is its own exporter
+    base = rows[0].obj
+    if any(r.obj is not base or not r.c_contiguous for r in rows):
+        return None
+    addrs = [_address(r) for r in rows]
+    stride = addrs[1] - addrs[0] if len(addrs) > 1 else s
+    if stride < s or any(b - a != stride for a, b in zip(addrs, addrs[1:])):
+        return None
+    whole = np.frombuffer(base, dtype=np.uint8)
+    return np.ndarray(
+        (len(addrs), s), dtype=np.uint8, buffer=whole,
+        offset=addrs[0] - _address(whole), strides=(stride, 1),
+    )
+
+
+def pack_survivors(staging: np.ndarray, rows_batch: list, surv: list[int], s: int) -> int:
+    """Fill staging[:B] ([b_pad, K, S]) with the surviving rows and zero the
+    pad slots; returns how many of the K shards crossed as one strided copy
+    (the rest went row by row)."""
+    b = len(rows_batch)
+    strided = 0
+    for ki, j in enumerate(surv):
+        window = _shard_window(rows_batch, j, s)
+        if window is not None:
+            staging[:b, ki, :] = window
+            strided += 1
+        else:
+            for bi, rows in enumerate(rows_batch):
+                staging[bi, ki] = np.frombuffer(rows[j], dtype=np.uint8)
+    if b < staging.shape[0]:
+        staging[b:] = 0
+    return strided
+
+
 def run_device_reconstruct(
     pipe,
+    staging: ReconStaging,
     rows_batch: list[list[bytes | None]],
     k: int,
     want: tuple[int, ...],
     surv: list[int],
     chunk_size: int,
     with_digests: bool,
-) -> list[tuple[list[bytes], list[bytes] | None]]:
-    """Assemble a uniform rows_batch into one padded [B, K, S] device
-    reconstruct program and unpack per-block results (the batching codec's
-    served decode/heal path)."""
+) -> "tuple[list[tuple[list[memoryview], list[bytes] | None]], int]":
+    """Run a uniform rows_batch as one padded [B, K, S] device reconstruct
+    program (the batching codec's served decode/heal path): the window
+    crosses host -> device -> host as arrays, once. Returns the per-block
+    results -- rebuilt rows as memoryviews over the one array the batch came
+    home in -- and the number of shards packed by one strided copy."""
     b_real = len(rows_batch)
     b_pad = max(bucket_batch(b_real), b_real)  # never allocate under b_real
     present = tuple(r is not None for r in rows_batch[0])
-    arr = np.zeros((b_pad, k, chunk_size), dtype=np.uint8)
-    for bi, rows in enumerate(rows_batch):
-        for ki, j in enumerate(surv):
-            arr[bi, ki] = np.frombuffer(rows[j], dtype=np.uint8)  # type: ignore[arg-type]
-    rebuilt, digests = pipe.reconstruct(arr, present, tuple(want), with_digests=with_digests)
-    rebuilt_np = np.asarray(rebuilt)
-    digests_np = np.asarray(digests) if with_digests else None
-    return [
-        (
-            [rebuilt_np[bi, wi].tobytes() for wi in range(len(want))],
+    w = len(want)
+    arr = staging.acquire((b_pad, k, chunk_size))
+    try:
+        with tracing.stage("recon-pack", "codec"):
+            strided = pack_survivors(arr, rows_batch, surv, chunk_size)
+        with tracing.stage("recon-h2d", "codec"):
+            rebuilt, digests = pipe.reconstruct(
+                arr, present, tuple(want), with_digests=with_digests
+            )
+        with tracing.stage("recon-device-wait", "codec"):
+            rebuilt.block_until_ready()
+        with tracing.stage("recon-d2h", "codec"):
+            rebuilt_np = np.asarray(rebuilt)
+            digests_np = np.asarray(digests) if with_digests else None
+    except BaseException:
+        staging.discard(arr)
+        raise
+    # The rebuilt rows have arrived, so the program has consumed its input:
+    # only now may another batch overwrite the staging array.
+    staging.release(arr)
+    with tracing.stage("recon-unpack", "codec"):
+        flat = memoryview(rebuilt_np.reshape(-1))
+        rows = [flat[i * chunk_size : (i + 1) * chunk_size] for i in range(b_real * w)]
+        out = [
             (
-                [digests_np[bi, wi].tobytes() for wi in range(len(want))]
-                if digests_np is not None
-                else None
-            ),
-        )
-        for bi in range(b_real)
-    ]
+                rows[bi * w : (bi + 1) * w],
+                [digests_np[bi, wi].tobytes() for wi in range(w)] if with_digests else None,
+            )
+            for bi in range(b_real)
+        ]
+    return out, strided
 
 
 def uniform_recon_plan(

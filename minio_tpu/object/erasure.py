@@ -854,8 +854,9 @@ def _whole_sum_matches(meta: FileInfo, part_number: int, blob: bytes) -> bool:
     return bitrot_mod.digest_of(blob, algo) == want
 
 
-def _frame_shard(chunks: list[bytes], digests: list[bytes]) -> bytes:
-    """Interleave digest||chunk frames (streaming bitrot file layout)."""
+def _frame_shard(chunks: list, digests: list[bytes]) -> bytes:
+    """Interleave digest||chunk frames (streaming bitrot file layout).
+    Chunks are bytes-like (a device codec rebuilds rows as memoryviews)."""
     parts: list[bytes] = []
     for d, c in zip(digests, chunks):
         parts.append(d)
@@ -1900,7 +1901,8 @@ class ErasureObjects:
         n_primaries: int,
     ) -> list:
         """Gather + verify one window's rows and return its response chunks
-        (row views on the healthy path; decoded bytes where reconstructed)."""
+        (row views on the healthy path; the codec's rebuilt rows, bytes-like,
+        where reconstructed)."""
         # Ranked rows first; spares pulled lazily on any failure (the
         # lazy-spare parallelReader discipline, erasure-decode.go:119).
         frames: list[list[tuple[memoryview, memoryview]] | None] = [None] * (k + mth)
@@ -1971,14 +1973,14 @@ class ErasureObjects:
                     results = self.codec.reconstruct_batch(
                         [rows_by_block[wi] for wi in idxs], k, mth, want
                     )
+                    rebuilt = 0
                     for wi, (chunks, _) in zip(idxs, results):
                         for slot, j in enumerate(want):
                             rows_by_block[wi][j] = chunks[slot]
-                            # Copy-ledger hop: a degraded read rebuilds
-                            # the missing rows into fresh buffers.
-                            GLOBAL_PROFILER.copy.record(
-                                "decode", COPIED, len(chunks[slot])
-                            )
+                            rebuilt += len(chunks[slot])
+                    # Copy-ledger hop: a degraded read rebuilds the missing
+                    # rows into fresh buffers -- one record a batch.
+                    GLOBAL_PROFILER.copy.record("decode", COPIED, rebuilt)
 
         # Healthy path: the response chunks ARE the data-row views -- no
         # join, no copy; _block_pieces trims the range/tail per block.
@@ -2070,17 +2072,17 @@ class ErasureObjects:
         if sum(1 for b in blobs if b is not None) < k:
             raise errors.InsufficientReadQuorum(bucket, object_name)
 
+        # Rows are views over each shard's one blob (stride chunk_full): no
+        # copy per row, and a batched reconstruct packs a shard in one copy.
+        views = [memoryview(b) if b is not None else None for b in blobs]
         for g0 in range(b0, b1 + 1, GROUP_BLOCKS):
             g1 = min(g0 + GROUP_BLOCKS - 1, b1)
-            rows_by_block: list[list[bytes | None]] = []
+            rows_by_block: list[list[memoryview | None]] = []
             for b in range(g0, g1 + 1):
                 cl = chunk_len(b)
                 off = b * chunk_full - region_off
                 rows_by_block.append(
-                    [
-                        blobs[j][off : off + cl] if blobs[j] is not None else None
-                        for j in range(k + mth)
-                    ]
+                    [v[off : off + cl] if v is not None else None for v in views]
                 )
             missing = tuple(j for j in range(k) if blobs[j] is None)
             if missing:
@@ -2374,9 +2376,9 @@ class ErasureObjects:
             blob = _read_raw(j, part)
             if not whole:
                 return _parse_frames(blob, part_chunks[part.number])
-            frames, pos = [], 0
+            frames, pos, mv = [], 0, memoryview(blob)
             for sz in part_chunks[part.number]:
-                chunk = blob[pos : pos + sz]
+                chunk = mv[pos : pos + sz]  # a view: rows at stride S in one blob
                 if len(chunk) != sz:
                     raise errors.FileCorrupt("short whole-bitrot shard file")
                 frames.append((None, chunk))

@@ -45,7 +45,13 @@ from ..control import tracing
 from ..control.perf import GLOBAL_PERF
 from ..control.profiler import COPIED, GLOBAL_PROFILER
 from ..models.pipeline import ErasurePipeline, Geometry
-from ..object.codec import BlockCodec, HostCodec
+from ..object.codec import (
+    BlockCodec,
+    HostCodec,
+    ReconStaging,
+    run_device_reconstruct,
+    uniform_recon_plan,
+)
 from ..ops import rs_matrix
 from ..parallel import mesh as mesh_lib
 from ..control.sanitizer import san_lock, san_rlock
@@ -146,6 +152,12 @@ class BatchingDeviceCodec(BlockCodec):
         self.batches_run = 0
         self.blocks_reconstructed = 0
         self.recon_batches_run = 0
+        # Of the K surviving shards of every reconstruct batch, how many
+        # crossed into the staging array as one strided copy (the rest row
+        # by row: rows in buffers of their own).
+        self.recon_shards_packed = 0
+        self.recon_shards_strided = 0
+        self._recon_staging = ReconStaging()
         self.digests_verified = 0
         self.verify_batches_run = 0
         # Padded-slot total: blocks_encoded / blocks_padded = batch occupancy
@@ -622,9 +634,10 @@ class BatchingDeviceCodec(BlockCodec):
         pattern run as ONE padded-batch device program (the served decode
         path the reference runs per block, cmd/erasure-decode.go:206,
         erasure-lowlevel-heal.go:31); tails and irregular batches fall back
-        to the host codec, mirroring the encode-side split."""
-        from ..object.codec import run_device_reconstruct, uniform_recon_plan
-
+        to the host codec, mirroring the encode-side split. The window
+        crosses as arrays, on the caller's thread: one strided copy per
+        surviving shard into a reused staging array, and the rebuilt rows
+        come back as memoryviews over the array the batch came home in."""
         with tracing.span(
             "erasure.reconstruct", "erasure", blocks=len(rows_batch), k=k, m=m
         ):
@@ -636,13 +649,16 @@ class BatchingDeviceCodec(BlockCodec):
             _, surv, s = plan
             self._ensure_worker(k, m)
             with tracing.stage("reconstruct-batch", "codec") as st:
-                out = run_device_reconstruct(
-                    self._pipelines[(k, m)], rows_batch, k, tuple(want), surv, s, with_digests
+                out, strided = run_device_reconstruct(
+                    self._pipelines[(k, m)], self._recon_staging, rows_batch, k,
+                    tuple(want), surv, s, with_digests,
                 )
             with self._stats_lock:
                 self.device_recon_seconds += st.wall
                 self.recon_batches_run += 1
                 self.blocks_reconstructed += len(rows_batch)
+                self.recon_shards_packed += k
+                self.recon_shards_strided += strided
             return out
 
     def digests_batch(self, chunks):
@@ -727,6 +743,8 @@ class BatchingDeviceCodec(BlockCodec):
                 "blocks_padded": self.blocks_padded,
                 "blocks_reconstructed": self.blocks_reconstructed,
                 "recon_batches_run": self.recon_batches_run,
+                "recon_shards_packed": self.recon_shards_packed,
+                "recon_shards_strided": self.recon_shards_strided,
                 "digests_verified": self.digests_verified,
                 "verify_batches_run": self.verify_batches_run,
                 "small_blocks_encoded": self.small_blocks_encoded,
