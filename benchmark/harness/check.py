@@ -37,7 +37,14 @@ window itself wrote:
                           the codec counted as reconstructed (each difference
                           never below 0): the device, not the host codec, did
                           the work. Nothing is added where `prepare` lost no
-                          shards
+                          shards. An acknowledged MPUT holds the full blocks
+                          of its parts, each part a stream of its own (8 of an
+                          8 MiB part, 64 of the upload)
+
+An MPUT (a whole multipart upload) is a write like a PUT: its key's record is
+the sha256 of the whole body and the ETag the parts' md5s give, both held at
+read-back; the degraded sample reads completed uploads back across their part
+boundaries.
 
 Every comparison is exact, so every limit is 0. The control (one parity shard
 fewer than the configuration states, which a run cannot tell from the outside
@@ -52,6 +59,8 @@ import random
 
 from . import window
 
+WRITES = ("PUT", "MPUT")  # what leaves a body on a key's record
+
 LIMITS = {
     "ops_failed": 0,
     "readback_mismatch": 0,
@@ -62,10 +71,10 @@ LIMITS = {
 
 
 def _acknowledged_writes(ops: list) -> list:
-    """Acknowledged PUTs and DELETEs, ramp to drain, in the order they ended.
+    """Acknowledged PUTs, MPUTs and DELETEs, ramp to drain, in the order they ended.
     Each key belongs to one client, so a key's ops are in order."""
     return [op for op in sorted(ops, key=lambda o: o[window.END])
-            if op[window.OK] and op[window.KIND] in ("PUT", "DELETE")]
+            if op[window.OK] and op[window.KIND] in WRITES + ("DELETE",)]
 
 
 def _draw(keys_oldest_first: list[str], seed: int, n: int) -> list[str]:
@@ -89,7 +98,7 @@ def degraded_sample(ops: list, t0: float, t1: float, seed: int, n: int) -> list[
     inside the window: only those still hold what the window wrote."""
     last = {op[window.KEY]: op for op in _acknowledged_writes(ops)}
     live = [op for op in last.values()
-            if op[window.KIND] == "PUT" and t0 <= op[window.END] < t1]
+            if op[window.KIND] in WRITES and t0 <= op[window.END] < t1]
     live.sort(key=lambda o: o[window.END])
     return _draw([op[window.KEY] for op in live], seed, n)
 
@@ -97,7 +106,7 @@ def degraded_sample(ops: list, t0: float, t1: float, seed: int, n: int) -> list[
 def wrote_nothing(ops: list) -> bool:
     """No PUT or DELETE was sent from ramp to drain, acknowledged or not:
     every key of a populated pool still holds what `populate` put."""
-    return not any(op[window.KIND] in ("PUT", "DELETE") for op in ops)
+    return not any(op[window.KIND] in WRITES + ("DELETE",) for op in ops)
 
 
 def pool_sample(pool_keys: list[str], seed: int, n: int) -> list[str]:
@@ -106,9 +115,19 @@ def pool_sample(pool_keys: list[str], seed: int, n: int) -> list[str]:
     return _draw(sorted(pool_keys), seed, n)
 
 
-def full_blocks_put(ops: list, block_bytes: int) -> int:
-    return sum(op[window.NBYTES] // block_bytes for op in ops
-               if op[window.OK] and op[window.KIND] == "PUT")
+def full_blocks_put(ops: list, block_bytes: int, part_bytes: int | None = None) -> int:
+    """Full blocks of the acknowledged PUTs and MPUTs. An MPUT's parts are
+    `part_bytes` each, the last the rest, and every part is a stream of its own."""
+    total = 0
+    for op in ops:
+        if not op[window.OK] or op[window.KIND] not in WRITES:
+            continue
+        n = op[window.NBYTES]
+        if op[window.KIND] == "MPUT":
+            total += n // part_bytes * (part_bytes // block_bytes) + n % part_bytes // block_bytes
+        else:
+            total += n // block_bytes
+    return total
 
 
 def degraded_blocks_got(ops: list, degraded: set[str], block_bytes: int) -> int:
@@ -119,7 +138,7 @@ def degraded_blocks_got(ops: list, degraded: set[str], block_bytes: int) -> int:
     never = float("inf")
     rewritten: dict[str, float] = {}
     for op in ops:
-        if op[window.KIND] in ("PUT", "DELETE") and op[window.KEY] in degraded:
+        if op[window.KIND] in WRITES + ("DELETE",) and op[window.KEY] in degraded:
             rewritten[op[window.KEY]] = min(rewritten.get(op[window.KEY], never), op[window.START])
     return sum(op[window.NBYTES] // block_bytes for op in ops
                if op[window.OK] and op[window.KIND] == "GET" and op[window.KEY] in degraded

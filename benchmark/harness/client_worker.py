@@ -26,6 +26,13 @@ The client is the reference of the comparison that decides ``correct``: it
 owns its keys (no other client touches them), remembers for each the sha256
 of the last body the server acknowledged or that the key was deleted, and
 holds every answer (GET body, HEAD length, 404 after DELETE) to that record.
+
+An ``MPUT`` is a whole multipart upload, the way an SDK sends a large object:
+Create, every part (``multipart.parts_in_flight`` at once, each on a connection
+of its own from a small pool of threads in this process), Complete. The client
+keeps its own account of it: the md5 of each part, held against the ``ETag``
+the UploadPart answered; ``md5(the parts' binary md5s) + "-N"``, held against
+the Complete's ``ETag`` inside the op and against the GET's at read-back.
 """
 
 from __future__ import annotations
@@ -36,10 +43,13 @@ import hmac
 import http.client
 import json
 import math
+import queue
 import random
+import re
 import sys
 import time
 import urllib.parse
+from concurrent.futures import ThreadPoolExecutor
 
 ALGORITHM = "AWS4-HMAC-SHA256"
 EMPTY_SHA256 = hashlib.sha256(b"").hexdigest()
@@ -110,17 +120,21 @@ class S3Conn:
             self.conn = None
 
     def request(self, method: str, path: str, body=b"", body_sha256: str = EMPTY_SHA256,
-                into: bytearray | None = None) -> tuple[int, int, dict, bytes]:
+                into: bytearray | None = None,
+                query: tuple[tuple[str, str], ...] = ()) -> tuple[int, int, dict, bytes]:
         """(status, body length, headers, body). A 200 GET body is read into
         ``into`` when given (no per-op allocation); other bodies are returned."""
-        headers = sign(self.access, self.secret, self.region, method, self.host, path, [],
-                       body_sha256)
+        headers = sign(self.access, self.secret, self.region, method, self.host, path,
+                       list(query), body_sha256)
         headers["content-length"] = str(len(body))
+        url = urllib.parse.quote(path)
+        if query:
+            url += "?" + "&".join(f"{_uri_encode(k)}={_uri_encode(v)}" for k, v in query)
         for attempt in (0, 1):
             if self.conn is None:
                 self.conn = http.client.HTTPConnection(self.host, timeout=self.timeout_s)
             try:
-                self.conn.request(method, urllib.parse.quote(path), body=body, headers=headers)
+                self.conn.request(method, url, body=body, headers=headers)
                 resp = self.conn.getresponse()
                 break
             except (http.client.RemoteDisconnected, BrokenPipeError, ConnectionResetError):
@@ -185,7 +199,44 @@ class Client:
         # would leave the mix of a window to the seed.
         unit = math.gcd(*mix.values())
         self.deck_ops = [op for op, share in sorted(mix.items()) for _ in range(share // unit)]
-        self.put_only = set(mix) == {"PUT"}
+        # Traffic of one kind of write cycles the ring; what a mix puts back
+        # where nothing is left to read is its own kind of write.
+        self.ring_op = next(iter(mix)) if set(mix) in ({"PUT"}, {"MPUT"}) else None
+        self.write_op = "MPUT" if "MPUT" in mix and "PUT" not in mix else "PUT"
+        self.etags: dict[str, str] = {}  # key -> ETag due at read-back (multipart objects)
+        self.open_uploads: list[tuple[str, str]] = []  # (key, upload id) no Complete closed
+        self.part_ends: list[float] = []  # when each UploadPart of a run answered 200
+        self.mp = spec.get("multipart")
+        if self.mp:
+            self._prepare_parts()
+
+    def _prepare_parts(self) -> None:
+        """The client's own account of every upload it can send: for each body
+        slice its parts' (start, end, sha256 to sign, md5), and the ETag the
+        object is due. Plus the part senders: a thread and a connection each."""
+        part = int(self.mp["part_bytes"])
+        self.parts, self.mp_etags = [], []
+        for body in self.bodies:
+            cuts = [(a, min(a + part, self.size)) for a in range(0, self.size, part)]
+            rows = [(a, b, hashlib.sha256(body[a:b]).hexdigest(), hashlib.md5(body[a:b]).digest())
+                    for a, b in cuts]
+            self.parts.append(rows)
+            self.mp_etags.append(
+                hashlib.md5(b"".join(r[3] for r in rows)).hexdigest() + f"-{len(rows)}")
+        n = max(1, min(int(self.mp["parts_in_flight"]), len(self.parts[0])))
+        spec = self.spec
+        self.part_conns: queue.SimpleQueue = queue.SimpleQueue()
+        for _ in range(n):
+            self.part_conns.put(S3Conn(spec["endpoint"], spec["access"], spec["secret"],
+                                       spec["region"], spec["timeout_s"]))
+        self.part_pool = ThreadPoolExecutor(max_workers=n, thread_name_prefix="part")
+
+    def close(self) -> None:
+        self.s3.close()
+        if self.mp:
+            self.part_pool.shutdown(wait=True)
+            while not self.part_conns.empty():
+                self.part_conns.get().close()
 
     def path(self, key: str) -> str:
         return f"/{self.bucket}/{key}"
@@ -196,15 +247,95 @@ class Client:
         i = self.rnd.randrange(N_OFFSETS)
         status, _, _, data = self.s3.request("PUT", self.path(key), self.bodies[i], self.shas[i])
         if status != 200:
-            # Unknown whether it landed: the key's record is void until the next PUT.
-            self.state.pop(key, None)
+            self._void(key)
             return False, 0, f"PUT {key}: HTTP {status} {data[:200]!r}"
         self.state[key] = self.shas[i]
+        self.etags.pop(key, None)
         return True, self.size, ""
+
+    def _void(self, key: str) -> None:
+        """Unknown whether a write landed: the key's record is void until the next one."""
+        self.state.pop(key, None)
+        self.etags.pop(key, None)
+
+    def _send_part(self, key: str, upload_id: str, number: int, body, sha: str,
+                   md5: bytes) -> tuple[str, str]:
+        """One UploadPart on a connection of its own: (the ETag answered, "")
+        or ("", what went wrong). The ETag is held to the client's own md5."""
+        conn = self.part_conns.get()
+        try:
+            status, _, hdrs, data = conn.request(
+                "PUT", self.path(key), body, sha,
+                query=(("partNumber", str(number)), ("uploadId", upload_id)))
+        except (OSError, http.client.HTTPException) as e:
+            conn.close()
+            return "", f"part {number}: {type(e).__name__}: {e}"
+        finally:
+            self.part_conns.put(conn)
+        if status != 200:
+            return "", f"part {number}: HTTP {status} {data[:200]!r}"
+        etag = hdrs.get("etag", "").strip('"')
+        self.part_ends.append(time.monotonic())
+        if etag != md5.hex():
+            return "", f"part {number}: ETag {etag!r}, the part's md5 is {md5.hex()}"
+        return etag, ""
+
+    def mput(self, key: str) -> tuple[bool, int, str]:
+        """Create, the parts (at most `parts_in_flight` at once), Complete.
+        It counts when the Complete answered 200 with the ETag due, and only then."""
+        i = self.rnd.randrange(N_OFFSETS)
+        body, rows = self.bodies[i], self.parts[i]
+        self._void(key)
+        status, _, _, data = self.s3.request("POST", self.path(key), query=(("uploads", ""),))
+        found = re.search(rb"<UploadId>([^<]+)</UploadId>", data) if status == 200 else None
+        if not found:
+            return False, 0, f"MPUT {key}: Create: HTTP {status} {data[:200]!r}"
+        upload_id = found.group(1).decode()
+        self.open_uploads.append((key, upload_id))
+        sent = [self.part_pool.submit(self._send_part, key, upload_id, n, body[a:b], sha, md5)
+                for n, (a, b, sha, md5) in enumerate(rows, start=1)]
+        answers = [f.result() for f in sent]
+        bad = [why for _, why in answers if why]
+        if bad:
+            return False, 0, f"MPUT {key}: {bad[0]}"
+        return self._complete(key, upload_id, [etag for etag, _ in answers], i)
+
+    def _complete(self, key: str, upload_id: str, etags: list[str],
+                  i: int) -> tuple[bool, int, str]:
+        """The Complete of an upload of body slice `i`, naming `etags` in order."""
+        doc = ("<CompleteMultipartUpload>" + "".join(
+            f"<Part><PartNumber>{n}</PartNumber><ETag>&quot;{e}&quot;</ETag></Part>"
+            for n, e in enumerate(etags, start=1)) + "</CompleteMultipartUpload>").encode()
+        status, _, _, data = self.s3.request(
+            "POST", self.path(key), doc, hashlib.sha256(doc).hexdigest(),
+            query=(("uploadId", upload_id),))
+        if status != 200:
+            return False, 0, f"MPUT {key}: Complete: HTTP {status} {data[:200]!r}"
+        self.open_uploads.remove((key, upload_id))
+        found = re.search(rb"<ETag>(?:&quot;|&#34;|\")?([0-9a-f-]+)", data)
+        if not found or found.group(1).decode() != self.mp_etags[i]:
+            return False, 0, (f"MPUT {key}: Complete answered ETag "
+                              f"{found.group(1) if found else data[:200]!r}, "
+                              f"due {self.mp_etags[i]}")
+        self.state[key] = self.shas[i]
+        self.etags[key] = self.mp_etags[i]
+        return True, self.size, ""
+
+    def abort_open_uploads(self) -> int:
+        """At drain, outside the window: uploads no Complete closed are aborted."""
+        n = 0
+        for key, upload_id in self.open_uploads:
+            try:
+                self.s3.request("DELETE", self.path(key), query=(("uploadId", upload_id),))
+            except (OSError, http.client.HTTPException):
+                self.s3.close()
+            n += 1
+        self.open_uploads.clear()
+        return n
 
     def get(self, key: str) -> tuple[bool, int, str]:
         want = self.state.get(key)
-        status, n, _, data = self.s3.request("GET", self.path(key), into=self.scratch)
+        status, n, hdrs, data = self.s3.request("GET", self.path(key), into=self.scratch)
         if want is None:
             ok = status == 404
             return ok, 0, "" if ok else f"GET {key}: HTTP {status}, the key was deleted"
@@ -214,6 +345,9 @@ class Client:
             return False, 0, f"GET {key}: {n} bytes, stored {self.size}"
         if hashlib.sha256(self.scratch).hexdigest() != want:
             return False, 0, f"GET {key}: sha256 differs from the acknowledged PUT"
+        due = self.etags.get(key)
+        if due is not None and hdrs.get("etag", "").strip('"') != due:
+            return False, 0, f"GET {key}: ETag {hdrs.get('etag')!r}, the upload's parts give {due}"
         return True, n, ""
 
     def stat(self, key: str) -> tuple[bool, int, str]:
@@ -230,43 +364,45 @@ class Client:
     def delete(self, key: str) -> tuple[bool, int, str]:
         status, _, _, data = self.s3.request("DELETE", self.path(key))
         if status not in (200, 204):
-            self.state.pop(key, None)
+            self._void(key)
             return False, 0, f"DELETE {key}: HTTP {status} {data[:200]!r}"
         self.state[key] = None
+        self.etags.pop(key, None)
         return True, 0, ""
 
     # -- the op generator --------------------------------------------------------
 
     def next_op(self) -> tuple[str, str]:
-        """(kind, key). PUT-only traffic cycles the ring. A mix is dealt from a
-        shuffled deck that holds it exactly, so every seed sends the same mix in
-        another order; reads and deletes take a present key, a PUT re-creates a deleted
-        key first and overwrites otherwise."""
-        if self.put_only:
+        """(kind, key). Traffic of PUTs alone, or of MPUTs alone, cycles the
+        ring. A mix is dealt from a shuffled deck that holds it exactly, so
+        every seed sends the same mix in another order; reads and deletes take
+        a present key, a PUT or MPUT re-creates a deleted key first and
+        overwrites otherwise."""
+        if self.ring_op:
             key = self.keys[self.ring_next % len(self.keys)]
             self.ring_next += 1
-            return "PUT", key
+            return self.ring_op, key
         if not self.deck:
             self.deck = list(self.deck_ops)
             self.rnd.shuffle(self.deck)
         kind = self.deck.pop()
         present = [k for k in self.keys if self.state.get(k) is not None]
         absent = [k for k in self.keys if self.state.get(k) is None]
-        if kind == "PUT":
+        if kind in ("PUT", "MPUT"):
             return kind, self.rnd.choice(absent or present)
         if not present or (kind == "DELETE" and len(present) <= len(self.keys) // 2):
             # Nothing to read, or half the share already deleted: put one back.
-            return "PUT", self.rnd.choice(absent)
+            return self.write_op, self.rnd.choice(absent)
         return kind, self.rnd.choice(present)
 
     def do(self, kind: str, key: str) -> tuple[bool, int, str]:
         try:
-            return {"PUT": self.put, "GET": self.get, "STAT": self.stat,
+            return {"PUT": self.put, "MPUT": self.mput, "GET": self.get, "STAT": self.stat,
                     "DELETE": self.delete}[kind](key)
         except (OSError, http.client.HTTPException) as e:
             self.s3.close()
-            if kind in ("PUT", "DELETE"):
-                self.state.pop(key, None)
+            if kind in ("PUT", "MPUT", "DELETE"):
+                self._void(key)
             return False, 0, f"{kind} {key}: {type(e).__name__}: {e}"
 
     # -- commands ----------------------------------------------------------------
@@ -284,6 +420,7 @@ class Client:
         """Closed loop from `start` (this client's place in the ramp) until the
         op in flight at `t1` has answered."""
         ops, errors = [], []
+        self.part_ends = []
         while time.monotonic() < start:
             time.sleep(min(0.005, max(0.0, start - time.monotonic())))
         began = time.monotonic()
@@ -305,10 +442,14 @@ class Client:
         cpu_w1 = (time.monotonic(), time.process_time())
         if cpu_w0 is None:
             cpu_w0 = (began, cpu0)
-        return {
+        out = {
             "ops": ops, "errors": errors, "late_s": began - start,
             "cpu_s": cpu_w1[1] - cpu_w0[1], "cpu_wall_s": cpu_w1[0] - cpu_w0[0],
         }
+        if self.mp:
+            out["part_ends"] = self.part_ends
+            out["uploads_aborted"] = self.abort_open_uploads()
+        return out
 
     def verify(self, keys: list[str] | None) -> dict:
         """GET the given keys (all with a record, when None) and hold each to
@@ -348,7 +489,7 @@ def main(argv: list[str]) -> int:
             break
         else:
             say({"error": f"unknown command {what!r}"})
-    client.s3.close()
+    client.close()
     return 0
 
 

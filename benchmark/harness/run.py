@@ -29,6 +29,7 @@ from .server import (  # noqa: E402
 from .traffic import ROOT, Cell  # noqa: E402
 
 WORKER = os.path.join(os.path.dirname(os.path.abspath(__file__)), "client_worker.py")
+REAPER = os.path.join(os.path.dirname(os.path.abspath(__file__)), "reaper.py")
 TRACE_DIR = os.path.join(ROOT, ".bench_trace")  # git-ignored; emptied by every traced run
 CONTROLS = ("parity-1",)
 
@@ -100,6 +101,34 @@ class Clients:
                 p.stdout.close()
 
 
+class Reaper:
+    """The sweep of superseded data directories (reaper.py), from the ramp's
+    first op until the drain's last."""
+
+    def __init__(self, dep: Deployment, keep: int, every_s: float):
+        self.proc = subprocess.Popen(
+            [sys.executable, REAPER, BUCKET, str(keep), str(every_s), *dep.drive_dirs],
+            stdin=subprocess.PIPE, stdout=subprocess.PIPE, text=True)
+
+    def close(self) -> dict | None:
+        """Stop the sweep and wait for it: what it removed, or None."""
+        try:
+            self.proc.stdin.close()  # the sweep ends when its stdin does
+        except OSError:
+            pass
+        try:
+            self.proc.wait(30)
+        except subprocess.TimeoutExpired:
+            self.proc.kill()
+            self.proc.wait(10)
+        out = self.proc.stdout.read()
+        self.proc.stdout.close()
+        try:
+            return json.loads(out.strip().splitlines()[-1])
+        except (ValueError, IndexError):
+            return None
+
+
 def sleep_until(t: float) -> None:
     while True:
         left = t - time.monotonic()
@@ -120,6 +149,7 @@ def run_window(cell: Cell, dep: Deployment, clients: Clients, seed: int, seconds
     the log lines."""
     t = cell.traffic
     degraded: set[str] = set()  # keys the prepare step left short of data shards
+    reaper = None
     try:
         ready = clients.gather()
         say(f"{len(ready)} clients ready; slowest body preparation "
@@ -140,6 +170,9 @@ def run_window(cell: Cell, dep: Deployment, clients: Clients, seed: int, seconds
                     f"{len(degraded)} objects")
 
         before = dep.snapshot()
+        if "reap_superseded" in t:
+            reaper = Reaper(dep, int(t["reap_superseded"]["keep"]),
+                            float(t["reap_superseded"]["every_s"]))
         go = time.monotonic() + 0.25
         t0 = go + float(t["ramp_s"])
         t1 = t0 + seconds
@@ -167,6 +200,9 @@ def run_window(cell: Cell, dep: Deployment, clients: Clients, seed: int, seconds
         snap_b, cpu_b = dep.snapshot(), time.process_time()
 
         results = clients.gather()
+        if reaper is not None:
+            swept, reaper = reaper.close(), None
+            say(f"superseded data directories removed from ramp to drain: {json.dumps(swept)}")
         after = dep.snapshot()
         device = dep.device()  # the peak is read before the check drives the device again
 
@@ -177,6 +213,10 @@ def run_window(cell: Cell, dep: Deployment, clients: Clients, seed: int, seconds
             + " ".join(f"{x:.3f}" for x in late))
         say("ops by type (touching the window / ended inside / failed ramp to drain): "
             + json.dumps(window.counts_by_kind(ops, a, b)))
+        if cell.part_bytes:
+            say(f"UploadParts answered 200 from ramp to drain: "
+                f"{sum(len(r['part_ends']) for r in results)}; uploads no Complete closed, "
+                f"aborted at drain: {sum(r['uploads_aborted'] for r in results)}")
         for r in results:
             for e in r["errors"]:
                 say(f"client error: {e}")
@@ -186,21 +226,16 @@ def run_window(cell: Cell, dep: Deployment, clients: Clients, seed: int, seconds
         numbers, stored, live = run_check(cell, dep, clients, ops, (a, b), seed, before, after,
                                           degraded)
     finally:
+        if reaper is not None:
+            reaper.close()
         clients.close()
 
     # -- numbers ---------------------------------------------------------------------
     e2e = window.end_to_end(ops, a, b)
     e2e["setup_s"] = setup_s
-    inside = window.ended_inside(ops, a, b)
-    puts = [op for op in ops if op[window.KIND] == "PUT"]
-    gets = [op for op in ops if op[window.KIND] == "GET"]
     user_live = live * int(t["object_bytes"])
     facts = {
-        "ops_ended": len(inside),
-        "puts_ended": len([op for op in inside if op[window.KIND] == "PUT"]),
-        "put_MiB": window.prorated_rate(puts, a, b, by_bytes=True) * (b - a) / window.MIB,
-        "gets_ended": len([op for op in inside if op[window.KIND] == "GET"]),
-        "get_MiB": window.prorated_rate(gets, a, b, by_bytes=True) * (b - a) / window.MIB,
+        **op_facts(ops, a, b, [end for r in results for end in r.get("part_ends", ())]),
         "client_cpu": (sum(r["cpu_s"] for r in results)
                        / (sum(r["cpu_wall_s"] for r in results) / len(results))),
         "stored_per_user_byte": stored / user_live if user_live else None,
@@ -227,6 +262,25 @@ def run_window(cell: Cell, dep: Deployment, clients: Clients, seed: int, seconds
         "attempted": len(ops), "failed": int(numbers["ops_failed"]),
         "compiles_in_window": (snap_b["compiles"] - snap_a["compiles"]
                                + snap_b["cache_entries"] - snap_a["cache_entries"]),
+    }
+
+
+def op_facts(ops: list, a: float, b: float, part_ends: list[float]) -> dict:
+    """What the window [a, b) holds of the clients' ops, for the per-layer
+    readers to divide by. A completed MPUT is one op, one of `puts_ended`, its
+    bytes in `put_MiB`: a PUT-path metric reads per object and per MiB under
+    either kind of write. `part_ends`: when each UploadPart answered 200."""
+    inside = window.ended_inside(ops, a, b)
+    puts = [op for op in ops if op[window.KIND] in check.WRITES]
+    gets = [op for op in ops if op[window.KIND] == "GET"]
+    return {
+        "ops_ended": len(inside),
+        "puts_ended": len([op for op in inside if op[window.KIND] in check.WRITES]),
+        "mputs_ended": len([op for op in inside if op[window.KIND] == "MPUT"]),
+        "parts_ended": sum(a <= end < b for end in part_ends),
+        "put_MiB": window.prorated_rate(puts, a, b, by_bytes=True) * (b - a) / window.MIB,
+        "gets_ended": len([op for op in inside if op[window.KIND] == "GET"]),
+        "get_MiB": window.prorated_rate(gets, a, b, by_bytes=True) * (b - a) / window.MIB,
     }
 
 
@@ -265,7 +319,7 @@ def run_check(cell: Cell, dep: Deployment, clients: Clients, ops: list,
     block = cell.config["block_bytes"]
     codec = {name: after["codec"].get(name, 0) - before["codec"].get(name, 0)
              for name in ("blocks_encoded", "blocks_reconstructed", "host_fallback_recon_blocks")}
-    sent_blocks = check.full_blocks_put(ops, block)
+    sent_blocks = check.full_blocks_put(ops, block, cell.part_bytes)
     got_blocks = check.degraded_blocks_got(ops, degraded, block)
     numbers["device_blocks_missing"] = (
         max(0, sent_blocks - codec["blocks_encoded"])
@@ -277,6 +331,13 @@ def run_check(cell: Cell, dep: Deployment, clients: Clients, ops: list,
         f"{len(sample)} more ({drawn_from}) with {lost_all} data shards removed; device "
         f"encoded {codec['blocks_encoded']} blocks, clients' acknowledged PUTs held "
         f"{sent_blocks}")
+    if cell.part_bytes:
+        checked = dep.snapshot()
+        recon = {name: checked["codec"].get(name, 0) - after["codec"].get(name, 0)
+                 for name in ("blocks_reconstructed", "host_fallback_recon_blocks")}
+        say(f"the check's degraded read-back, across part boundaries: device reconstructed "
+            f"{recon['blocks_reconstructed']} blocks, host_fallback_recon_blocks moved by "
+            f"{recon['host_fallback_recon_blocks']}")
     if degraded:
         say(f"device reconstructed {codec['blocks_reconstructed']} blocks ramp to drain "
             f"(blocks_reconstructed), acknowledged GETs of the {len(degraded)} keys the prepare "
@@ -342,7 +403,7 @@ def execute(args, deployment_hook=None) -> tuple[int, dict | None]:
     clients = Clients(cell, args.seed, dep.endpoint)
     line = None
     try:
-        dep.start(cell.footprint_bytes())
+        dep.start(cell.footprint_bytes(), cell.traffic.get("mallopt"))
         import jax
 
         if not args.rehearse and jax.device_count() != cell.chips:

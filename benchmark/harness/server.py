@@ -37,6 +37,10 @@ PINNED_ENV = (
 )
 
 
+# glibc's mallopt(3) parameters a traffic file may pin (name -> number in <malloc.h>)
+MALLOPT_PARAMS = {"M_TRIM_THRESHOLD": -1, "M_TOP_PAD": -2, "M_MMAP_THRESHOLD": -3}
+
+
 class SetupError(Exception):
     """The run cannot be made: no chip, no room, a server that did not start."""
 
@@ -107,7 +111,9 @@ class Deployment:
 
     # -- start -------------------------------------------------------------------
 
-    def start(self, need_bytes: int) -> None:
+    def start(self, need_bytes: int, mallopt: dict[str, int] | None = None) -> None:
+        if mallopt:
+            self.pin_allocator(mallopt)
         if not os.path.isdir(SHM):
             raise SetupError(f"{SHM} is not there: the drives are memory-backed or nothing")
         clean_stale()
@@ -167,6 +173,28 @@ class Deployment:
     def _on_event(self, event: str, duration: float, **_kw) -> None:
         if "backend_compile" in event:
             self.compile_events.append(time.monotonic())
+
+    @staticmethod
+    def pin_allocator(pins: dict[str, int]) -> None:
+        """Fix glibc malloc's policy for this process, the server's:
+        ``mallopt(3)`` for each of `pins` (MALLOPT_PARAMS names them), which is what ``MALLOC_TOP_PAD_`` and its kin in the server's
+        environment do at its start; this process has started already when a
+        cell's files are read. Left alone the policy is a matter of history: the
+        mmap and trim thresholds rise for good when a large chunk happens to be
+        freed, and an arena's 64 MiB heaps are unmapped and mapped again, or
+        kept, by what else lies in them. A batch's arrays (16 to 64 MiB each) are
+        then faulted in page by page, or not, from some moment of chance on, and
+        a run of a minute reads when that moment came (PERF.md section 6, PR 34)."""
+        import ctypes
+
+        try:
+            mallopt = ctypes.CDLL(None).mallopt
+        except (OSError, AttributeError) as e:
+            raise SetupError(f"no mallopt to pin the allocator with: {e}") from e
+        mallopt.argtypes, mallopt.restype = (ctypes.c_int, ctypes.c_int), ctypes.c_int
+        for name, value in pins.items():
+            if mallopt(MALLOPT_PARAMS[name], value) != 1:
+                raise SetupError(f"mallopt({name}, {value}) was refused")
 
     # -- sources the per-layer readers take ----------------------------------------
 

@@ -18,7 +18,8 @@ from __future__ import annotations
 import json
 import os
 
-from . import readers, roofline
+from . import check, readers, roofline
+from .server import MALLOPT_PARAMS
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 BENCH_DIR = os.path.dirname(HERE)
@@ -33,13 +34,17 @@ CONFIG_FIELDS = {
 TRAFFIC_FIELDS = {
     "name", "source", "reduced", "assumed", "why", "loop", "clients", "object_bytes",
     "mix", "keys", "prepare", "typical_op_s", "ramp_s", "trace_seconds", "check",
-    "op_timeout_s", "tmpfs_bytes", "tmpfs_why",
+    "op_timeout_s", "tmpfs_bytes", "tmpfs_why", "multipart", "reap_superseded", "mallopt",
 }
 METRIC_FIELDS = {"name", "unit", "better", "layer", "moves", "source", "workloads",
                  "reader", "what"}
 PREPARE_STEPS = {"populate", "lose_shards"}
 CHECK_FIELDS = {"readback_sample", "degraded_sample"}
-OPS = {"PUT", "GET", "STAT", "DELETE"}
+MULTIPART_FIELDS = {"part_bytes", "parts_in_flight"}
+REAP_FIELDS = {"keep", "every_s"}
+MALLOPT_MAX = 2**31 - 1  # mallopt takes an int
+OPS = {*check.WRITES, "GET", "STAT", "DELETE"}
+MIN_PART_BYTES = 5 << 20  # S3's least part, the last excepted (the program holds a Complete to it)
 
 
 def _load(path: str, fields: set[str], what: str) -> dict:
@@ -87,8 +92,32 @@ def load_traffic(name: str) -> dict:
         raise ValueError(f"traffic mix {name}: mix must share 100 among {sorted(OPS)}")
     if t["keys"].get("kind") not in ("ring", "pool"):
         raise ValueError(f"traffic mix {name}: keys.kind must be ring or pool")
-    if set(t["mix"]) != {"PUT"} and t["keys"]["kind"] != "pool":
+    # a ring of keys takes one kind of write and nothing else
+    if set(t["mix"]) not in ({w} for w in check.WRITES) and t["keys"]["kind"] != "pool":
         raise ValueError(f"traffic mix {name}: reads and deletes need keys.kind pool")
+    if ("MPUT" in t["mix"]) != ("multipart" in t):
+        raise ValueError(f"traffic mix {name}: MPUT in the mix and the field multipart "
+                         "come together or not at all")
+    if "multipart" in t:
+        mp = t["multipart"]
+        if set(mp) != MULTIPART_FIELDS:
+            raise ValueError(f"traffic mix {name}: multipart takes {sorted(MULTIPART_FIELDS)}")
+        several = t["object_bytes"] > mp["part_bytes"]
+        if mp["parts_in_flight"] < 1 or (several and mp["part_bytes"] < MIN_PART_BYTES):
+            raise ValueError(f"traffic mix {name}: multipart wants parts_in_flight >= 1 and "
+                             f"part_bytes >= {MIN_PART_BYTES} where an object has more than one")
+    if "reap_superseded" in t:
+        reap = t["reap_superseded"]
+        if (set(reap) != REAP_FIELDS or reap["keep"] < 2 or reap["every_s"] <= 0
+                or t["keys"]["kind"] != "ring"):
+            raise ValueError(f"traffic mix {name}: reap_superseded takes {sorted(REAP_FIELDS)}, "
+                             "keep >= 2 (the version xl.meta names and the one before), "
+                             "every_s > 0, and a ring of keys")
+    pins = t.get("mallopt", {"M_TOP_PAD": 0})
+    if not pins or set(pins) - set(MALLOPT_PARAMS) or not all(
+            isinstance(v, int) and 0 <= v <= MALLOPT_MAX for v in pins.values()):
+        raise ValueError(f"traffic mix {name}: mallopt pins some of {sorted(MALLOPT_PARAMS)} "
+                         f"to whole numbers from 0 to {MALLOPT_MAX}")
     for step in t.get("prepare", []):
         if len(step) != 1 or set(step) - PREPARE_STEPS:
             raise ValueError(f"traffic mix {name}: unknown prepare step {step}")
@@ -139,7 +168,13 @@ class Cell:
         """The sandbox rehearsal's tiny sizes: the same files, control flow and
         check, on a CPU that encodes a few MiB a second."""
         t = self.traffic
-        t["object_bytes"] = min(int(t["object_bytes"]), 2 << 20)
+        if "multipart" in t:
+            # The least upload with more than one part: S3's 5 MiB, then a short last one.
+            t["object_bytes"] = MIN_PART_BYTES + (3 << 19)
+            t["multipart"] = {"part_bytes": MIN_PART_BYTES, "parts_in_flight":
+                              min(2, int(t["multipart"]["parts_in_flight"]))}
+        else:
+            t["object_bytes"] = min(int(t["object_bytes"]), 2 << 20)
         t["clients"] = min(int(t["clients"]), 4)
         if t["keys"]["kind"] == "pool":
             t["keys"] = {"kind": "pool", "objects": 3 * t["clients"]}
@@ -169,9 +204,17 @@ class Cell:
     def client_spec(self, idx: int, seed: int, endpoint: str, bucket: str,
                     access: str, secret: str, region: str) -> dict:
         t = self.traffic
-        return {
+        spec = {
             "client": idx, "clients": self.clients, "seed": seed, "endpoint": endpoint,
             "bucket": bucket, "access": access, "secret": secret, "region": region,
             "object_bytes": int(t["object_bytes"]), "mix": t["mix"], "keys": t["keys"],
             "timeout_s": float(t.get("op_timeout_s", 120.0)),
         }
+        if "multipart" in t:
+            spec["multipart"] = t["multipart"]
+        return spec
+
+    @property
+    def part_bytes(self) -> int | None:
+        """The size of an MPUT's parts (the last takes the rest), or None."""
+        return int(self.traffic["multipart"]["part_bytes"]) if "multipart" in self.traffic else None

@@ -42,7 +42,7 @@ def main(argv: list[str]) -> int:
     dep = Deployment(cell.config, rehearse=args.rehearse, parity=parity)
     bad = 0
     try:
-        dep.start(cell.footprint_bytes())
+        dep.start(cell.footprint_bytes(), cell.traffic.get("mallopt"))
         run.make_bucket(dep)
         for seed in (int(s) for s in args.seeds.split(",")):
             dep.wipe_objects()  # every overwrite leaves its old data directory behind

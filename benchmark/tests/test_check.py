@@ -45,6 +45,27 @@ def test_degraded_blocks_got_counts_acknowledged_gets_of_degraded_keys_until_rew
     assert check.full_blocks_put(ops, MIB) == 0  # the one PUT was not acknowledged
 
 
+def test_an_mput_is_a_write_and_holds_the_full_blocks_of_its_parts():
+    ops = [
+        op("MPUT", "c000/k0000", 1, 2, 64 * MIB),             # 8 parts of 8 MiB: 64 blocks
+        op("MPUT", "c000/k0001", 2, 3, 0, ok=False),          # no Complete: nothing is due
+        op("MPUT", "c000/k0002", 3, 9, 64 * MIB),
+        op("PUT", "c000/k0003", 4, 5, 3 * MIB + 7),
+    ]
+    assert check.full_blocks_put(ops, MIB, 8 * MIB) == 64 + 64 + 3
+    # parts of 5.5 MiB, the last 2.25: each part is a stream of its own, tails are no full blocks
+    odd = [op("MPUT", "c000/k0000", 1, 2, 13 * MIB + (MIB >> 2))]
+    assert check.full_blocks_put(odd, MIB, 5 * MIB + (MIB >> 1)) == 5 + 5 + 2
+    assert not check.wrote_nothing(ops[1:2])
+    assert check.readback_sample(ops, 3, 12)[0] == "c000/k0002"  # the newest acknowledged write
+    assert sorted(check.readback_sample(ops, 3, 12)) == ["c000/k0000", "c000/k0002", "c000/k0003"]
+    # the degraded sample: uploads whose Complete answered inside the window
+    assert sorted(check.degraded_sample(ops, 0.0, 8.0, 3, 4)) == ["c000/k0000", "c000/k0003"]
+    assert check.degraded_blocks_got(
+        [op("GET", "c000/k0000", 1, 2, 64 * MIB), op("MPUT", "c000/k0000", 2, 3, 64 * MIB),
+         op("GET", "c000/k0000", 3, 4, 64 * MIB)], {"c000/k0000"}, MIB) == 64
+
+
 def test_every_limit_is_zero_and_a_number_not_read_fails():
     good = dict.fromkeys(check.LIMITS, 0)
     assert list(check.LIMITS) == ["ops_failed", "readback_mismatch", "degraded_mismatch",

@@ -6,7 +6,7 @@ import re
 
 import pytest
 
-from benchmark.harness import readers, traffic
+from benchmark.harness import readers, run, traffic
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
@@ -81,7 +81,7 @@ def test_every_data_file_on_disk_loads_and_read_only_cells_have_a_pool_to_check(
             assert load(f[:-5])["name"] == f[:-5]
     for w in man["workloads"]:
         cell = traffic.Cell(w["name"], man)
-        if "PUT" not in cell.traffic["mix"]:
+        if not {"PUT", "MPUT"} & set(cell.traffic["mix"]):
             # nothing is PUT in the window: the degraded sample needs the populated pool
             assert cell.populates, w["name"]
         assert cell.lost_data <= cell.config["guarantees"]["drives_lost_tolerated"]
@@ -125,6 +125,37 @@ def test_metric_files_match_manifest_entries(man):
     on_disk = {f[:-5] for f in os.listdir(os.path.join(traffic.BENCH_DIR, "metrics"))}
     assert on_disk == {e["name"] for e in man["per_layer"]}
     assert all("\n" not in layer and len(layer) <= 200 for layer in layers)
+
+
+def test_the_multipart_cell_its_metrics_and_the_facts_they_divide_by(man):
+    """PR 34: six cells, all on one chip; `throughput` in four; the PUT-path
+    metrics the multipart path feeds take the cell, five new ones read it alone;
+    every ledger reader's `per` is a fact the harness counts."""
+    cell_name = "mpput64m-p8m-c4"
+    assert [w["name"] for w in man["workloads"]][-1] == cell_name
+    assert len(man["workloads"]) == 6 and all(w["chips"] == 1 for w in man["workloads"])
+    e2e = {m["name"]: m for m in man["end_to_end"]}
+    assert e2e["throughput"]["workloads"] == ["put64m-c8", "put64m-c8-ec4p4",
+                                              "degraded-get64m-c8", cell_name]
+    cell = traffic.Cell(cell_name, man)
+    assert (cell.entry["config"], cell.entry["traffic"]) == ("ec12p4-d16-chip1", cell_name)
+    assert [m["name"] for m in cell.end_to_end] == ["throughput", "setup_s"]
+    mine = {m["name"]: m for m in cell.per_layer}
+    put_path = {m["name"] for m in traffic.Cell("put64m-c8", man).per_layer}
+    new = {"mp_front_ms_per_upload", "mp_part_ms_per_part", "mp_commit_ms_per_upload",
+           "mp_complete_ms_per_upload", "mp_drive_calls_per_upload"}
+    assert set(mine) == put_path | new
+    assert {"codec_roofline", "device_idle", "compiles_in_window", "blocks_per_batch"} <= set(mine)
+    for name in new:
+        assert mine[name]["workloads"] == [cell_name] and mine[name]["moves"] == "throughput"
+        assert mine[name]["reader"]["kind"] == "ledger"
+    assert mine["mp_drive_calls_per_upload"]["reader"]["field"] == "count"
+    facts = run.op_facts([], 0.0, 1.0, [])
+    assert {"mputs_ended", "parts_ended", "puts_ended", "put_MiB"} <= set(facts)
+    for w in man["workloads"]:
+        for m in traffic.Cell(w["name"], man).per_layer:
+            per = m["reader"].get("per")
+            assert per is None or per in facts, (m["name"], per)
 
 
 def test_unknown_fields_are_errors(tmp_path, monkeypatch):

@@ -2,7 +2,7 @@
 the sound program, and comes out false for the control and for each fault the
 cells can have, planted underneath the timed path.
 
-Run by hand (about a quarter of an hour; each case boots a server in this process):
+Run by hand (about ten minutes; each case boots a server in this process):
 
     JAX_PLATFORMS=cpu python3 -m pytest benchmark/tests/test_rehearsal.py -q -p no:cacheprovider
 
@@ -32,6 +32,9 @@ def args(workload, seed, seconds=8.0, control=None):
                               rehearse=True, control=control, describe_trace=None, dump_ops=None)
 
 
+MP_SECONDS = 40.0  # an upload of two parts compiles its groups' programs on a slow CPU first
+
+
 def numbers(line):
     return {k: v["value"] for k, v in line["compared"].items()}
 
@@ -58,22 +61,22 @@ def test_control_one_parity_shard_fewer_is_not_correct():
 
 def _break_encode(mutate):
     """Plant a fault where the device's answer is produced: the pipeline's
-    encode, which returns (shards [B, K+M, S], digests [B, K+M, 32])."""
+    encode, which returns (parity [B, M, S], digests [B, K+M, 32]) since PR 30."""
     from minio_tpu.models import pipeline
 
     orig = pipeline.ErasurePipeline.encode
 
     def broken(self, data_shards):
-        shards, digests = orig(self, data_shards)
-        shards, digests = np.array(shards), np.array(digests)
-        mutate(self, shards, digests)
-        return shards, digests
+        parity, digests = orig(self, data_shards)
+        parity, digests = np.array(parity), np.array(digests)
+        mutate(self, parity, digests)
+        return parity, digests
 
     pipeline.ErasurePipeline.encode = broken
     return lambda: setattr(pipeline.ErasurePipeline, "encode", orig)
 
 
-def _run_broken(workload, seed, mutate):
+def _run_broken(workload, seed, mutate, seconds):
     """The fault goes in once the server has started: the install's own
     warm-up holds every program to the host codec and refuses a wrong one, so a
     fault that is there from the start never serves. The window is long
@@ -81,39 +84,45 @@ def _run_broken(workload, seed, mutate):
     (run alone, no earlier case has compiled it) to end inside."""
     restore = []
     try:
-        return bench_run.execute(args(workload, seed, seconds=20.0),
+        return bench_run.execute(args(workload, seed, seconds=seconds),
                                  deployment_hook=lambda dep: restore.append(_break_encode(mutate)))
     finally:
         for r in restore:
             r()
 
 
-def test_fault_parity_altered_where_it_is_produced():
+# one PUT a request; an upload's parts (with the window each needs on a slow CPU)
+ENCODING_CELLS = [("put64m-c8", 20.0), ("mpput64m-p8m-c4", MP_SECONDS)]
+
+
+@pytest.mark.parametrize("workload,seconds", ENCODING_CELLS)
+def test_fault_parity_altered_where_it_is_produced(workload, seconds):
     """One parity byte of every block flipped, and the row's digest made to
     match: bitrot verification passes, only the bytes read back can tell."""
     from minio_tpu.ops.highwayhash import hash256
 
-    def mutate(pipe, shards, digests):
+    def mutate(pipe, parity, digests):
         k = pipe.geom.data
-        shards[:, k, 0] ^= 0x5A
-        for b in range(shards.shape[0]):
-            digests[b, k] = np.frombuffer(hash256(shards[b, k].tobytes()), dtype=np.uint8)
+        parity[:, 0, 0] ^= 0x5A
+        for b in range(parity.shape[0]):
+            digests[b, k] = np.frombuffer(hash256(parity[b, 0].tobytes()), dtype=np.uint8)
 
-    rc, line = _run_broken("put64m-c8", 13, mutate)
+    rc, line = _run_broken(workload, 13, mutate, seconds)
     assert rc == 0 and line["correct"] is False
     assert numbers(line)["degraded_mismatch"] >= 1
 
 
-def test_fault_half_of_the_batch_left_out():
+@pytest.mark.parametrize("workload,seconds", ENCODING_CELLS)
+def test_fault_half_of_the_batch_left_out(workload, seconds):
     """The second half of every device batch comes back without parity."""
 
-    def mutate(pipe, shards, digests):
-        half = max(1, shards.shape[0] // 2)
-        shards[half:, pipe.geom.data:] = 0
-        if shards.shape[0] == 1:
-            shards[:, pipe.geom.data:] = 0
+    def mutate(pipe, parity, digests):
+        half = max(1, parity.shape[0] // 2)
+        parity[half:] = 0
+        if parity.shape[0] == 1:
+            parity[:] = 0
 
-    rc, line = _run_broken("put64m-c8", 14, mutate)
+    rc, line = _run_broken(workload, 14, mutate, seconds)
     assert rc == 0 and line["correct"] is False
     assert numbers(line)["degraded_mismatch"] >= 1
 
@@ -143,6 +152,55 @@ def test_fault_put_returns_the_state_unchanged():
     assert rc == 0 and line["correct"] is False
     n = numbers(line)
     assert n["ops_failed"] + n["readback_mismatch"] >= 1
+
+
+def test_multipart_cell_is_correct_on_the_sound_program():
+    rc, line = bench_run.execute(args("mpput64m-p8m-c4", 22, seconds=MP_SECONDS))
+    assert rc == 0 and line["correct"] is True, line
+    assert all(v == 0 for v in numbers(line).values()), numbers(line)
+    assert line["attempted"] >= 4 and line["metrics"] == {}
+
+
+def test_multipart_cell_control_one_parity_shard_fewer_is_not_correct():
+    rc, line = bench_run.execute(args("mpput64m-p8m-c4", 23, seconds=MP_SECONDS,
+                                      control="parity-1"))
+    assert rc == 0 and line["correct"] is False
+    n = numbers(line)
+    assert n["degraded_mismatch"] >= 1
+    assert n["ops_failed"] == 0 and n["readback_mismatch"] == 0
+
+
+def test_fault_complete_answers_200_and_commits_nothing():
+    """The step that returns its state unchanged: a Complete over an existing
+    key answers 200 with the ETag due, and the key keeps what it held."""
+    import hashlib
+
+    from minio_tpu.object.multipart import MultipartManager
+    from minio_tpu.object.types import ObjectInfo
+    from minio_tpu.utils import errors
+
+    orig = MultipartManager._complete_multipart_upload
+
+    def hollow(self, bucket, object_name, upload_id, parts):
+        try:
+            self.eo.get_object_info(bucket, object_name)
+        except errors.ObjectNotFound:
+            return orig(self, bucket, object_name, upload_id, parts)
+        have = {p.number: p for p in self.list_parts(bucket, object_name, upload_id, 0, 10_000)}
+        infos = [have[n] for n, _ in parts]
+        etag = hashlib.md5(b"".join(bytes.fromhex(p.etag) for p in infos)).hexdigest()
+        return ObjectInfo(bucket=bucket, name=object_name, size=sum(p.size for p in infos),
+                          etag=f"{etag}-{len(infos)}")
+
+    MultipartManager._complete_multipart_upload = hollow
+    try:
+        rc, line = bench_run.execute(args("mpput64m-p8m-c4", 24, seconds=MP_SECONDS))
+    finally:
+        MultipartManager._complete_multipart_upload = orig
+    assert rc == 0 and line["correct"] is False
+    n = numbers(line)
+    assert n["ops_failed"] == 0  # every Complete answered as a sound one would
+    assert n["readback_mismatch"] + n["degraded_mismatch"] >= 1
 
 
 def test_fault_get_answer_altered():

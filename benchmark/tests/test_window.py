@@ -2,7 +2,7 @@
 
 import pytest
 
-from benchmark.harness import window
+from benchmark.harness import run, window
 
 MIB = 1 << 20
 
@@ -74,3 +74,19 @@ def test_counts_by_kind():
     c = window.counts_by_kind(ops, 0.0, 10.0)
     assert c["PUT"] == {"touching": 2, "ended_inside": 2, "failed": 1}
     assert c["GET"] == {"touching": 0, "ended_inside": 0, "failed": 0}
+
+
+def test_an_mput_that_straddles_the_edge_is_prorated_like_any_op_and_counts_as_a_put():
+    # One upload wholly inside, one that began 1 s before the end and ended 1 s after it
+    # (half its bytes count), one that failed; parts are counted by when each answered.
+    ops = [op("MPUT", 2.0, 3.0, 64 * MIB), op("MPUT", 9.0, 11.0, 64 * MIB),
+           op("MPUT", 4.0, 5.0, 0, ok=False), op("PUT", 5.0, 6.0, 64 * MIB)]
+    e2e = window.end_to_end(ops, 0.0, 10.0)
+    assert e2e["throughput"] == pytest.approx((64 + 32 + 64) / 10.0)
+    assert e2e["ops_rate"] == pytest.approx(0.25)
+    facts = run.op_facts(ops, 0.0, 10.0, part_ends=[2.5, 2.9, 9.9, 10.0, 10.5, -0.1])
+    assert facts["mputs_ended"] == 2 and facts["puts_ended"] == 3  # the failed one ended inside too
+    assert facts["ops_ended"] == 3 and facts["parts_ended"] == 3
+    assert facts["put_MiB"] == pytest.approx(64 + 32 + 64) and facts["get_MiB"] == 0
+    assert window.counts_by_kind(ops, 0.0, 10.0)["MPUT"] == {
+        "touching": 3, "ended_inside": 2, "failed": 1}
