@@ -464,6 +464,19 @@ class MetricsSys:
                 metric("minio_tpu_codec_chip_blocks_total", blocks,
                        {"chip": str(chip)},
                        help_="Real blocks encoded per data-parallel mesh group.")
+            # How evenly a mesh's batches were dealt: even / fullest is the
+            # balance (1.0 = every dp group carried the same real blocks).
+            for share, key in (("even", "mesh_blocks_even"),
+                               ("fullest", "mesh_blocks_fullest")):
+                metric("minio_tpu_codec_mesh_blocks_total", round(st[key], 4),
+                       {"share": share},
+                       help_="Over the full-block batches of a codec mesh: real "
+                             "blocks / dp (even) and the real blocks of the "
+                             "fullest data-parallel group (fullest).")
+            metric("minio_tpu_codec_mesh_chip_batches_total", st["mesh_chip_batches"],
+                   help_="Chips given at least one real block, summed over the "
+                         "full-block batches of a codec mesh (/ encode batches "
+                         "= chips a batch used).")
         if "small_blocks_encoded" in st:
             metric("minio_tpu_codec_small_blocks_encoded_total",
                    st["small_blocks_encoded"],

@@ -128,6 +128,9 @@ STAGES: frozenset = frozenset({
     ("codec", "collect"),
     ("codec", "pack"),
     ("codec", "h2d"),
+    # On a codec mesh only, inside h2d: the sharded device_put alone (the
+    # rest of h2d is the launch), one record per batch.
+    ("codec", "mesh-put"),
     ("codec", "device-wait"),
     ("codec", "d2h"),
     ("codec", "scatter"),

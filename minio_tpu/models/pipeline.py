@@ -164,6 +164,14 @@ class ErasurePipeline:
         mesh_step.__name__ = mesh_step.__qualname__ = f"mtpu_encode_hash_{tag}"
         return jax.jit(mesh_step)
 
+    def place(self, data_shards: np.ndarray):
+        """The host batch where encode() takes it from: on a mesh one sharded
+        upload (each chip its [B/dp, K, S/sp] slice from the host, every tp
+        replica a copy of its own), else the array as it is."""
+        if self.mesh is None:
+            return data_shards
+        return jax.device_put(data_shards, mesh_lib.data_sharding(self.mesh))
+
     def encode(self, data_shards) -> tuple[jax.Array, jax.Array]:
         """[B, K, S] -> ([B, M, S] parity, [B, K+M, 32] digests).
 

@@ -11,7 +11,9 @@ Design:
     shapes, one fused encode+hash program (models/pipeline.py). With more
     than one local chip the pipeline is shard_map'd over the codec mesh
     (parallel/mesh.py codec_mesh, MTPU_MESH_SHAPE): batches pad to a
-    multiple of dp and fan data-parallel over blocks.
+    multiple of dp, their real blocks are dealt round-robin to the dp
+    groups (_deal), and the array crosses to the chips as one sharded
+    device_put.
   * Sub-window blocks >= 4 KiB coalesce on a second queue behind a bounded
     latency budget (MTPU_BATCH_WAIT_US): concurrent small-object PUTs share
     one parity-only device batch, padded on the shard-BYTE axis (GF math is
@@ -79,6 +81,14 @@ def _len_bucket(s: int) -> int:
     while b < s:
         b <<= 1
     return b
+
+
+def _deal(b_real: int, b_pad: int, dp: int) -> list[int]:
+    """The slot of each real block of a batch of b_pad slots (dp divides it).
+    Block i goes to dp group i mod dp, so the groups' real blocks differ by
+    at most one and the padding is shared; with dp 1 block i is in slot i."""
+    per = b_pad // dp
+    return [(i % dp) * per + i // dp for i in range(b_real)]
 
 
 def _small_wait_s() -> float | None:
@@ -190,6 +200,13 @@ class BatchingDeviceCodec(BlockCodec):
         # blocks the dp-group g carried; with no mesh both stay trivial.
         self.mesh_devices = 1
         self.chip_blocks: list[int] = []
+        # Over the full-block batches of a mesh: b_real / dp (what every dp
+        # group would carry if blocks could be cut), the real blocks of the
+        # fullest group (even / fullest = the balance of the dealing), and
+        # the chips that were given at least one real block.
+        self.mesh_blocks_even = 0.0
+        self.mesh_blocks_fullest = 0
+        self.mesh_chip_batches = 0
         # Round-trip seconds per kernel class by the HOST clock: launch to
         # the bytes' arrival on the host, queueing behind the batch before
         # included. Not device time (control/devtrace.py reads that from a
@@ -338,7 +355,7 @@ class BatchingDeviceCodec(BlockCodec):
             data = rng.integers(0, 256, (b, k, n), dtype=np.uint8)
             want = np.stack([self._host._encode_one(data[i], m) for i in range(b)])
             if kind == "encode":  # parity rows back, all k+m rows hashed
-                got, digests = pipe.encode(data)
+                got, digests = pipe.encode(pipe.place(data))  # as a batch is launched
                 want_rows, hashed = want[:, k:], want
             elif kind == "parity":
                 got, digests = pipe.encode_parity(data), None
@@ -439,12 +456,12 @@ class BatchingDeviceCodec(BlockCodec):
                 s = batch[0].shards.shape[1]
                 b_real = len(batch)
                 b_pad = _bucket(b_real)
-                if pipe.mesh is not None:
-                    dp = pipe.mesh.shape["dp"]
-                    b_pad = -(-b_pad // dp) * dp  # dp must divide the batch axis
+                dp = pipe.mesh.shape["dp"] if pipe.mesh is not None else 1
+                b_pad = -(-b_pad // dp) * dp  # dp must divide the batch axis
+                slots = _deal(b_real, b_pad, dp)
                 arr = np.zeros((b_pad, k, s), dtype=np.uint8)
-                for i, req in enumerate(batch):
-                    arr[i] = req.shards
+                for slot, req in zip(slots, batch):
+                    arr[slot] = req.shards
             # encode-batch runs from the launch to the bytes' arrival on the
             # host (_resolve_batch closes it): the round trip, by the host's
             # clock. Under double-buffering the next batch's dispatch falls
@@ -452,11 +469,18 @@ class BatchingDeviceCodec(BlockCodec):
             enc = tracing.stage("encode-batch", "codec")
             enc.__enter__()
             with tracing.stage("h2d", "codec"):
+                h2d = arr.nbytes
+                if pipe.mesh is not None:
+                    # The sharded upload alone, apart from the launch: every
+                    # tp replica takes its own copy of its slice from the host.
+                    with tracing.stage("mesh-put", "codec"):
+                        arr = pipe.place(arr)
+                    h2d *= pipe.mesh.shape["tp"]
                 parity, digests = pipe.encode(arr)
-                GLOBAL_PROFILER.copy.record("device-h2d", COPIED, arr.nbytes)
+                GLOBAL_PROFILER.copy.record("device-h2d", COPIED, h2d)
                 with self._stats_lock:
-                    self.h2d_bytes += arr.nbytes
-            return (batch, parity, digests, k, m, b_real, b_pad, enc, pipe)
+                    self.h2d_bytes += h2d
+            return (batch, parity, digests, k, m, slots, b_pad, enc, pipe)
         except Exception as e:  # noqa: BLE001
             for req in batch:
                 if not req.future.done():
@@ -464,7 +488,8 @@ class BatchingDeviceCodec(BlockCodec):
             return None
 
     def _resolve_batch(self, rec) -> None:
-        batch, parity, digests, k, m, b_real, b_pad, enc, pipe = rec
+        batch, parity, digests, k, m, slots, b_pad, enc, pipe = rec
+        b_real = len(batch)
         try:
             # What the worker pays waiting for the device (not device time:
             # under double-buffering the next batch is already in flight),
@@ -479,6 +504,11 @@ class BatchingDeviceCodec(BlockCodec):
                 enc.__exit__(None, None, None)
                 d2h = parity_np.nbytes + digests_np.nbytes
                 GLOBAL_PROFILER.copy.record("device-d2h", COPIED, d2h)
+                if pipe.mesh is not None:
+                    dp = pipe.mesh.shape["dp"]
+                    groups = [0] * dp  # real blocks of each dp group
+                    for slot in slots:
+                        groups[slot // (b_pad // dp)] += 1
                 with self._stats_lock:
                     self.device_encode_seconds += enc.wall
                     self.batches_run += 1
@@ -487,16 +517,19 @@ class BatchingDeviceCodec(BlockCodec):
                     self.d2h_bytes += d2h
                     self.encoded_user_bytes += b_real * self.block_size
                     if pipe.mesh is not None:
-                        dp = pipe.mesh.shape["dp"]
-                        per = b_pad // dp
                         for g in range(min(dp, len(self.chip_blocks))):
-                            self.chip_blocks[g] += min(max(b_real - g * per, 0), per)
-                for i, req in enumerate(batch):
+                            self.chip_blocks[g] += groups[g]
+                        self.mesh_blocks_even += b_real / dp
+                        self.mesh_blocks_fullest += max(groups)
+                        self.mesh_chip_batches += (
+                            sum(1 for n in groups if n) * (pipe.mesh.size // dp)
+                        )
+                for slot, req in zip(slots, batch):
                     req.future.set_result(
                         (
                             [req.shards[j].tobytes() for j in range(k)]
-                            + [parity_np[i, j].tobytes() for j in range(m)],
-                            [digests_np[i, j].tobytes() for j in range(k + m)],
+                            + [parity_np[slot, j].tobytes() for j in range(m)],
+                            [digests_np[slot, j].tobytes() for j in range(k + m)],
                         )
                     )
         except Exception as e:  # noqa: BLE001
@@ -758,6 +791,9 @@ class BatchingDeviceCodec(BlockCodec):
                 "double_buffered_batches": self.double_buffered_batches,
                 "mesh_devices": self.mesh_devices,
                 "chip_blocks": list(self.chip_blocks),
+                "mesh_blocks_even": self.mesh_blocks_even,
+                "mesh_blocks_fullest": self.mesh_blocks_fullest,
+                "mesh_chip_batches": self.mesh_chip_batches,
                 "host_fallback_blocks": self.host_fallback_blocks,
                 "host_fallback_recon_blocks": self.host_fallback_recon_blocks,
                 "host_fallback_digest_chunks": self.host_fallback_digest_chunks,

@@ -19,38 +19,61 @@ from minio_tpu.parallel import mesh as mesh_lib
 K, M = 12, 4
 
 
-def _host_oracle(data):
+def _host_oracle(data, m=M):
     """[B, K, S] -> (parity, digests of all K+M rows) via the numpy reference."""
-    shards = np.stack([rs_ref.encode(data[i], M) for i in range(data.shape[0])])
+    k = data.shape[1]
+    shards = np.stack([rs_ref.encode(data[i], m) for i in range(data.shape[0])])
     digests = np.stack(
         [
             np.stack(
                 [
                     np.frombuffer(hh.hash256(shards[i, j].tobytes()), dtype=np.uint8)
-                    for j in range(K + M)
+                    for j in range(k + m)
                 ]
             )
             for i in range(data.shape[0])
         ]
     )
-    return shards[:, K:], digests
+    return shards[:, k:], digests
 
 
-@pytest.mark.parametrize("shape", [(2, 2, 2), (4, 2, 1), (8, 1, 1), (1, 2, 4)])
-def test_mesh_encode_matches_host(shape):
-    if jax.device_count() < 8:
+# The shapes a four-chip host can take (parallel/mesh.py factor_mesh picks one
+# of them, MTPU_MESH_SHAPE any), at the three served geometries and at one
+# whose six streams do not divide a 2 x 2 stream grid.
+FOUR_CHIP_SHAPES = [(4, 1, 1), (2, 2, 1), (2, 1, 2), (1, 2, 2)]
+EIGHT = [(8, shape, K, M) for shape in [(2, 2, 2), (4, 2, 1), (8, 1, 1), (1, 2, 4)]]
+FOUR = [(4, shape, k, m) for shape in FOUR_CHIP_SHAPES
+        for k, m in [(12, 4), (4, 4), (2, 2), (4, 2)]]
+
+
+@pytest.mark.parametrize(
+    "n,shape,k,m", EIGHT + FOUR,
+    ids=lambda v: "x".join(map(str, v)) if isinstance(v, tuple) else str(v))
+def test_mesh_encode_matches_host(n, shape, k, m):
+    if jax.device_count() < n:
         pytest.skip("needs the 8-device virtual platform from conftest")
-    mesh = mesh_lib.make_mesh(8, shape=shape)
+    from minio_tpu.parallel.batching import BatchingDeviceCodec
+
     dp, tp, sp = shape
-    geom = Geometry(K, M, block_size=K * 64 * max(sp, 1))
+    geom = Geometry(k, m, block_size=k * 64 * max(sp, 1))
+    # The batcher's rule decides whether this geometry runs on the mesh: where
+    # its streams do not tile the tp x sp grid it gets the single-device
+    # program, which must be as right.
+    batcher = BatchingDeviceCodec(block_size=geom.block_size, mesh=mesh_lib.make_mesh(n, shape=shape))
+    mesh = batcher._mesh_for(k, m)
+    tiles = (k + m) % (tp * sp) == 0
+    assert (mesh is not None) == tiles
     pipe = ErasurePipeline(geom, mesh=mesh)
     rng = np.random.default_rng(42)
-    data = rng.integers(0, 256, (2 * dp, K, geom.shard_size), dtype=np.uint8)
-    arr = jax.device_put(data, mesh_lib.data_sharding(mesh))
+    data = rng.integers(0, 256, (2 * dp, k, geom.shard_size), dtype=np.uint8)
 
-    parity, digests = pipe.encode(arr)
-    want_parity, want_digests = _host_oracle(data)
-    assert parity.shape == (2 * dp, M, geom.shard_size)
+    parity, digests = pipe.encode(pipe.place(data))
+    if mesh is not None:
+        assert parity.sharding.is_equivalent_to(
+            jax.sharding.NamedSharding(mesh, mesh_lib.parity_spec()), parity.ndim)
+        assert len(parity.sharding.device_set) == n
+    want_parity, want_digests = _host_oracle(data, m)
+    assert parity.shape == (2 * dp, m, geom.shard_size)
     assert np.array_equal(np.asarray(parity), want_parity)
     assert np.array_equal(np.asarray(digests), want_digests)
 

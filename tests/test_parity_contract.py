@@ -72,6 +72,9 @@ def test_batcher_returns_its_own_data_rows_and_device_parity(k, m, block_size):
         assert digests == want_digests
     assert (st["batches_run"], st["blocks_encoded"], st["blocks_padded"]) == (1, 3, 4)
     assert st["host_fallback_blocks"] == 0
-    assert st["h2d_bytes"] == 4 * k * s
+    # On conftest's virtual devices a geometry that tiles the codec mesh is
+    # uploaded once per tp replica (one device, or no tiling: once).
+    mesh = codec._pipelines[(k, m)].mesh
+    assert st["h2d_bytes"] == 4 * k * s * (mesh.shape["tp"] if mesh is not None else 1)
     assert st["d2h_bytes"] == 4 * (m * s + 32 * (k + m))
     assert st["encoded_user_bytes"] == 3 * block_size

@@ -7,7 +7,8 @@ TPU compiler rejects -- for the programs a v5e serves (XLA bit-matmul RS, Pallas
 hash): encode + hash at the production shape (12+4, 1 MiB blocks -> 87,382 B
 shards, one 16-block codec group) and for the three served geometries at the
 warm-up's largest batch (64), whose outputs are parity + digests only; the
-small queue's parity program; the mesh step; and the two reconstruct programs,
+small queue's parity program; the mesh step of a four-chip host at the
+warm-up's largest batch; and the two reconstruct programs,
 heal (with digests) and degraded GET (without). Each program's temporaries and
 outputs are printed. It proves nothing about execution or bit-exactness on
 silicon: that is chip_smoke.py's job.
@@ -35,7 +36,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import topologies
-from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+from jax.sharding import Mesh, SingleDeviceSharding
 
 from minio_tpu import jaxenv
 
@@ -72,15 +73,15 @@ def recon_step(hash_fn):
 
 
 def mesh_step():
-    # What codec_mesh() builds by itself on a four-chip host: factor_mesh(4).
-    # Built now, before the compile threads.
+    # What codec_mesh() builds by itself on a four-chip host, factor_mesh(4),
+    # at the largest batch bucket the cell put64m-c8-chip4 is served with, its
+    # input placed as the batcher's sharded upload places it. Built now,
+    # before the compile threads.
     shape = mesh_lib.factor_mesh(4)
     assert shape == (2, 2, 1), shape
     mesh = Mesh(np.array(devs).reshape(shape), mesh_lib.AXES)
     pipe = pipeline.ErasurePipeline(pipeline.Geometry(K, M), mesh=mesh)
-    return lambda: pipe._encode_fn.lower(
-        sds((BATCH, K, S), sharding=NamedSharding(mesh, mesh_lib.data_spec()))
-    )
+    return lambda: pipe._encode_fn.lower(sds((64, K, S), sharding=mesh_lib.data_sharding(mesh)))
 
 
 assert pipeline.hash_batch_fn() is hhp.hash256_batch
@@ -95,7 +96,7 @@ programs = {
     "fused 2+2 x64": fused_step(2, 2, 64, 524288),
     # The small-object queue's parity-only program for 64 KiB objects at 2+2.
     "parity 2+2 x64": lambda: jax.jit(rs.RSCodec(2, 2).encode).lower(sds((64, 2, 32768))),
-    "mesh (2,2,1)": mesh_step(),
+    "mesh (2,2,1) x64": mesh_step(),
     "reconstruct + digests (heal)": recon_step(hhp.hash256_batch),
     "reconstruct (degraded GET)": recon_step(None),
 }
