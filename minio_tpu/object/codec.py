@@ -328,13 +328,18 @@ class ReconStaging:
         self._lock = san_lock("ReconStaging._lock")
         self._free: dict[tuple[int, ...], list[np.ndarray]] = {}
         self.outstanding = 0
+        # Acquires served from the free list, and those that allocated.
+        self.reused = 0
+        self.allocated = 0
 
     def acquire(self, shape: tuple[int, ...]) -> np.ndarray:
         with self._lock:
             self.outstanding += 1
             free = self._free.get(shape)
             if free:
+                self.reused += 1
                 return free.pop()
+            self.allocated += 1
         return np.empty(shape, dtype=np.uint8)
 
     def release(self, arr: np.ndarray) -> None:
@@ -360,27 +365,49 @@ def _address(buf) -> int:
     return np.frombuffer(buf, dtype=np.uint8).__array_interface__["data"][0]
 
 
+def strided_runs(views: list, s: int) -> "list[tuple[int, int, np.ndarray | None]]":
+    """Split `views` (buffers of s bytes) into maximal runs of consecutive
+    views at one constant stride >= s inside one exporter -- a read window's
+    frames (stride DIGEST_LEN + S over the shard's blob), slices of a raw
+    shard file or a PUT window's blocks (stride S) -- each as (start, stop,
+    ONE [stop - start, s] strided array over the exporter). A view that
+    joins no run (bytes: its own exporter; a non-contiguous view) is
+    (i, i + 1, None): the caller copies that one alone. The arrays export
+    the caller's buffer: drop them before the caller may release it."""
+    keys = []  # (exporter, address) of a view that may join a run
+    for v in views:
+        ok = isinstance(v, memoryview) and v.c_contiguous and v.nbytes == s
+        keys.append((v.obj, _address(v)) if ok else None)
+    out: list[tuple[int, int, np.ndarray | None]] = []
+    i = 0
+    while i < len(views):
+        if keys[i] is None:
+            out.append((i, i + 1, None))
+            i += 1
+            continue
+        base, addr = keys[i]
+        stop, stride = i + 1, s
+        if stop < len(views) and keys[stop] is not None and keys[stop][0] is base:
+            stride = keys[stop][1] - addr
+        if stride >= s:
+            while (stop < len(views) and keys[stop] is not None and keys[stop][0] is base
+                   and keys[stop][1] - keys[stop - 1][1] == stride):
+                stop += 1
+        whole = np.frombuffer(base, dtype=np.uint8)
+        out.append((i, stop, np.ndarray(
+            (stop - i, s), dtype=np.uint8, buffer=whole,
+            offset=addr - _address(whole), strides=(stride, 1),
+        )))
+        i = stop
+    return out
+
+
 def _shard_window(rows_batch: list, j: int, s: int) -> np.ndarray | None:
-    """Shard j's rows as ONE [B, S] strided array, when they are views at a
-    constant stride inside one exporter -- a read window's frames (stride
-    DIGEST_LEN + S over the shard's blob) or slices of a raw shard file
-    (stride S). None when the input is anything else (rows in buffers of
-    their own, a row moved elsewhere): the caller packs those row by row."""
-    rows = [r[j] for r in rows_batch]
-    if not all(isinstance(r, memoryview) for r in rows):
-        return None  # a bytes row is its own exporter
-    base = rows[0].obj
-    if any(r.obj is not base or not r.c_contiguous for r in rows):
-        return None
-    addrs = [_address(r) for r in rows]
-    stride = addrs[1] - addrs[0] if len(addrs) > 1 else s
-    if stride < s or any(b - a != stride for a, b in zip(addrs, addrs[1:])):
-        return None
-    whole = np.frombuffer(base, dtype=np.uint8)
-    return np.ndarray(
-        (len(addrs), s), dtype=np.uint8, buffer=whole,
-        offset=addrs[0] - _address(whole), strides=(stride, 1),
-    )
+    """Shard j's rows as ONE [B, S] strided array, when they are one run
+    (strided_runs); None when the input is anything else (rows in buffers
+    of their own, a row moved elsewhere): the caller packs those row by row."""
+    runs = strided_runs([r[j] for r in rows_batch], s)
+    return runs[0][2] if len(runs) == 1 else None
 
 
 def pack_survivors(staging: np.ndarray, rows_batch: list, surv: list[int], s: int) -> int:

@@ -52,6 +52,7 @@ from ..object.codec import (
     HostCodec,
     ReconStaging,
     run_device_reconstruct,
+    strided_runs,
     uniform_recon_plan,
 )
 from ..ops import rs_matrix
@@ -110,9 +111,12 @@ def _small_wait_s() -> float | None:
 
 @dataclass
 class _Request:
-    # [K, S] split data block: packed and uploaded, and handed back as the
-    # result's K data chunks (the device returns parity and digests only).
-    shards: np.ndarray
+    # The caller's full block (a view into its window, or bytes), not a
+    # copy: the worker packs it into the batch's staging array, the only
+    # copy, and the result's K data chunks come from there (the device
+    # returns parity and digests only). The caller waits on the future, so
+    # its window outlives the batch; no view of it may outlive the pack.
+    block: bytes | memoryview
     future: Future
     # When the request was queued (perf_counter): codec/queue-wait is the
     # dispatch start less this, for the oldest request of a batch.
@@ -168,6 +172,13 @@ class BatchingDeviceCodec(BlockCodec):
         self.recon_shards_packed = 0
         self.recon_shards_strided = 0
         self._recon_staging = ReconStaging()
+        # Full-block batches stage in arrays of their own free list: two a
+        # shape, the batch being packed and the one in the pending slot.
+        # pack_copies counts the numpy copies that moved block bytes into
+        # them: one a run of a caller's window (and dp group), one a block
+        # that joins no run.
+        self._encode_staging = ReconStaging(per_shape=2)
+        self.pack_copies = 0
         self.digests_verified = 0
         self.verify_batches_run = 0
         # Padded-slot total: blocks_encoded / blocks_padded = batch occupancy
@@ -441,9 +452,43 @@ class BatchingDeviceCodec(BlockCodec):
         if pending is not None:
             self._resolve_batch(pending)
 
+    def _pack(self, flat: np.ndarray, batch: list[_Request], slots: list[int], dp: int) -> int:
+        """Copy the batch's blocks into their slots of `flat` ([b_pad, K*S],
+        every byte of it written): one strided copy per run of a caller's
+        window (strided_runs) and dp group, one copy per block that joins no
+        run; then the real slots' K*S - n tail and the pad slots zeroed.
+        Returns the copies that moved block bytes. No view of a caller's
+        buffer outlives this call (_Window.release() invalidates them)."""
+        n = self.block_size
+        per = flat.shape[0] // dp
+        copies = 0
+        runs = strided_runs([req.block for req in batch], n)
+        try:
+            for start, stop, run in runs:
+                if run is None:
+                    flat[slots[start], :n] = np.frombuffer(batch[start].block, np.uint8)
+                    copies += 1
+                    continue
+                for g in range(dp):  # block i sits in slot (i mod dp)*per + i div dp
+                    first = start + (g - start) % dp
+                    if first < stop:
+                        src = run[first - start :: dp]
+                        flat[slots[first] : slots[first] + len(src), :n] = src
+                        copies += 1
+        finally:
+            runs = run = src = None  # a traceback must not pin the caller's buffer
+        if flat.shape[1] > n:
+            flat[:, n:] = 0
+        for g in range(dp):
+            real = len(range(g, len(batch), dp))
+            if real < per:
+                flat[g * per + real : (g + 1) * per] = 0
+        return copies
+
     def _dispatch_batch(self, pipe: ErasurePipeline, k: int, m: int, batch: list[_Request]):
         """Marshal + launch one encode batch; returns the pending record to
         resolve later, or None if dispatch itself failed."""
+        staging = None
         try:
             # Each stage takes its own bookkeeping inside, so that the stages
             # leave nothing of the worker's time between them.
@@ -453,15 +498,16 @@ class BatchingDeviceCodec(BlockCodec):
                 GLOBAL_PERF.ledger.record("codec", "queue-wait", max(waits))
                 with self._stats_lock:
                     self.queue_wait_block_seconds += sum(waits)
-                s = batch[0].shards.shape[1]
+                s = rs_matrix.shard_size(self.block_size, k)
                 b_real = len(batch)
                 b_pad = _bucket(b_real)
                 dp = pipe.mesh.shape["dp"] if pipe.mesh is not None else 1
                 b_pad = -(-b_pad // dp) * dp  # dp must divide the batch axis
                 slots = _deal(b_real, b_pad, dp)
-                arr = np.zeros((b_pad, k, s), dtype=np.uint8)
-                for slot, req in zip(slots, batch):
-                    arr[slot] = req.shards
+                # Reused across batches of this shape: the program has read
+                # it by the time _resolve_batch gives it back.
+                staging = self._encode_staging.acquire((b_pad, k, s))
+                copies = self._pack(staging.reshape(b_pad, k * s), batch, slots, dp)
             # encode-batch runs from the launch to the bytes' arrival on the
             # host (_resolve_batch closes it): the round trip, by the host's
             # clock. Under double-buffering the next batch's dispatch falls
@@ -469,6 +515,7 @@ class BatchingDeviceCodec(BlockCodec):
             enc = tracing.stage("encode-batch", "codec")
             enc.__enter__()
             with tracing.stage("h2d", "codec"):
+                arr = staging
                 h2d = arr.nbytes
                 if pipe.mesh is not None:
                     # The sharded upload alone, apart from the launch: every
@@ -480,21 +527,24 @@ class BatchingDeviceCodec(BlockCodec):
                 GLOBAL_PROFILER.copy.record("device-h2d", COPIED, h2d)
                 with self._stats_lock:
                     self.h2d_bytes += h2d
-            return (batch, parity, digests, k, m, slots, b_pad, enc, pipe)
+            return (batch, parity, digests, k, m, slots, b_pad, enc, pipe, staging, copies)
         except Exception as e:  # noqa: BLE001
+            if staging is not None:
+                self._encode_staging.discard(staging)  # the runtime may still read it
             for req in batch:
                 if not req.future.done():
                     req.future.set_exception(e)
             return None
 
     def _resolve_batch(self, rec) -> None:
-        batch, parity, digests, k, m, slots, b_pad, enc, pipe = rec
+        batch, parity, digests, k, m, slots, b_pad, enc, pipe, staging, copies = rec
         b_real = len(batch)
         try:
             # What the worker pays waiting for the device (not device time:
             # under double-buffering the next batch is already in flight),
             # then the way back to the host of what the host lacks: the
-            # parity rows and the digests. The data rows are req.shards.
+            # parity rows and the digests. The data rows are in the staging
+            # array.
             with tracing.stage("device-wait", "codec"):
                 jax.block_until_ready((parity, digests))
             with tracing.stage("d2h", "codec"):
@@ -514,6 +564,7 @@ class BatchingDeviceCodec(BlockCodec):
                     self.batches_run += 1
                     self.blocks_encoded += b_real
                     self.blocks_padded += b_pad
+                    self.pack_copies += copies
                     self.d2h_bytes += d2h
                     self.encoded_user_bytes += b_real * self.block_size
                     if pipe.mesh is not None:
@@ -527,15 +578,20 @@ class BatchingDeviceCodec(BlockCodec):
                 for slot, req in zip(slots, batch):
                     req.future.set_result(
                         (
-                            [req.shards[j].tobytes() for j in range(k)]
+                            [staging[slot, j].tobytes() for j in range(k)]
                             + [parity_np[slot, j].tobytes() for j in range(m)],
                             [digests_np[slot, j].tobytes() for j in range(k + m)],
                         )
                     )
         except Exception as e:  # noqa: BLE001
+            self._encode_staging.discard(staging)  # the runtime may still read it
             for req in batch:
                 if not req.future.done():
                     req.future.set_exception(e)
+            return
+        # Parity and digests are home, so the program has consumed its input
+        # and every result holds its own bytes: the array may stage again.
+        self._encode_staging.release(staging)
 
     def _small_worker(self, key: tuple) -> None:
         k, m = key[0], key[1]
@@ -623,7 +679,6 @@ class BatchingDeviceCodec(BlockCodec):
             return self._encode(blocks, k, m)
 
     def _encode(self, blocks, k, m):
-        shard_size_full = rs_matrix.shard_size(self.block_size, k)
         futures: list[Future | None] = [None] * len(blocks)
         host_idx: list[int] = []
         q = None
@@ -634,7 +689,7 @@ class BatchingDeviceCodec(BlockCodec):
                 if q is None:
                     q = self._ensure_worker(k, m)
                 f: Future = Future()
-                q.put(_Request(rs_matrix.split(np.frombuffer(block, np.uint8), k), f))
+                q.put(_Request(block, f))
                 futures[i] = f
             elif self.small_wait_s is not None and _SMALL_MIN <= n < self.block_size:
                 # Sub-window block: coalesce with concurrent small PUTs into
@@ -778,6 +833,9 @@ class BatchingDeviceCodec(BlockCodec):
                 "recon_batches_run": self.recon_batches_run,
                 "recon_shards_packed": self.recon_shards_packed,
                 "recon_shards_strided": self.recon_shards_strided,
+                "pack_copies": self.pack_copies,
+                "encode_staging_allocated": self._encode_staging.allocated,
+                "encode_staging_reused": self._encode_staging.reused,
                 "digests_verified": self.digests_verified,
                 "verify_batches_run": self.verify_batches_run,
                 "small_blocks_encoded": self.small_blocks_encoded,

@@ -6,7 +6,8 @@ import threading
 import numpy as np
 import pytest
 
-from minio_tpu.object.codec import HostCodec
+from minio_tpu.object.codec import HostCodec, strided_runs
+from minio_tpu.ops import rs_matrix
 from minio_tpu.parallel.batching import BatchingDeviceCodec
 
 # Stressed under adversarial thread scheduling by tools/race_gate.py.
@@ -397,3 +398,171 @@ def test_codec_programs_carry_names():
     w = np.zeros((4 * 8, 2 * 8), np.int8)
     rec = pipeline._reconstruct_step.lower(x, w, None).as_text(debug_info=True)
     assert "module @jit_mtpu_reconstruct " in rec and "mtpu.rs_reconstruct" in rec
+
+
+# -- a full-block batch packs runs of a caller's window into reused staging ---
+#
+# Blocks of 12 x 256 - 2 B at 12+4 (K*S = n + 2: the real slots have a tail
+# to zero) and 4 x 768 B at 4+4 (K*S = n), so the CPU backend runs in
+# milliseconds; every staging array starts out filled with 0xFF.
+
+PACK_GEOMS = [(12, 4, 12 * 256 - 2), (4, 4, 4 * 768)]
+
+
+def _pack_codec(k, m, n, b_pad=16):
+    """A one-device codec whose free list holds one dirty [b_pad, K, S] array."""
+    codec = BatchingDeviceCodec(block_size=n, max_batch=64, batch_timeout_s=0.25, mesh=None)
+    staging = codec._encode_staging
+    arr = staging.acquire((b_pad, k, rs_matrix.shard_size(n, k)))
+    arr.fill(0xFF)
+    staging.release(arr)
+    return codec
+
+
+def _window(seed, n_blocks, n):
+    rng = np.random.default_rng(seed)
+    win = bytearray(rng.integers(0, 256, n_blocks * n, dtype=np.uint8).tobytes())
+    mv = memoryview(win)
+    return win, [mv[i * n : (i + 1) * n] for i in range(n_blocks)]
+
+
+def _host_of(blocks, k, m):
+    return HostCodec().encode([bytes(b) for b in blocks], k, m)
+
+
+def _watch_encode(codec, k, m, seen):
+    """Record a copy of every array the pipeline is launched with."""
+    codec._ensure_worker(k, m)
+    pipe = codec._pipelines[(k, m)]
+    real = pipe.encode
+
+    def watched(arr):
+        seen.append(np.array(arr))
+        return real(arr)
+
+    pipe.encode = watched
+
+
+@pytest.mark.parametrize("b_real", [16, 13])
+@pytest.mark.parametrize("k,m,n", PACK_GEOMS)
+def test_one_window_is_one_copy_and_bytes_blocks_one_each(k, m, n, b_real):
+    codec = _pack_codec(k, m, n)
+    seen: list = []
+    try:
+        _watch_encode(codec, k, m, seen)
+        win, blocks = _window(7, b_real, n)
+        assert codec.encode(blocks, k, m) == _host_of(blocks, k, m)
+        st = codec.stats()
+        assert (st["batches_run"], st["blocks_encoded"], st["pack_copies"]) == (1, b_real, 1)
+        assert (st["encode_staging_allocated"], st["encode_staging_reused"]) == (1, 1)
+        flat = seen[0].reshape(16, -1)
+        assert not flat[:, n:].any() and not flat[b_real:].any()  # tail and pad zeroed
+        as_bytes = [bytes(b) for b in blocks]
+        assert codec.encode(as_bytes, k, m) == _host_of(blocks, k, m)
+        st = codec.stats()
+        assert (st["batches_run"], st["pack_copies"]) == (2, 1 + b_real)
+    finally:
+        codec.close()
+
+
+@pytest.mark.parametrize("k,m,n", PACK_GEOMS)
+def test_two_windows_interleaved_in_one_batch_come_back_to_their_own(k, m, n):
+    codec = _pack_codec(k, m, n)
+    try:
+        _, a = _window(11, 8, n)
+        _, b = _window(12, 8, n)
+        one_by_one = [blk for pair in zip(a, b) for blk in pair]  # a0 b0 a1 b1 ...
+        assert codec.encode(one_by_one, k, m) == _host_of(one_by_one, k, m)
+        assert codec.stats()["pack_copies"] == 16
+        by_fours = a[:4] + b[:4] + a[4:] + b[4:]
+        assert codec.encode(by_fours, k, m) == _host_of(by_fours, k, m)
+        assert codec.stats()["pack_copies"] == 16 + 4
+        # two requests at once, each its own window: every row comes home
+        out: dict = {}
+        start = threading.Barrier(2)
+
+        def one(name, blocks):
+            start.wait(30)
+            out[name] = codec.encode(blocks, k, m)
+
+        threads = [threading.Thread(target=one, args=x) for x in (("a", a), ("b", b))]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(60)
+        assert out["a"] == _host_of(a, k, m) and out["b"] == _host_of(b, k, m)
+    finally:
+        codec.close()
+
+
+@pytest.mark.parametrize("k,m,n", PACK_GEOMS)
+def test_staging_is_reused_back_only_after_resolve_and_discarded_on_failure(k, m, n):
+    codec = BatchingDeviceCodec(block_size=n, max_batch=64, batch_timeout_s=0.25, mesh=None)
+    staging = codec._encode_staging
+    free_at_resolve: list = []
+    real_resolve = codec._resolve_batch
+
+    def resolve(rec):
+        free_at_resolve.append(staging.free_count())
+        real_resolve(rec)
+        free_at_resolve.append(staging.free_count())
+
+    codec._resolve_batch = resolve
+    try:
+        for seed in (21, 22):
+            _, blocks = _window(seed, 16, n)
+            assert codec.encode(blocks, k, m) == _host_of(blocks, k, m)
+        st = codec.stats()
+        assert (st["encode_staging_allocated"], st["encode_staging_reused"]) == (1, 1)
+        assert free_at_resolve == [0, 1, 0, 1] and staging.outstanding == 0
+        pipe = codec._pipelines[(k, m)]
+
+        def boom(arr):
+            raise RuntimeError("device lost")
+
+        pipe.encode = boom
+        _, blocks = _window(23, 16, n)
+        with pytest.raises(RuntimeError):
+            codec.encode(blocks, k, m)
+        assert staging.outstanding == 0 and staging.free_count() == 0  # never recycled
+    finally:
+        codec.close()
+
+
+@pytest.mark.parametrize("k,m,n", PACK_GEOMS)
+def test_results_alias_neither_the_window_nor_the_staging_array(k, m, n):
+    codec = _pack_codec(k, m, n)
+    try:
+        win, blocks = _window(31, 16, n)
+        want = _host_of(blocks, k, m)
+        got = codec.encode(blocks, k, m)
+        win[:] = bytes(len(win))  # the caller reuses its window ...
+        _, other = _window(32, 16, n)
+        codec.encode(other, k, m)  # ... and the staging array packs again
+        assert codec.stats()["encode_staging_reused"] == 2
+        assert got == want
+        assert all(type(c) is bytes for rows, digests in got for c in rows + digests)
+        for b in blocks:  # nothing exports the window any more
+            b.release()
+        win.extend(b"x")
+    finally:
+        codec.close()
+
+
+def test_strided_runs_split_at_every_break():
+    win = bytearray(64)
+    mv = memoryview(win)
+    other = memoryview(bytearray(16))
+    views = [mv[0:8], mv[8:16], mv[16:24], mv[40:48], mv[48:56], b"x" * 8, other[0:8],
+             mv[56:64]]
+    runs = strided_runs(views, 8)
+    assert [(a, b) for a, b, _ in runs] == [(0, 3), (3, 5), (5, 6), (6, 7), (7, 8)]
+    assert runs[2][2] is None and runs[1][2].strides == (8, 1)
+    whole = np.frombuffer(win, np.uint8)
+    assert np.shares_memory(runs[0][2], whole) and runs[0][2].shape == (3, 8)
+    assert [r[2].shape for r in strided_runs([mv[0:8], mv[24:32], mv[48:56]], 8)] == [(3, 8)]
+    assert strided_runs([mv[0:8], mv[48:56]], 8)[0][2].strides == (48, 1)
+    # a step under s (overlap) or backwards joins nothing
+    assert [(a, b) for a, b, _ in strided_runs([mv[8:16], mv[4:12], mv[0:8]], 8)] == [
+        (0, 1), (1, 2), (2, 3)]
+    del runs, whole

@@ -80,24 +80,34 @@ def test_deal_gives_every_group_its_share_and_one_device_the_old_order(dp):
 # -- ragged batches, one at a time: the counters against hand arithmetic ------------------
 
 
+def _window_blocks(seed: int, n: int) -> list[memoryview]:
+    """n blocks as views into one window: one run, which the dealing splits
+    across the dp groups."""
+    mv = memoryview(bytearray(b"".join(_blocks(seed, n))))
+    return [mv[i * BLOCK : (i + 1) * BLOCK] for i in range(n)]
+
+
 @needs_four
+@pytest.mark.parametrize("source", ["bytes", "window"])
 @pytest.mark.parametrize("shape", SHAPES, ids=_shape_id)
-def test_ragged_batches_on_a_mesh_equal_the_references_and_the_arithmetic(shape):
+def test_ragged_batches_on_a_mesh_equal_the_references_and_the_arithmetic(shape, source):
     dp, tp, sp = shape
     codec = BatchingDeviceCodec(block_size=BLOCK, max_batch=64, batch_timeout_s=0.25,
                                 mesh=mesh_lib.make_mesh(4, shape))
     puts_before = _mesh_put_count()
     want = {"even": 0.0, "fullest": 0, "chip_batches": 0, "chips": [0] * dp, "h2d": 0,
-            "d2h": 0, "padded": 0}
+            "d2h": 0, "padded": 0, "copies": 0}
     try:
         for n in RAGGED:
-            blocks = _blocks(100 + n, n)
+            blocks = (_blocks if source == "bytes" else _window_blocks)(100 + n, n)
             # one call, one thread: the hold gathers all n blocks into one batch
             got = codec.encode(blocks, K, M)
             for block, (rows, digests) in zip(blocks, got):
-                want_rows, want_digests = ref.encode_block(block, K, M)
+                want_rows, want_digests = ref.encode_block(bytes(block), K, M)
                 assert rows == want_rows and digests == want_digests
             b_pad = -(-batching._bucket(n) // dp) * dp
+            # a bytes block is a copy of its own; a window's run one a dp group
+            want["copies"] += n if source == "bytes" else min(n, dp)
             want["even"] += n / dp
             want["fullest"] += -(-n // dp)
             want["chip_batches"] += min(n, dp) * tp * sp
@@ -117,6 +127,7 @@ def test_ragged_batches_on_a_mesh_equal_the_references_and_the_arithmetic(shape)
     assert st["mesh_chip_batches"] == want["chip_batches"]
     assert st["chip_blocks"] == want["chips"] and sum(st["chip_blocks"]) == sum(RAGGED)
     assert st["h2d_bytes"] == want["h2d"] and st["d2h_bytes"] == want["d2h"]
+    assert st["pack_copies"] == want["copies"]
     assert ("codec", "mesh-put") in STAGES
     assert _mesh_put_count() - puts_before == len(RAGGED)  # one record a batch
 
@@ -370,7 +381,7 @@ def test_the_four_chip_cell_is_in_the_manifest_as_data():
     throughput = [m for m in man["end_to_end"] if m["name"] == "throughput"][0]
     assert throughput["workloads"][-1] == "put64m-c8-chip4"
     mine = [m for m in man["per_layer"] if m.get("workloads") == ["put64m-c8-chip4"]]
-    assert len(mine) == 15 and all(m["name"].endswith(".chip4") for m in mine)
+    assert len(mine) == 16 and all(m["name"].endswith(".chip4") for m in mine)
     assert not any("roofline" in m["name"] for m in mine)
     counters = set(BatchingDeviceCodec().stats()) | {"compiles", "cache_entries"}
     for m in mine:
