@@ -41,6 +41,32 @@ window itself wrote:
                           of its parts, each part a stream of its own (8 of an
                           8 MiB part, 64 of the upload)
 
+A cell whose traffic heals (op HEAL, drives replaced before every op) is held
+to five numbers more, and `device_blocks_missing` counts the blocks its
+acknowledged heals rebuilt (the pool's full blocks, each op) against the
+codec's `blocks_reconstructed`:
+
+    heals_short           1 where no HEAL was acknowledged from ramp to drain
+                          (each acknowledged one reported every object of the
+                          pool scanned and healed and none failed), else 0
+    healed_shard_mismatch shard files on the replaced drives that differ, after
+                          the drain, from the sha256 recorded right after
+                          `populate`, before the first swap (a missing file
+                          differs)
+    reference_mismatch    of a sample, drawn from the seed, of the pool's
+                          objects, healed shard files on the replaced drives
+                          that differ from what the plain reference
+                          (harness/reference.py: numpy RS and HighwayHash-256
+                          from the seeded body, the configuration's stated
+                          geometry) gives
+    host_fallback_recon_blocks, host_fallback_digest_chunks
+                          blocks rebuilt and chunks verified by the host codec
+                          from ramp to drain: the device verifies and rebuilds
+                          a heal, or the cell times something else
+
+Its degraded sample loses its data shards on drives other than the replaced
+ones, so that the healed rows are among the survivors the read needs.
+
 An MPUT (a whole multipart upload) is a write like a PUT: its key's record is
 the sha256 of the whole body and the ETag the parts' md5s give, both held at
 read-back; the degraded sample reads completed uploads back across their part
@@ -50,7 +76,10 @@ Every comparison is exact, so every limit is 0. The control (one parity shard
 fewer than the configuration states, which a run cannot tell from the outside
 until drives are lost) reads ``degraded_mismatch`` = the sample size; where the
 `prepare` step lost as many data shards as the configuration tolerates, every
-GET of the window fails too (``ops_failed``).
+GET of the window fails too (``ops_failed``). A heal cell's control (13+3)
+heals its three rows and its files differ from the reference's 12+4
+(``reference_mismatch``); its degraded sample cannot be read
+(``degraded_mismatch``).
 """
 
 from __future__ import annotations
@@ -68,6 +97,18 @@ LIMITS = {
     "degraded_short": 0,
     "device_blocks_missing": 0,
 }
+HEAL_LIMITS = {
+    "heals_short": 0,
+    "healed_shard_mismatch": 0,
+    "reference_mismatch": 0,
+    "host_fallback_recon_blocks": 0,
+    "host_fallback_digest_chunks": 0,
+}
+
+
+def limits(heals: bool) -> dict[str, float]:
+    """The numbers a cell is held to: a heal cell's five more."""
+    return {**LIMITS, **HEAL_LIMITS} if heals else LIMITS
 
 
 def _acknowledged_writes(ops: list) -> list:
@@ -145,11 +186,19 @@ def degraded_blocks_got(ops: list, degraded: set[str], block_bytes: int) -> int:
                and op[window.END] <= rewritten.get(op[window.KEY], never))
 
 
-def decide(numbers: dict[str, float]) -> tuple[bool, dict]:
+def healed_blocks(ops: list, block_bytes: int) -> int:
+    """Full blocks the acknowledged HEALs rebuilt, ramp to drain: each op's
+    bytes are the pool's, whole blocks."""
+    return sum(op[window.NBYTES] // block_bytes for op in ops
+               if op[window.OK] and op[window.KIND] == "HEAL")
+
+
+def decide(numbers: dict[str, float],
+           held_to: dict[str, float] | None = None) -> tuple[bool, dict]:
     """(correct, compared): each number beside its limit. A number that was not
     read fails: nothing is correct by default."""
     compared, correct = {}, True
-    for name, limit in LIMITS.items():
+    for name, limit in (held_to or LIMITS).items():
         value = numbers.get(name)
         compared[name] = {"value": value, "limit": limit}
         if value is None or value > limit:

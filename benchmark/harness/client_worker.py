@@ -17,6 +17,8 @@ Protocol: one JSON object per line on stdin, one per line on stdout.
     {"cmd": "run", "start", "t0", "t1"} -> {"ops": [...], "cpu_s", ...}
     {"cmd": "verify", "keys": [...]|null} -> {"verified": n, "mismatch": [...]}
     {"cmd": "live"}                    -> {"live": n}   keys with a body on record
+    {"cmd": "warm_heal"}               -> {"ok": bool, "detail": str}
+    {"cmd": "slices", "keys": [...]}   -> {"offsets": {key: offset of its body slice}}
     {"cmd": "exit"}
 
 Times are ``time.monotonic()``: CLOCK_MONOTONIC is one clock for every process
@@ -33,6 +35,15 @@ of its own from a small pool of threads in this process), Complete. The client
 keeps its own account of it: the md5 of each part, held against the ``ETag``
 the UploadPart answered; ``md5(the parts' binary md5s) + "-N"``, held against
 the Complete's ``ETag`` inside the op and against the GET's at read-back.
+
+A ``HEAL`` is ``mc admin heal -r`` after a drive swap: just before the op's
+clock starts the client empties the bucket's directory on each drive the spec
+names as replaced (every object directory goes; ``format.json`` and
+``.minio.sys`` stay: a new drive the disk monitor has formatted), then the op
+runs from a signed ``POST /mtpu/admin/v1/heal`` of the bucket to the poll of
+``GET /mtpu/admin/v1/heal/{seq}`` (every ``poll_s``) that reads ``running``
+false. It counts the pool's bytes when ``scanned == healed ==`` the pool's
+objects and ``failed == 0``, and only then.
 """
 
 from __future__ import annotations
@@ -43,9 +54,11 @@ import hmac
 import http.client
 import json
 import math
+import os
 import queue
 import random
 import re
+import shutil
 import sys
 import time
 import urllib.parse
@@ -56,6 +69,7 @@ EMPTY_SHA256 = hashlib.sha256(b"").hexdigest()
 N_OFFSETS = 8  # body slices per client; sha256 of each is made before the window
 OFFSET_SPAN = 1 << 20  # slices start inside the buffer's first MiB
 MIX_SUM = 100  # a mix is shares of 100
+ADMIN = "/mtpu/admin/v1"
 
 
 # -- SigV4 (copy of minio_tpu/api/auth.py, signing side only) -------------------
@@ -168,28 +182,34 @@ def client_keys(keys: dict, idx: int, n: int) -> list[str]:
     raise ValueError(f"unknown keys.kind {keys['kind']!r}")
 
 
+def body_buffer(seed: int, idx: int, size: int) -> tuple[bytes, random.Random, list[int]]:
+    """Client `idx`'s buffer, its generator of ops (as the offsets leave it)
+    and the offsets of its N_OFFSETS body slices of `size` bytes: the seed
+    alone decides all three."""
+    import numpy as np
+
+    buf = np.random.default_rng([seed, idx, 1]).bytes(size + OFFSET_SPAN)
+    rnd = random.Random(seed * 1_000_003 + idx)
+    return buf, rnd, sorted(rnd.sample(range(OFFSET_SPAN), N_OFFSETS))
+
+
 class Client:
     def __init__(self, spec: dict):
-        import numpy as np
-
         self.spec = spec
         self.idx, self.n = spec["client"], spec["clients"]
         self.bucket = spec["bucket"]
         self.size = spec["object_bytes"]
         self.s3 = S3Conn(spec["endpoint"], spec["access"], spec["secret"], spec["region"],
                          spec["timeout_s"])
-        seed = spec["seed"]
-        rng = np.random.default_rng([seed, self.idx, 1])
-        self.buf = rng.bytes(self.size + OFFSET_SPAN)
-        self.rnd = random.Random(seed * 1_000_003 + self.idx)
-        offs = sorted(self.rnd.sample(range(OFFSET_SPAN), N_OFFSETS))
+        self.buf, self.rnd, self.offsets = body_buffer(spec["seed"], self.idx, self.size)
         view = memoryview(self.buf)
-        self.bodies = [view[o:o + self.size] for o in offs]
+        self.bodies = [view[o:o + self.size] for o in self.offsets]
         self.shas = [hashlib.sha256(b).hexdigest() for b in self.bodies]
         self.scratch = bytearray(self.size)
         self.keys = client_keys(spec["keys"], self.idx, self.n)
         self.ring_next = 0
         self.state: dict[str, str | None] = {}  # key -> sha256 acknowledged, None = deleted
+        self.slice_of: dict[str, int] = {}  # key -> body slice of its acknowledged write
         self.deck: list[str] = []
         mix = spec["mix"]
         if sum(mix.values()) != MIX_SUM:
@@ -209,6 +229,7 @@ class Client:
         self.mp = spec.get("multipart")
         if self.mp:
             self._prepare_parts()
+        self.heal_spec = spec.get("heal")
 
     def _prepare_parts(self) -> None:
         """The client's own account of every upload it can send: for each body
@@ -250,6 +271,7 @@ class Client:
             self._void(key)
             return False, 0, f"PUT {key}: HTTP {status} {data[:200]!r}"
         self.state[key] = self.shas[i]
+        self.slice_of[key] = i
         self.etags.pop(key, None)
         return True, self.size, ""
 
@@ -318,6 +340,7 @@ class Client:
                               f"{found.group(1) if found else data[:200]!r}, "
                               f"due {self.mp_etags[i]}")
         self.state[key] = self.shas[i]
+        self.slice_of[key] = i
         self.etags[key] = self.mp_etags[i]
         return True, self.size, ""
 
@@ -370,6 +393,51 @@ class Client:
         self.etags.pop(key, None)
         return True, 0, ""
 
+    def replace_drives(self, key: str = "") -> None:
+        """The drive swap before a HEAL: empty the bucket's directory on each
+        replaced drive (or remove one object's directory there); the bucket
+        directory, format.json and .minio.sys stay."""
+        for drive in self.heal_spec["replace_dirs"]:
+            top = os.path.join(drive, self.bucket)
+            for name in [key] if key else os.listdir(top):
+                if os.path.isdir(os.path.join(top, name)):
+                    shutil.rmtree(os.path.join(top, name))
+
+    def heal(self, prefix: str = "") -> tuple[bool, int, str]:
+        """Start a heal sequence of the bucket (or of `prefix` in it) and poll
+        it until it ends; ok when every object of the pool (1 under a prefix)
+        was scanned and healed and none failed."""
+        want = 1 if prefix else int(self.heal_spec["objects"])
+        doc = json.dumps({"bucket": self.bucket, **({"prefix": prefix} if prefix else {})}).encode()
+        status, _, _, data = self.s3.request("POST", ADMIN + "/heal", doc,
+                                             hashlib.sha256(doc).hexdigest())
+        if status != 200:
+            return False, 0, f"HEAL start: HTTP {status} {data[:200]!r}"
+        seq = json.loads(data)["healSequence"]
+        deadline = time.monotonic() + float(self.spec["timeout_s"])
+        while True:
+            time.sleep(float(self.heal_spec["poll_s"]))
+            status, _, _, data = self.s3.request("GET", f"{ADMIN}/heal/{seq}")
+            if status != 200:
+                return False, 0, f"HEAL {seq} status: HTTP {status} {data[:200]!r}"
+            st = json.loads(data)
+            if not st["running"]:
+                break
+            if time.monotonic() > deadline:
+                return False, 0, f"HEAL {seq} still running after {self.spec['timeout_s']}s"
+        if not (st["scanned"] == st["healed"] == want and st["failed"] == 0):
+            return False, 0, (f"HEAL {seq} ended scanned {st['scanned']} healed {st['healed']} "
+                              f"failed {st['failed']}, {want} objects due")
+        return True, want * self.size, ""
+
+    def warm_heal(self) -> dict:
+        """Set-up: swap the drives under one object of the pool and heal it, so
+        that the programs a heal runs are compiled (or loaded) before the ramp."""
+        key = self.keys[0]
+        self.replace_drives(key)
+        ok, _, detail = self.heal(key)
+        return {"ok": ok, "detail": detail, "key": key}
+
     # -- the op generator --------------------------------------------------------
 
     def next_op(self) -> tuple[str, str]:
@@ -378,6 +446,8 @@ class Client:
         every seed sends the same mix in another order; reads and deletes take
         a present key, a PUT or MPUT re-creates a deleted key first and
         overwrites otherwise."""
+        if self.heal_spec:
+            return "HEAL", self.bucket
         if self.ring_op:
             key = self.keys[self.ring_next % len(self.keys)]
             self.ring_next += 1
@@ -397,6 +467,8 @@ class Client:
 
     def do(self, kind: str, key: str) -> tuple[bool, int, str]:
         try:
+            if kind == "HEAL":
+                return self.heal()
             return {"PUT": self.put, "MPUT": self.mput, "GET": self.get, "STAT": self.stat,
                     "DELETE": self.delete}[kind](key)
         except (OSError, http.client.HTTPException) as e:
@@ -433,6 +505,8 @@ class Client:
             if now >= t1:
                 break
             kind, key = self.next_op()
+            if kind == "HEAL":
+                self.replace_drives()  # the swap is done before the op's clock starts
             a = time.monotonic()
             ok, nbytes, detail = self.do(kind, key)
             b = time.monotonic()
@@ -485,6 +559,10 @@ def main(argv: list[str]) -> int:
             say(client.verify(cmd.get("keys")))
         elif what == "live":
             say({"live": sum(1 for v in client.state.values() if v is not None)})
+        elif what == "warm_heal":
+            say(client.warm_heal())
+        elif what == "slices":
+            say({"offsets": {k: client.offsets[client.slice_of[k]] for k in cmd["keys"]}})
         elif what == "exit":
             break
         else:

@@ -15,6 +15,8 @@ beyond its own counters and ledger:
     src["facts"]   = numbers of the window the harness counted itself
     src["geometry"] = (K, M, shard length); src["block_bytes"]; src["device_kind"]
     src["lost_data"] = data shards the traffic file's `prepare` removed per object
+    src["replaced"] = drives a heal traffic file replaces before every op (rows
+                      rebuilt a block), or 0
 
 kinds:
     ledger   rows (layer/stage) summed, field wall_s|cpu_s|count, times scale,
@@ -27,6 +29,10 @@ kinds:
              slice) | recon_ms_per_GiB | recon_roofline (the same over the
              blocks reconstructed there: in a cell whose window writes nothing
              every program found by the shard length is the reconstruct program)
+             | heal_ms_per_GiB | heal_roofline (a heal's window writes nothing:
+             the programs found by the shard length are the verify program
+             and the reconstruct + digests program, over the blocks
+             reconstructed in the slice, each a block of `replaced` rows)
     process  value: a fact by name (client_cpu, stored_per_user_byte, warmup_s)
 """
 
@@ -108,17 +114,25 @@ def read_trace(reader: dict, src: dict) -> float | None:
     value = reader["value"]
     if value == "idle_share":
         return 100.0 * (1.0 - tr["busy_s"] / tr["span_s"])
-    if value not in ("codec_ms_per_GiB", "codec_roofline", "recon_ms_per_GiB", "recon_roofline"):
+    if value not in ("codec_ms_per_GiB", "codec_roofline", "recon_ms_per_GiB", "recon_roofline",
+                     "heal_ms_per_GiB", "heal_roofline"):
         raise ValueError(f"unknown trace value {value!r}")
     recon = value.startswith("recon")
-    blocks = _counter(src["traced"], "blocks_reconstructed" if recon else "blocks_encoded")
+    heal = value.startswith("heal")
+    blocks = _counter(src["traced"],
+                      "blocks_reconstructed" if recon or heal else "blocks_encoded")
     if not blocks or not tr.get("codec_s"):
         return None  # no full block went through, or no codec program was found
     k, m, shard_len = src["geometry"]
     if value.endswith("_ms_per_GiB"):
         user_gib = blocks * src["block_bytes"] / GIB
         return tr["codec_s"] * 1e3 / user_gib
-    if recon:
+    if heal:
+        if not src.get("replaced"):
+            return None  # no drive was replaced: nothing says how many rows a block rebuilt
+        least, _bound = roofline.heal_least_seconds(
+            int(blocks), k, m, int(src["replaced"]), shard_len, src["device_kind"])
+    elif recon:
         if not src.get("lost_data"):
             return None  # the traffic file lost no shards: nothing says how many rows a block rebuilt
         least, _bound = roofline.recon_least_seconds(

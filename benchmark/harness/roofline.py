@@ -50,6 +50,17 @@ def recon_bytes_moved(data: int, rebuilt: int, shard_len: int, digests: bool) ->
     return data * shard_len + rebuilt * shard_len + (DIGEST_BYTES * rebuilt if digests else 0)
 
 
+def heal_bytes_moved(data: int, parity: int, lost: int, shard_len: int) -> int:
+    """HBM bytes the heal of one full block needs at the least, whatever programs
+    do it: the K+M-lost surviving rows read once (verified and the source of the
+    rebuild alike), the `lost` rows written, one digest for every row (the
+    survivors' to compare, the rebuilt rows' to store). Not today's separate
+    verify (K+M-lost rows read) and reconstruct (K more read) programs' bytes:
+    a program that fuses them does less, and must not read over 100 %."""
+    return ((data + parity - lost) * shard_len + lost * shard_len
+            + DIGEST_BYTES * (data + parity))
+
+
 def block_gf_ops(data: int, parity: int, shard_len: int) -> int:
     """GF(2^8) multiply-adds of the parity rows: M x K per shard byte. Not the
     bound on a v5e (see least_seconds): kept so that the figure is on record."""
@@ -77,4 +88,16 @@ def recon_least_seconds(blocks: int, data: int, rebuilt: int, shard_len: int,
     p = peaks(device_kind)
     t_hbm = blocks * recon_bytes_moved(data, rebuilt, shard_len, False) / p["hbm_bytes_per_s"]
     t_ops = blocks * block_gf_ops(data, rebuilt, shard_len) / p["int8_ops_per_s"]
+    return (t_hbm, "hbm") if t_hbm >= t_ops else (t_ops, "int8")
+
+
+def heal_least_seconds(blocks: int, data: int, parity: int, lost: int, shard_len: int,
+                       device_kind: str) -> tuple[float, str]:
+    """The least time the chip could take to heal `lost` rows of each of
+    `blocks` full blocks: heal_bytes_moved against HBM, lost x K multiply-adds a
+    shard byte against the int8 peak (the hash, as in least_seconds, counts by
+    its bytes only)."""
+    p = peaks(device_kind)
+    t_hbm = blocks * heal_bytes_moved(data, parity, lost, shard_len) / p["hbm_bytes_per_s"]
+    t_ops = blocks * block_gf_ops(data, lost, shard_len) / p["int8_ops_per_s"]
     return (t_hbm, "hbm") if t_hbm >= t_ops else (t_ops, "int8")

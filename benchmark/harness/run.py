@@ -15,16 +15,17 @@ import time
 T_IMPORT = time.monotonic()  # as near to process start as Python gets; see process_age
 
 import argparse  # noqa: E402
+import hashlib  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
 import subprocess  # noqa: E402
 import sys  # noqa: E402
 
-from . import check, readers, trace, window  # noqa: E402
-from .client_worker import S3Conn, client_keys  # noqa: E402 - imports no jax
+from . import check, readers, reference, trace, window  # noqa: E402
+from .client_worker import S3Conn, body_buffer, client_keys  # noqa: E402 - imports no jax
 from .server import (  # noqa: E402
-    ACCESS, BUCKET, REGION, SECRET, SHM, Deployment, SetupError,
+    ACCESS, BUCKET, REGION, SECRET, SHM, Deployment, SetupError, hash_order,
 )
 from .traffic import ROOT, Cell  # noqa: E402
 
@@ -55,10 +56,11 @@ def process_age() -> float:
 class Clients:
     """The client processes of a run and the line protocol with them."""
 
-    def __init__(self, cell: Cell, seed: int, endpoint: str):
+    def __init__(self, cell: Cell, seed: int, dep: Deployment):
         self.procs = []
         for i in range(cell.clients):
-            spec = cell.client_spec(i, seed, endpoint, BUCKET, ACCESS, SECRET, REGION)
+            spec = cell.client_spec(i, seed, dep.endpoint, BUCKET, ACCESS, SECRET, REGION,
+                                    dep.drive_dirs)
             self.procs.append(subprocess.Popen(
                 [sys.executable, WORKER, json.dumps(spec)],
                 stdin=subprocess.PIPE, stdout=subprocess.PIPE, text=True, bufsize=1,
@@ -78,9 +80,12 @@ class Clients:
         return [self._read(i) for i in (range(len(self.procs)) if which is None else which)]
 
     def ask_all(self, cmd: dict) -> list[dict]:
-        for i in range(len(self.procs)):
+        return self.ask(range(len(self.procs)), cmd)
+
+    def ask(self, which, cmd: dict) -> list[dict]:
+        for i in which:
             self.send(i, cmd)
-        return self.gather()
+        return self.gather(which)
 
     def close(self) -> None:
         for p in self.procs:
@@ -149,6 +154,7 @@ def run_window(cell: Cell, dep: Deployment, clients: Clients, seed: int, seconds
     the log lines."""
     t = cell.traffic
     degraded: set[str] = set()  # keys the prepare step left short of data shards
+    shards: dict = {}  # (drive, key) -> sha256 of the shard file a heal must restore
     reaper = None
     try:
         ready = clients.gather()
@@ -168,6 +174,14 @@ def run_window(cell: Cell, dep: Deployment, clients: Clients, seed: int, seconds
                     degraded.add(key)
                 say(f"removed {step['lose_shards']['data']} data shards of "
                     f"{len(degraded)} objects")
+        if cell.replaced:
+            shards = {(d, key): file_sha(dep.shard_file(d, key))
+                      for key in pool_keys(cell) for d in cell.replaced}
+            warm = clients.ask([0], {"cmd": "warm_heal"})[0]
+            if not warm["ok"]:
+                raise SetupError(f"the set-up's heal of one object failed: {warm['detail']}")
+            say(f"recorded {len(shards)} shard files on the drives to replace; healed "
+                f"{warm['key']} once to warm the heal's programs")
 
         before = dep.snapshot()
         if "reap_superseded" in t:
@@ -176,9 +190,9 @@ def run_window(cell: Cell, dep: Deployment, clients: Clients, seed: int, seconds
         go = time.monotonic() + 0.25
         t0 = go + float(t["ramp_s"])
         t1 = t0 + seconds
-        for i in range(cell.clients):
+        for i in range(cell.runners):
             clients.send(i, {"cmd": "run", "t0": t0, "t1": t1,
-                             "start": go + i / cell.clients * float(t["typical_op_s"])})
+                             "start": go + i / cell.runners * float(t["typical_op_s"])})
         setup_s = go - t_process
         say(f"set-up done after {setup_s:.2f}s; ramp {t['ramp_s']}s, window {seconds}s")
 
@@ -199,7 +213,7 @@ def run_window(cell: Cell, dep: Deployment, clients: Clients, seed: int, seconds
         sleep_until(t1)
         snap_b, cpu_b = dep.snapshot(), time.process_time()
 
-        results = clients.gather()
+        results = clients.gather(range(cell.runners))
         if reaper is not None:
             swept, reaper = reaper.close(), None
             say(f"superseded data directories removed from ramp to drain: {json.dumps(swept)}")
@@ -224,7 +238,7 @@ def run_window(cell: Cell, dep: Deployment, clients: Clients, seed: int, seconds
             f"({len(os.sched_getaffinity(0))} cpus to run on)")
 
         numbers, stored, live = run_check(cell, dep, clients, ops, (a, b), seed, before, after,
-                                          degraded)
+                                          degraded, shards)
     finally:
         if reaper is not None:
             reaper.close()
@@ -243,7 +257,8 @@ def run_window(cell: Cell, dep: Deployment, clients: Clients, seed: int, seconds
     }
     src = {"window": (snap_a, snap_b), "traced": traced_pair, "trace": {}, "facts": facts,
            "geometry": cell.geometry, "block_bytes": cell.config["block_bytes"],
-           "device_kind": device["kind"], "lost_data": cell.lost_data}
+           "device_kind": device["kind"], "lost_data": cell.lost_data,
+           "replaced": len(cell.replaced)}
     if traced:
         xplane = trace.find_xplane(TRACE_DIR)
         if describe_to:
@@ -269,10 +284,12 @@ def op_facts(ops: list, a: float, b: float, part_ends: list[float]) -> dict:
     """What the window [a, b) holds of the clients' ops, for the per-layer
     readers to divide by. A completed MPUT is one op, one of `puts_ended`, its
     bytes in `put_MiB`: a PUT-path metric reads per object and per MiB under
-    either kind of write. `part_ends`: when each UploadPart answered 200."""
+    either kind of write. A HEAL's bytes (the pool's) are `heal_MiB`.
+    `part_ends`: when each UploadPart answered 200."""
     inside = window.ended_inside(ops, a, b)
     puts = [op for op in ops if op[window.KIND] in check.WRITES]
     gets = [op for op in ops if op[window.KIND] == "GET"]
+    heals = [op for op in ops if op[window.KIND] == "HEAL"]
     return {
         "ops_ended": len(inside),
         "puts_ended": len([op for op in inside if op[window.KIND] in check.WRITES]),
@@ -281,6 +298,8 @@ def op_facts(ops: list, a: float, b: float, part_ends: list[float]) -> dict:
         "put_MiB": window.prorated_rate(puts, a, b, by_bytes=True) * (b - a) / window.MIB,
         "gets_ended": len([op for op in inside if op[window.KIND] == "GET"]),
         "get_MiB": window.prorated_rate(gets, a, b, by_bytes=True) * (b - a) / window.MIB,
+        "heals_ended": len([op for op in inside if op[window.KIND] == "HEAL"]),
+        "heal_MiB": window.prorated_rate(heals, a, b, by_bytes=True) * (b - a) / window.MIB,
     }
 
 
@@ -290,15 +309,59 @@ def pool_keys(cell: Cell) -> list[str]:
             for key in client_keys(cell.traffic["keys"], i, cell.clients)]
 
 
+def file_bytes(path: str | None) -> bytes | None:
+    if path is None:
+        return None
+    with open(path, "rb") as f:
+        return f.read()
+
+
+def file_sha(path: str | None) -> str | None:
+    data = file_bytes(path)
+    return None if data is None else hashlib.sha256(data).hexdigest()
+
+
+def check_heal(cell: Cell, dep: Deployment, clients: Clients, seed: int,
+               shards: dict) -> dict[str, float]:
+    """A heal cell's shard files after the drain: against the record taken
+    before the first swap, and a sample of objects against the plain reference."""
+    t_ref = time.monotonic()
+    changed = sum(1 for (d, key), sha in shards.items()
+                  if sha is None or file_sha(dep.shard_file(d, key)) != sha)
+    sample = check.pool_sample(pool_keys(cell), seed + 2,
+                               int(cell.traffic.get("check", {}).get("reference_sample", 2)))
+    k, m, size = cell.config["data"], cell.config["parity"], int(cell.traffic["object_bytes"])
+    wrong = 0
+    for key in sample:
+        owner = owner_of(key)
+        offset = clients.ask([owner], {"cmd": "slices", "keys": [key]})[0]["offsets"][key]
+        buf, _, _ = body_buffer(seed, owner, size)
+        rows = [hash_order(f"{BUCKET}/{key}", dep.drives)[d] - 1 for d in cell.replaced]
+        due = reference.shard_files(buf[offset:offset + size], k, m,
+                                    int(cell.config["block_bytes"]), rows)
+        for d, row, want in zip(cell.replaced, rows, due):
+            if file_bytes(dep.shard_file(d, key)) != want:
+                wrong += 1
+                say(f"check: {key}: the shard file of row {row} on drive d{d + 1} is not "
+                    "the plain reference's")
+    say(f"check of the heal took {time.monotonic() - t_ref:.2f}s: {changed} of {len(shards)} "
+        f"shard files on the replaced drives differ from the record; {wrong} of "
+        f"{len(sample) * len(cell.replaced)} ({', '.join(sample)}) from the plain reference")
+    return {"healed_shard_mismatch": changed, "reference_mismatch": wrong}
+
+
 def run_check(cell: Cell, dep: Deployment, clients: Clients, ops: list,
               win: tuple[float, float], seed: int, before: dict, after: dict,
-              degraded: set[str]):
+              degraded: set[str], shards: dict):
     """The comparison that decides `correct` (harness/check.py), once the window
     has closed: (numbers compared, bytes on the drives, live objects).
-    `degraded` are the keys the prepare step left short of data shards."""
+    `degraded` are the keys the prepare step left short of data shards;
+    `shards` the shard files a heal cell's replaced drives held before the swap."""
     t, (a, b) = cell.traffic, win
     t_check = time.monotonic()
     numbers: dict[str, float] = {"ops_failed": sum(1 for op in ops if not op[window.OK])}
+    if cell.replaced:
+        numbers.update(check_heal(cell, dep, clients, seed, shards))
     chk = t.get("check", {})
     stored = dep.stored_bytes()
     live = sum(v["live"] for v in clients.ask_all({"cmd": "live"}))
@@ -312,18 +375,29 @@ def run_check(cell: Cell, dep: Deployment, clients: Clients, ops: list,
         sample, drawn_from = check.pool_sample(pool_keys(cell), seed, n_degraded), "populated pool"
     lost_all = int(cell.config["guarantees"]["drives_lost_tolerated"])
     for key in sample:
-        dep.lose_shards(key, lost_all)
+        dep.lose_shards(key, lost_all, spare=tuple(cell.replaced))
     deg = verify_keys(clients, sample)
     numbers["degraded_mismatch"] = sum(v["mismatches"] for v in deg)
     numbers["degraded_short"] = max(1, len(sample)) - sum(v["verified"] for v in deg)
     block = cell.config["block_bytes"]
     codec = {name: after["codec"].get(name, 0) - before["codec"].get(name, 0)
-             for name in ("blocks_encoded", "blocks_reconstructed", "host_fallback_recon_blocks")}
+             for name in ("blocks_encoded", "blocks_reconstructed", "host_fallback_recon_blocks",
+                          "host_fallback_digest_chunks")}
     sent_blocks = check.full_blocks_put(ops, block, cell.part_bytes)
     got_blocks = check.degraded_blocks_got(ops, degraded, block)
+    healed = check.healed_blocks(ops, block)
     numbers["device_blocks_missing"] = (
         max(0, sent_blocks - codec["blocks_encoded"])
-        + max(0, got_blocks - codec["blocks_reconstructed"]))
+        + max(0, got_blocks + healed - codec["blocks_reconstructed"]))
+    if cell.replaced:
+        numbers["heals_short"] = int(not any(
+            op[window.OK] and op[window.KIND] == "HEAL" for op in ops))
+        numbers["host_fallback_recon_blocks"] = codec["host_fallback_recon_blocks"]
+        numbers["host_fallback_digest_chunks"] = codec["host_fallback_digest_chunks"]
+        say(f"device reconstructed {codec['blocks_reconstructed']} blocks ramp to drain, the "
+            f"acknowledged heals rebuilt {healed}; host_fallback_recon_blocks moved by "
+            f"{codec['host_fallback_recon_blocks']}, host_fallback_digest_chunks by "
+            f"{codec['host_fallback_digest_chunks']}")
     for v in back + deg:
         for e in v["mismatch"]:
             say(f"check: {e}")
@@ -359,7 +433,7 @@ def verify_keys(clients: Clients, keys: list[str]) -> list[dict]:
 
 
 def result_line(cell: Cell, out: dict, traced: bool, rehearse: bool, control: str | None) -> dict:
-    correct, compared = check.decide(out["numbers"])
+    correct, compared = check.decide(out["numbers"], check.limits(bool(cell.replaced)))
     metrics: dict[str, dict] = {}
     if rehearse:
         pass  # a CPU run prints no number under the name of a device metric
@@ -400,7 +474,7 @@ def execute(args, deployment_hook=None) -> tuple[int, dict | None]:
     cell = Cell(args.workload, rehearse=args.rehearse)
     parity = cell.config["parity"] - 1 if args.control == "parity-1" else None
     dep = Deployment(cell.config, rehearse=args.rehearse, parity=parity)
-    clients = Clients(cell, args.seed, dep.endpoint)
+    clients = Clients(cell, args.seed, dep)
     line = None
     try:
         dep.start(cell.footprint_bytes(), cell.traffic.get("mallopt"))

@@ -14,6 +14,7 @@ The boot, the wait for the takeover and the shard-losing helpers follow
 
 from __future__ import annotations
 
+import glob
 import os
 import shutil
 import socket
@@ -245,22 +246,30 @@ class Deployment:
         return [(order[i] - 1, os.path.join(self.drive_dirs[i], BUCKET, key))
                 for i in range(self.drives)]
 
-    def lose_shards(self, key: str, data: int) -> list[str]:
+    def lose_shards(self, key: str, data: int, spare: tuple[int, ...] = ()) -> list[str]:
         """Bring an object to `data` missing data shards: the victims are the
-        first `data` drives, in drive order, that hold a data row, so a second
-        call finds what the first removed and removes nothing more. Any other
-        directory of the object that is not there is an error: the object was
-        not stored where the placement says. Returns the victims."""
+        first `data` drives, in drive order, that hold a data row, the drives
+        `spare` (0-based) passed over, so a second call finds what the first
+        removed and removes nothing more. Any other directory of the object
+        that is not there, on a drive not spared, is an error: the object was
+        not stored where the placement says (what a spare drive holds is the
+        heal check's to judge). Returns the victims."""
         k = self.drives - self.parity
         dirs = self.shard_dirs(key)
-        victims = [d for row, d in dirs if row < k][:data]
-        for _, d in dirs:
-            if d not in victims and not os.path.isdir(d):
+        victims = [d for i, (row, d) in enumerate(dirs) if row < k and i not in spare][:data]
+        for i, (_, d) in enumerate(dirs):
+            if d not in victims and i not in spare and not os.path.isdir(d):
                 raise FileNotFoundError(f"expected shard directory {d}")
         for d in victims:
             if os.path.isdir(d):
                 shutil.rmtree(d)
         return victims
+
+    def shard_file(self, drive: int, key: str) -> str | None:
+        """The object's shard file (``part.1`` under its data directory) on
+        drive `drive` (0-based), or None where there is none."""
+        found = glob.glob(os.path.join(self.drive_dirs[drive], BUCKET, key, "*", "part.1"))
+        return found[0] if len(found) == 1 else None
 
     def stored_bytes(self) -> int:
         return sum(dir_bytes(os.path.join(d, BUCKET)) for d in self.drive_dirs)

@@ -35,15 +35,18 @@ TRAFFIC_FIELDS = {
     "name", "source", "reduced", "assumed", "why", "loop", "clients", "object_bytes",
     "mix", "keys", "prepare", "typical_op_s", "ramp_s", "trace_seconds", "check",
     "op_timeout_s", "tmpfs_bytes", "tmpfs_why", "multipart", "reap_superseded", "mallopt",
+    "heal",
 }
 METRIC_FIELDS = {"name", "unit", "better", "layer", "moves", "source", "workloads",
                  "reader", "what"}
 PREPARE_STEPS = {"populate", "lose_shards"}
-CHECK_FIELDS = {"readback_sample", "degraded_sample"}
+CHECK_FIELDS = {"readback_sample", "degraded_sample", "reference_sample"}
 MULTIPART_FIELDS = {"part_bytes", "parts_in_flight"}
 REAP_FIELDS = {"keep", "every_s"}
+HEAL_FIELDS = {"replace", "poll_s"}
+HEAL_RAMP = 1.5  # a heal traffic's ramp, in typical ops: the first heal ends before the window
 MALLOPT_MAX = 2**31 - 1  # mallopt takes an int
-OPS = {*check.WRITES, "GET", "STAT", "DELETE"}
+OPS = {*check.WRITES, "GET", "STAT", "DELETE", "HEAL"}
 MIN_PART_BYTES = 5 << 20  # S3's least part, the last excepted (the program holds a Complete to it)
 
 
@@ -106,6 +109,25 @@ def load_traffic(name: str) -> dict:
         if mp["parts_in_flight"] < 1 or (several and mp["part_bytes"] < MIN_PART_BYTES):
             raise ValueError(f"traffic mix {name}: multipart wants parts_in_flight >= 1 and "
                              f"part_bytes >= {MIN_PART_BYTES} where an object has more than one")
+    if ("HEAL" in t["mix"]) != ("heal" in t):
+        raise ValueError(f"traffic mix {name}: HEAL in the mix and the field heal "
+                         "come together or not at all")
+    if "heal" in t:
+        h = t["heal"]
+        rep = h.get("replace")
+        if (set(h) != HEAL_FIELDS or not isinstance(rep, list) or not rep
+                or len(set(rep)) != len(rep)
+                or not all(isinstance(d, int) and d >= 1 for d in rep) or h["poll_s"] <= 0):
+            raise ValueError(f"traffic mix {name}: heal takes {sorted(HEAL_FIELDS)}: distinct "
+                             "drive numbers from 1, and poll_s > 0")
+        steps = [next(iter(step)) for step in t.get("prepare", [])]
+        if (t["mix"] != {"HEAL": 100} or t["keys"]["kind"] != "pool"
+                or steps != ["populate"]):
+            raise ValueError(f"traffic mix {name}: a heal is HEAL alone over a pool that "
+                             "`populate` put, and nothing else prepared")
+        if t["ramp_s"] < HEAL_RAMP * t["typical_op_s"]:
+            raise ValueError(f"traffic mix {name}: a heal's ramp_s is at least {HEAL_RAMP} "
+                             "typical ops")
     if "reap_superseded" in t:
         reap = t["reap_superseded"]
         if (set(reap) != REAP_FIELDS or reap["keep"] < 2 or reap["every_s"] <= 0
@@ -152,6 +174,10 @@ class Cell:
         self.traffic = load_traffic(self.entry["traffic"])
         if rehearse:
             self._shrink()
+        if self.replaced and (max(self.replaced) >= self.config["drives"] or len(self.replaced)
+                              > self.config["guarantees"]["drives_lost_tolerated"]):
+            raise ValueError(f"cell {workload}: replaces drives the configuration does not "
+                             "have, or more than it tolerates losing")
         if self.config["chips"] != self.chips:
             raise ValueError(f"cell {workload}: chips {self.chips} but configuration "
                              f"{self.config['name']} is laid out for {self.config['chips']}")
@@ -186,6 +212,18 @@ class Cell:
         return int(self.traffic["clients"])
 
     @property
+    def runners(self) -> int:
+        """Clients that run the closed loop. A heal is one sequence at a time
+        (`mc admin heal` runs one): client 0 heals, the others put the pool
+        and hold its records, as the clients of a read-only cell do."""
+        return 1 if "heal" in self.traffic else self.clients
+
+    @property
+    def replaced(self) -> list[int]:
+        """0-based indices of the drives a heal traffic replaces before every op."""
+        return [d - 1 for d in self.traffic.get("heal", {}).get("replace", [])]
+
+    @property
     def populates(self) -> bool:
         return any("populate" in step for step in self.traffic.get("prepare", []))
 
@@ -202,7 +240,8 @@ class Cell:
         return int(self.traffic["tmpfs_bytes"])
 
     def client_spec(self, idx: int, seed: int, endpoint: str, bucket: str,
-                    access: str, secret: str, region: str) -> dict:
+                    access: str, secret: str, region: str,
+                    drive_dirs: list[str] | None = None) -> dict:
         t = self.traffic
         spec = {
             "client": idx, "clients": self.clients, "seed": seed, "endpoint": endpoint,
@@ -212,6 +251,9 @@ class Cell:
         }
         if "multipart" in t:
             spec["multipart"] = t["multipart"]
+        if "heal" in t:
+            spec["heal"] = {"replace_dirs": [drive_dirs[d] for d in self.replaced],
+                            "poll_s": float(t["heal"]["poll_s"]), "objects": int(t["keys"]["objects"])}
         return spec
 
     @property

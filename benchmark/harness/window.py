@@ -1,8 +1,9 @@
 """The arithmetic of the measured window.
 
 An op is ``[kind, key, start, end, nbytes, ok]`` on the shared monotonic
-clock. A rate is all the correct work over all the time of the window: each
-correct op contributes its weight (bytes, or 1) times the share of its own
+clock (a HEAL's key is the bucket, its bytes the pool's). A rate is all the
+correct work over all the time of the window: each correct op contributes its
+weight (bytes, or 1) times the share of its own
 [start, end] that lies inside [t0, t1]. An op that began in the ramp or ended
 in the drain counts for the part inside; a window in which the server stalled
 reads low, not empty. Nothing here is a median of pieces.
@@ -54,6 +55,9 @@ def end_to_end(ops: list, t0: float, t1: float) -> dict[str, float]:
     out = {
         "throughput": prorated_rate(ops, t0, t1, by_bytes=True) / MIB,
         "ops_rate": prorated_rate(ops, t0, t1, by_bytes=False),
+        # the pool's bytes brought back to full redundancy, a HEAL op's weight
+        "heal_rate": prorated_rate([op for op in ops if op[KIND] == "HEAL"], t0, t1,
+                                   by_bytes=True) / MIB,
     }
     lat = [(op[END] - op[START]) * 1e3 for op in ended_inside(ops, t0, t1)]
     if lat:

@@ -46,9 +46,9 @@ def main(argv: list[str]) -> int:
         run.make_bucket(dep)
         for seed in (int(s) for s in args.seeds.split(",")):
             dep.wipe_objects()  # every overwrite leaves its old data directory behind
-            clients = run.Clients(cell, seed, dep.endpoint)
+            clients = run.Clients(cell, seed, dep)
             out = run.run_window(cell, dep, clients, seed, args.seconds, False, run.T_IMPORT)
-            correct, compared = check.decide(out["numbers"])
+            correct, compared = check.decide(out["numbers"], check.limits(bool(cell.replaced)))
             # A CPU number is never written under the name of a device metric.
             e2e = {} if args.rehearse else {k: v for k, v in out["e2e"].items() if k != "setup_s"}
             print(json.dumps({"workload": args.workload, "seed": seed, "control": args.control,

@@ -106,3 +106,38 @@ def test_lose_shards_still_refuses_an_object_that_is_not_where_the_placement_say
     with pytest.raises(FileNotFoundError):
         dep.lose_shards(key, 4)
     assert all(os.path.isdir(d) for d in victims)  # refused before anything was removed
+
+
+def test_a_heal_cell_is_held_to_five_numbers_more_and_its_blocks():
+    heals = [op("HEAL", "bench", 1, 6, 2048 * MIB), op("HEAL", "bench", 6, 11, 0, ok=False),
+             op("HEAL", "bench", 11, 16, 2048 * MIB)]
+    assert check.healed_blocks(heals, MIB) == 2 * 2048
+    assert check.healed_blocks(heals + [op("GET", "c000/o00000", 1, 2, 64 * MIB)], MIB) == 4096
+    assert check.limits(False) == check.LIMITS
+    held = check.limits(True)
+    assert set(held) - set(check.LIMITS) == set(check.HEAL_LIMITS) and all(
+        v == 0 for v in held.values())
+    numbers = {name: 0 for name in held}
+    assert check.decide(numbers, held)[0] is True
+    assert check.decide({**numbers, "reference_mismatch": 1}, held)[0] is False
+    missing = dict(numbers)
+    missing.pop("host_fallback_digest_chunks")
+    assert check.decide(missing, held)[0] is False  # a number not read fails
+    assert check.decide(missing)[0] is True  # a cell that heals nothing is not held to it
+
+
+def test_lose_shards_passes_over_the_spare_drives(tmp_path):
+    """The heal cell's degraded sample loses its data shards outside the
+    replaced drives, so the healed rows are among the survivors."""
+    dep = server.Deployment.__new__(server.Deployment)
+    dep.drives, dep.parity = 16, 4
+    dep.drive_dirs = [str(tmp_path / f"d{i + 1}") for i in range(16)]
+    key = "c000/o00003"
+    for row, d in dep.shard_dirs(key):
+        os.makedirs(d)
+    victims = dep.lose_shards(key, 4, spare=(0, 1, 2))
+    rows = dict((d, row) for row, d in dep.shard_dirs(key))
+    assert len(victims) == 4 and all(rows[v] < 12 for v in victims)
+    assert not set(victims) & set(dep.drive_dirs[i] + "/bench/" + key for i in range(3))
+    assert all(os.path.isdir(dep.drive_dirs[i] + "/bench/" + key) for i in range(3))
+    assert dep.lose_shards(key, 4, spare=(0, 1, 2)) == victims  # nothing more the second time

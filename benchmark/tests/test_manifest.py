@@ -127,16 +127,30 @@ def test_metric_files_match_manifest_entries(man):
     assert all("\n" not in layer and len(layer) <= 200 for layer in layers)
 
 
+CELLS_AND_CHIPS = {  # the manifest as it stands (PR 37): the cells in order, each one's chips
+    "put64m-c8": 1, "put64m-c8-ec4p4": 1, "mixed10m-c20": 1, "degraded-get64m-c8": 1,
+    "put64k-c32-ec2p2": 1, "mpput64m-p8m-c4": 1, "put64m-c8-chip4": 4, "heal64m-d3": 1,
+}
+
+
+def test_the_cells_and_the_chips_each_asks_for(man):
+    assert {w["name"]: w["chips"] for w in man["workloads"]} == CELLS_AND_CHIPS
+    assert [w["name"] for w in man["workloads"]] == list(CELLS_AND_CHIPS)
+    four = sum(w["chips"] == 4 for w in man["workloads"])
+    assert four <= max(1, len(man["workloads"]) // 2)
+
+
 def test_the_multipart_cell_its_metrics_and_the_facts_they_divide_by(man):
-    """PR 34: six cells, all on one chip; `throughput` in four; the PUT-path
-    metrics the multipart path feeds take the cell, five new ones read it alone;
-    every ledger reader's `per` is a fact the harness counts."""
+    """PR 34: the multipart cell on one chip; `throughput` in five cells (PR 35
+    added the four-chip PUT); the PUT-path metrics the multipart path feeds take
+    the cell, five new ones read it alone; every ledger reader's `per` is a fact
+    the harness counts."""
     cell_name = "mpput64m-p8m-c4"
-    assert [w["name"] for w in man["workloads"]][-1] == cell_name
-    assert len(man["workloads"]) == 6 and all(w["chips"] == 1 for w in man["workloads"])
+    assert CELLS_AND_CHIPS[cell_name] == 1
     e2e = {m["name"]: m for m in man["end_to_end"]}
     assert e2e["throughput"]["workloads"] == ["put64m-c8", "put64m-c8-ec4p4",
-                                              "degraded-get64m-c8", cell_name]
+                                              "degraded-get64m-c8", cell_name,
+                                              "put64m-c8-chip4"]
     cell = traffic.Cell(cell_name, man)
     assert (cell.entry["config"], cell.entry["traffic"]) == ("ec12p4-d16-chip1", cell_name)
     assert [m["name"] for m in cell.end_to_end] == ["throughput", "setup_s"]
@@ -156,6 +170,49 @@ def test_the_multipart_cell_its_metrics_and_the_facts_they_divide_by(man):
         for m in traffic.Cell(w["name"], man).per_layer:
             per = m["reader"].get("per")
             assert per is None or per in facts, (m["name"], per)
+
+
+HEAL_METRICS = {  # PR 37: what each reads, by reader kind
+    "heal_verify_ms_per_MiB": "ledger", "chunks_per_verify_batch": "counter",
+    "recon_roundtrip_ms.heal": "counter", "blocks_per_recon_batch.heal": "counter",
+    "recon_host_ms_per_MiB.heal": "ledger", "heal_drive_ms_per_MiB": "ledger",
+    "heal_dev_ms_per_GiB": "trace", "heal_roofline": "trace", "device_idle.heal": "trace",
+    "compiles_in_window.heal": "counter",
+}
+
+
+def test_the_heal_cell_its_metrics_and_the_facts_they_divide_by(man):
+    """PR 37: `heal64m-d3` on one chip; `heal_rate` lists it alone and it reports
+    `heal_rate` and `setup_s`; the heal metrics read it alone and move
+    `heal_rate`; `warmup_s` takes it; every `per` is a fact `op_facts` counts."""
+    cell_name = "heal64m-d3"
+    cell = traffic.Cell(cell_name, man)
+    assert (cell.entry["config"], cell.entry["traffic"], cell.chips) == (
+        "ec12p4-d16-chip1", cell_name, 1)
+    e2e = {m["name"]: m for m in man["end_to_end"]}
+    assert e2e["heal_rate"]["workloads"] == [cell_name]
+    assert (e2e["heal_rate"]["unit"], e2e["heal_rate"]["better"],
+            e2e["heal_rate"]["source"]) == ("MiB/s", "higher", "host_clock")
+    assert [m["name"] for m in cell.end_to_end] == ["heal_rate", "setup_s"]
+    mine = {m["name"]: m for m in cell.per_layer}
+    assert set(mine) == set(HEAL_METRICS) | {"warmup_s"}
+    facts = run.op_facts([], 0.0, 1.0, [])
+    assert {"heals_ended", "heal_MiB"} <= set(facts)
+    for name, kind in HEAL_METRICS.items():
+        m = mine[name]
+        assert m["workloads"] == [cell_name] and m["moves"] == "heal_rate", name
+        assert m["reader"]["kind"] == kind
+        per = m["reader"].get("per")
+        assert per is None or per in facts
+    assert mine["heal_roofline"]["unit"] == "%"
+    t = cell.traffic
+    assert t["mix"] == {"HEAL": 100} and t["heal"]["replace"] == [1, 2, 3]
+    assert t["heal"]["poll_s"] == 0.05 and t["keys"] == {"kind": "pool", "objects": 32}
+    assert t["ramp_s"] >= 1.5 * t["typical_op_s"] and cell.runners == 1
+    # degraded-get64m-c8's pool: the same clients, keys, bodies and sizes
+    pool = traffic.load_traffic("degraded-get64m-c8")
+    for key in ("clients", "object_bytes", "keys", "tmpfs_bytes"):
+        assert t[key] == pool[key], key
 
 
 def test_unknown_fields_are_errors(tmp_path, monkeypatch):

@@ -48,6 +48,16 @@ def _load_altered(tmp_path, monkeypatch, name, alter):
     ("mpput64m-p8m-c4", lambda t: t["multipart"].update(parts_in_flight=0), "parts_in_flight"),
     ("mpput64m-p8m-c4", lambda t: t["multipart"].update(part_bites=1), "multipart takes"),
     ("mpput64m-p8m-c4", lambda t: t.update(mix={"MPUT": 50, "GET": 50}), "pool"),
+    # PR 37: a heal is HEAL alone, with its field, over a populated pool, after a long ramp
+    ("heal64m-d3", lambda t: t.pop("heal"), "come together"),
+    ("degraded-get64m-c8", lambda t: t.update(heal={"replace": [1], "poll_s": 0.05}),
+     "come together"),
+    ("heal64m-d3", lambda t: t.update(mix={"HEAL": 50, "GET": 50}), "HEAL alone"),
+    ("heal64m-d3", lambda t: t["prepare"].append({"lose_shards": {"data": 1}}), "HEAL alone"),
+    ("heal64m-d3", lambda t: t["heal"].update(replace=[1, 1]), "heal takes"),
+    ("heal64m-d3", lambda t: t["heal"].update(replace=[0]), "heal takes"),
+    ("heal64m-d3", lambda t: t["heal"].update(poll_s=0), "heal takes"),
+    ("heal64m-d3", lambda t: t.update(ramp_s=t["typical_op_s"]), "typical ops"),
 ])
 def test_load_traffic_refuses(tmp_path, monkeypatch, name, alter, match):
     with pytest.raises(ValueError, match=match):
@@ -148,6 +158,19 @@ def test_next_op_deals_every_standing_cell_the_ops_the_parent_dealt(name, seed, 
     ops = deal(client_worker, traffic.load_traffic(name), seed, idx)
     dealt = hashlib.sha256(json.dumps(ops).encode()).hexdigest()[:16]
     assert dealt == PARENT_DEALS[name, seed, idx]
+
+
+def test_the_healer_deals_heals_of_the_bucket_and_draws_nothing_from_its_generator():
+    t = traffic.load_traffic("heal64m-d3")
+    spec_heal = {"replace_dirs": ["/nonexistent/d1"], "poll_s": 0.05, "objects": 32}
+    c = _client(client_worker, t, 7, 0)
+    c.heal_spec = spec_heal
+    state = c.rnd.getstate()
+    assert [c.next_op() for _ in range(5)] == [("HEAL", "bench")] * 5
+    assert c.rnd.getstate() == state
+    # the pool's owners: the same keys and bodies as degraded-get64m-c8's clients
+    pool = _client(client_worker, traffic.load_traffic("degraded-get64m-c8"), 7, 0)
+    assert c.keys == pool.keys and c.shas == pool.shas and c.offsets == pool.offsets
 
 
 def test_mput_alone_cycles_the_ring_and_draws_the_body_a_put_would():

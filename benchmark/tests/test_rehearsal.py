@@ -18,8 +18,8 @@ import os
 import numpy as np
 import pytest
 
+from benchmark.harness import check, server, traffic
 from benchmark.harness import run as bench_run
-from benchmark.harness import server, traffic
 
 pytestmark = pytest.mark.skipif(
     os.environ.get("JAX_PLATFORMS", "") != "cpu",
@@ -265,8 +265,8 @@ def test_lose_shards_prepare_step_removes_data_shards_and_the_run_is_correct():
     def hook(dep):
         orig = dep.lose_shards
 
-        def counting(key, data):
-            victims = orig(key, data)
+        def counting(key, data, **kw):
+            victims = orig(key, data, **kw)
             seen.setdefault(key, []).append(victims)
             return victims
 
@@ -364,3 +364,158 @@ def test_fault_reconstructed_row_altered_where_it_is_produced():
     assert rc == 0 and line["correct"] is False
     n = numbers(line)
     assert n["ops_failed"] >= 1 and n["degraded_mismatch"] >= 1
+
+
+# -- the heal cell (PR 37) ----------------------------------------------------------
+
+HEAL = "heal64m-d3"
+
+
+def _heal_run(seed, patch=None, control=None):
+    """A whole rehearsal of the heal cell; `patch()` plants a fault once set-up
+    is done (at the window's first snapshot: the install's warm-up and the
+    set-up's one-object heal would refuse it, and the run would print no
+    result) and returns what undoes it."""
+    undo = []
+
+    def hook(dep):
+        snapshot = dep.snapshot
+
+        def planting():
+            if not undo:
+                undo.append(patch())
+            return snapshot()
+
+        dep.snapshot = planting
+
+    try:
+        return bench_run.execute(args(HEAL, seed, control=control),
+                                 deployment_hook=hook if patch else None)
+    finally:
+        for u in undo:
+            u()
+
+
+def _replace(owner, name, new):
+    orig = getattr(owner, name)
+    setattr(owner, name, new)
+    return lambda: setattr(owner, name, orig)
+
+
+def test_heal_cell_is_correct_on_the_sound_program():
+    rc, line = _heal_run(31)
+    assert rc == 0 and line["correct"] is True, line
+    n = numbers(line)
+    assert set(n) == set(check.limits(True)) and all(v == 0 for v in n.values()), n
+    assert line["attempted"] >= 2 and line["failed"] == 0 and line["metrics"] == {}
+
+
+def test_heal_cell_control_one_parity_shard_fewer_is_not_correct():
+    """13+3 heals its three rows too: only the plain reference (12+4) and the
+    degraded read-back (12 survivors of 13 needed) can tell."""
+    rc, line = _heal_run(32, control="parity-1")
+    assert rc == 0 and line["correct"] is False
+    n = numbers(line)
+    assert n["reference_mismatch"] >= 1 and n["degraded_mismatch"] >= 1
+    assert n["ops_failed"] == 0 and n["healed_shard_mismatch"] == 0
+
+
+def _flip_first_byte_of_every_block(rebuilt):
+    rebuilt[:, 0, 0] ^= 0x5A
+
+
+def _zero_the_second_half_of_the_batch(rebuilt):
+    rebuilt[max(1, rebuilt.shape[0] // 2):] = 0
+
+
+@pytest.mark.parametrize("mutate", [_flip_first_byte_of_every_block,
+                                    _zero_the_second_half_of_the_batch])
+def test_heal_fault_rebuilt_rows_altered_where_the_heal_writes_them(mutate):
+    """Where the reconstruct + digests program hands a heal's batch back (heal
+    asks for digests, a GET does not): one byte of the first rebuilt row of
+    every block flipped, or the second half of the batch left out (zeros); the
+    digests left as the program made them."""
+    from minio_tpu.models import pipeline
+
+    orig = pipeline.ErasurePipeline.reconstruct
+
+    def altered(self, survivors, present, want, with_digests=True):
+        rebuilt, digests = orig(self, survivors, present, want, with_digests=with_digests)
+        if with_digests:
+            import jax.numpy as jnp
+
+            rows = np.array(rebuilt)
+            mutate(rows)
+            rebuilt = jnp.asarray(rows)  # the caller waits on it as on the program's own
+        return rebuilt, digests
+
+    rc, line = _heal_run(33, lambda: _replace(pipeline.ErasurePipeline, "reconstruct", altered))
+    assert rc == 0 and line["correct"] is False
+    n = numbers(line)
+    assert n["healed_shard_mismatch"] >= 1 and n["reference_mismatch"] >= 1
+
+
+def test_heal_fault_half_of_every_verify_batch_left_out():
+    """The second half of every device verify batch comes back as zero
+    digests: sound rows read as rotten, a heal finds fewer than K good rows
+    and fails, or rebuilds rows it need not."""
+    from minio_tpu.models import pipeline
+
+    orig = pipeline.ErasurePipeline.verify_digests
+
+    def half(self, shards):
+        digs = np.array(orig(self, shards))
+        digs[max(1, digs.shape[0] // 2):] = 0
+        return digs
+
+    rc, line = _heal_run(37, lambda: _replace(pipeline.ErasurePipeline, "verify_digests", half))
+    assert rc == 0 and line["correct"] is False
+
+
+def test_heal_fault_reports_done_and_writes_nothing():
+    """Every object reported healed (its missing drives counted), nothing written."""
+    from minio_tpu.object.erasure import ErasureObjects
+
+    orig = ErasureObjects.heal_object
+
+    def hollow(self, bucket, object_name, version_id="", dry_run=False):
+        res = orig(self, bucket, object_name, version_id, dry_run=True)
+        res.after_drive_state = list(res.before_drive_state)
+        return res
+
+    rc, line = _heal_run(34, lambda: _replace(ErasureObjects, "heal_object", hollow))
+    assert rc == 0 and line["correct"] is False
+    n = numbers(line)
+    assert n["ops_failed"] == 0  # every sequence answered as a sound one would
+    assert n["healed_shard_mismatch"] >= 1 and n["reference_mismatch"] >= 1
+    assert n["degraded_mismatch"] >= 1
+
+
+def test_heal_fault_every_reconstruct_on_the_host_codec():
+    from minio_tpu.parallel.batching import BatchingDeviceCodec
+
+    def on_host(self, rows_batch, k, m, want, with_digests=False):
+        with self._stats_lock:
+            self.host_fallback_recon_blocks += len(rows_batch)
+        return self._host.reconstruct_batch(rows_batch, k, m, want, with_digests)
+
+    rc, line = _heal_run(35, lambda: _replace(BatchingDeviceCodec, "reconstruct_batch", on_host))
+    assert rc == 0 and line["correct"] is False
+    n = numbers(line)
+    assert n["host_fallback_recon_blocks"] >= 1 and n["device_blocks_missing"] >= 1
+    assert n["healed_shard_mismatch"] == 0 and n["reference_mismatch"] == 0
+
+
+def test_heal_fault_every_verify_on_the_host_codec():
+    from minio_tpu.parallel.batching import BatchingDeviceCodec
+
+    def on_host(self, chunks):
+        with self._stats_lock:
+            self.host_fallback_digest_chunks += len(chunks)
+        return self._host.digests_batch(chunks)
+
+    rc, line = _heal_run(36, lambda: _replace(BatchingDeviceCodec, "digests_batch", on_host))
+    assert rc == 0 and line["correct"] is False
+    n = numbers(line)
+    assert n["host_fallback_digest_chunks"] >= 1
+    assert n["healed_shard_mismatch"] == 0 and n["reference_mismatch"] == 0

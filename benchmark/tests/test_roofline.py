@@ -81,3 +81,47 @@ def test_counter_reader_finds_the_s3_fronts_counters():
     assert readers.read_counter(reader, {"window": pair}) == pytest.approx(192.0)
     old = ({"codec": {}}, {"codec": {}})  # a snapshot without the group reads nothing
     assert readers.read_counter(reader, {"window": old}) is None
+
+
+@pytest.mark.parametrize("k,m,lost,s,due", [
+    # 16 rows of 87,382 B (13 survivors read once, 3 rebuilt written) + 16 digests
+    (12, 4, 3, 87382, 1398624),
+    (4, 4, 3, 262144, 8 * 262144 + 8 * 32),
+    (2, 2, 2, 524288, 4 * 524288 + 4 * 32),
+])
+def test_heal_bytes_are_the_least_a_heal_needs(k, m, lost, s, due):
+    """PR 37: survivors read once, lost rows written, a digest a row: not the
+    (K+M-lost) + K rows today's separate verify and reconstruct programs read."""
+    assert 1398624 == 16 * 87382 + 16 * 32 < 28 * 87382  # 13 + 12 + 3 rows at 12+4
+    assert roofline.heal_bytes_moved(k, m, lost, s) == due
+    t, bound = roofline.heal_least_seconds(2048, k, m, lost, s, "TPU v5 lite")
+    assert bound == "hbm" and t == pytest.approx(2048 * due / 819e9)
+
+
+def test_heal_readers_read_both_programs_of_a_made_up_slice():
+    """A traced slice of a heal: a verify module (its input [64, S]) and a
+    reconstruct + digests module ([16, 12, S] in, [16, 3, S] out), and a module
+    that is neither; trace.reduce finds the two by the shard length and the
+    heal readers divide their time by the blocks reconstructed in the slice."""
+    from benchmark.harness import trace
+
+    s = 87382
+    ops = [("verify", 0, 3000, (64, s)), ("hash", 3000, 1000, (64, 2728, 8)),
+           ("other", 5000, 500, (8, 8)),
+           ("recon", 6000, 2000, (16, 12, s, 16, s, 3)), ("digests", 8000, 1000, (48, 2728, 8))]
+    modules = [("jit_verify", 0, 4000, ()), ("jit_other", 5000, 500, ()),
+               ("jit_mtpu_reconstruct", 6000, 3000, ())]
+    tr = trace.reduce({"/device:TPU:0": {"ops": ops, "modules": modules}}, s, (0, 10000))
+    assert tr["codec_runs"] == 2 and tr["codec_s"] == pytest.approx(7000e-9)
+    tr["span_s"] = 10e-6
+    pair = ({"codec": {"blocks_reconstructed": 100}}, {"codec": {"blocks_reconstructed": 116}})
+    src = {"traced": pair, "trace": tr, "geometry": (12, 4, s), "block_bytes": 1 << 20,
+           "device_kind": "TPU v5 lite", "lost_data": 0, "replaced": 3}
+    assert readers.read_trace({"value": "heal_ms_per_GiB"}, src) == pytest.approx(
+        7000e-9 * 1e3 / (16 / 1024))
+    assert readers.read_trace({"value": "heal_roofline"}, src) == pytest.approx(
+        100 * 16 * 1398624 / 819e9 / 7000e-9)
+    assert readers.read_trace({"value": "idle_share"}, src) == pytest.approx(100 * (1 - 0.75))
+    assert readers.read_trace({"value": "recon_roofline"}, src) is None  # nothing lost in prepare
+    src["replaced"] = 0  # no drive replaced: no count of rebuilt rows, no share
+    assert readers.read_trace({"value": "heal_roofline"}, src) is None
