@@ -67,6 +67,22 @@ codec's `blocks_reconstructed`:
 Its degraded sample loses its data shards on drives other than the replaced
 ones, so that the healed rows are among the survivors the read needs.
 
+A cell whose configuration has several nodes (harness/cluster.py) is held to
+one number more, and reads its samples back across nodes:
+
+    reference_mismatch    of a sample, drawn from the seed, of the keys whose
+                          last acknowledged write, ramp to drain, was a PUT
+                          that ended inside the window, the shard files (the
+                          newest data directory on every drive of the set,
+                          whichever node holds it) that differ from what the
+                          plain reference gives for the body put; an empty
+                          sample reads 1
+
+Its read-back and degraded samples are read through the node after the one
+the key's client writes through (client i writes through node i mod N, reads
+back through node i + 1 mod N): an acknowledged write is read back from
+another node, and the degraded read is rebuilt by that node's chip.
+
 An MPUT (a whole multipart upload) is a write like a PUT: its key's record is
 the sha256 of the whole body and the ETag the parts' md5s give, both held at
 read-back; the degraded sample reads completed uploads back across their part
@@ -106,9 +122,12 @@ HEAL_LIMITS = {
 }
 
 
-def limits(heals: bool) -> dict[str, float]:
-    """The numbers a cell is held to: a heal cell's five more."""
-    return {**LIMITS, **HEAL_LIMITS} if heals else LIMITS
+CLUSTER_LIMITS = {"reference_mismatch": 0}
+
+
+def limits(heals: bool, cluster: bool = False) -> dict[str, float]:
+    """The numbers a cell is held to: a heal cell's five more, a cluster's one."""
+    return {**LIMITS, **(HEAL_LIMITS if heals else {}), **(CLUSTER_LIMITS if cluster else {})}
 
 
 def _acknowledged_writes(ops: list) -> list:
@@ -142,6 +161,17 @@ def degraded_sample(ops: list, t0: float, t1: float, seed: int, n: int) -> list[
             if op[window.KIND] in WRITES and t0 <= op[window.END] < t1]
     live.sort(key=lambda o: o[window.END])
     return _draw([op[window.KEY] for op in live], seed, n)
+
+
+def reference_sample(ops: list, t0: float, t1: float, seed: int, n: int) -> list[str]:
+    """Keys whose last acknowledged write, ramp to drain, was a PUT (one
+    stream of blocks, as the reference lays it out) that ended inside the
+    window; drawn apart from the degraded sample."""
+    last = {op[window.KEY]: op for op in _acknowledged_writes(ops)}
+    live = sorted((op for op in last.values()
+                   if op[window.KIND] == "PUT" and t0 <= op[window.END] < t1),
+                  key=lambda o: o[window.END])
+    return _draw([op[window.KEY] for op in live], seed + 3, n)
 
 
 def wrote_nothing(ops: list) -> bool:

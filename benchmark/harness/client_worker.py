@@ -15,7 +15,9 @@ Protocol: one JSON object per line on stdin, one per line on stdout.
     -> {"ready": ...}  buffer made, sha256 of every body slice known
     {"cmd": "populate"}                -> {"populated": n, "failed": n}
     {"cmd": "run", "start", "t0", "t1"} -> {"ops": [...], "cpu_s", ...}
-    {"cmd": "verify", "keys": [...]|null} -> {"verified": n, "mismatch": [...]}
+    {"cmd": "verify", "keys": [...]|null, "via": url|null}
+                                       -> {"verified": n, "mismatch": [...]}
+                                          read through `via` (another node) where given
     {"cmd": "live"}                    -> {"live": n}   keys with a body on record
     {"cmd": "warm_heal"}               -> {"ok": bool, "detail": str}
     {"cmd": "slices", "keys": [...]}   -> {"offsets": {key: offset of its body slice}}
@@ -525,15 +527,26 @@ class Client:
             out["uploads_aborted"] = self.abort_open_uploads()
         return out
 
-    def verify(self, keys: list[str] | None) -> dict:
+    def verify(self, keys: list[str] | None, via: str | None = None) -> dict:
         """GET the given keys (all with a record, when None) and hold each to
-        the record: the acknowledged bytes, or 404 after a DELETE."""
+        the record: the acknowledged bytes, or 404 after a DELETE. `via`: the
+        endpoint to read through, where not the one the client writes to."""
         mismatch = []
         todo = [k for k in (self.keys if keys is None else keys) if k in self.state]
-        for key in todo:
-            ok, _, detail = self.do("GET", key)
-            if not ok:
-                mismatch.append(detail)
+        own = self.s3
+        if via:
+            spec = self.spec
+            self.s3 = S3Conn(via, spec["access"], spec["secret"], spec["region"],
+                             spec["timeout_s"])
+        try:
+            for key in todo:
+                ok, _, detail = self.do("GET", key)
+                if not ok:
+                    mismatch.append(detail)
+        finally:
+            if via:
+                self.s3.close()
+                self.s3 = own
         return {"verified": len(todo), "mismatch": mismatch[:5], "mismatches": len(mismatch),
                 "live": sum(1 for k in todo if self.state.get(k) is not None)}
 
@@ -556,7 +569,7 @@ def main(argv: list[str]) -> int:
         elif what == "run":
             say(client.run(cmd["start"], cmd["t0"], cmd["t1"]))
         elif what == "verify":
-            say(client.verify(cmd.get("keys")))
+            say(client.verify(cmd.get("keys"), cmd.get("via")))
         elif what == "live":
             say({"live": sum(1 for v in client.state.values() if v is not None)})
         elif what == "warm_heal":

@@ -18,9 +18,15 @@ beyond its own counters and ledger:
     src["replaced"] = drives a heal traffic file replaces before every op (rows
                       rebuilt a block), or 0
 
+In a cluster (harness/cluster.py) a snapshot is the nodes' summed row by row,
+and the trace is the chip nodes' merged (busy time the mean of the chips, codec
+device time summed): every reader below reads a cluster as one node.
+
 kinds:
     ledger   rows (layer/stage) summed, field wall_s|cpu_s|count, times scale,
-             over a fact (per) or 1
+             over a fact (per) or 1; `prefixes` adds every row of the window
+             that starts with one of them (rows named by a peer, such as
+             `rpc-peer/{path}@{peer}`); no row found reads nothing
     counter  numerator / denominator, each a list of codec counters, S3-front
              counters or snapshot counters summed and differenced over the
              window; a missing denominator is 1
@@ -42,7 +48,7 @@ from . import roofline
 
 GIB = 1 << 30
 KINDS = {
-    "ledger": {"kind", "rows", "field", "scale", "per"},
+    "ledger": {"kind", "rows", "prefixes", "field", "scale", "per"},
     "counter": {"kind", "numerator", "denominator", "scale"},
     "trace": {"kind", "value"},
     "process": {"kind", "value"},
@@ -56,6 +62,8 @@ def validate(name: str, reader: dict) -> None:
     extra = set(reader) - KINDS[kind]
     if extra:
         raise ValueError(f"metric {name}: unknown reader fields {sorted(extra)}")
+    if kind == "ledger" and not (reader.get("rows") or reader.get("prefixes")):
+        raise ValueError(f"metric {name}: a ledger reader names rows or prefixes")
 
 
 def _delta(pair: tuple[dict, dict], group: str, name: str, field: str | None = None) -> float | None:
@@ -79,9 +87,20 @@ def _counter(pair: tuple[dict, dict], name: str) -> float | None:
     return None
 
 
+def ledger_rows(reader: dict, snap: dict) -> list[str]:
+    """The rows a ledger reader names, and every row of `snap` that starts with
+    one of its prefixes."""
+    rows = list(reader.get("rows", []))
+    prefixes = tuple(reader.get("prefixes", ()))
+    if prefixes:
+        rows += sorted(r for r in snap["ledger"] if r.startswith(prefixes) and r not in rows)
+    return rows
+
+
 def read_ledger(reader: dict, src: dict) -> float | None:
     field = reader.get("field", "wall_s")
-    parts = [_delta(src["window"], "ledger", row, field) for row in reader["rows"]]
+    parts = [_delta(src["window"], "ledger", row, field)
+             for row in ledger_rows(reader, src["window"][1])]
     if all(p is None for p in parts):
         return None
     total = sum(p for p in parts if p is not None)

@@ -2,10 +2,12 @@
 
     python3 benchmark/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
-This process holds the chip and serves the store; the clients are processes of
-their own (client_worker.py). The last line of standard output is the result;
-the lines before it say what a refused run needs to be read: the server's
-install report, the ramp's lateness, op counts by type, bytes on the drives.
+This process holds the chip and serves the store (harness/server.py), or, for a
+configuration of several nodes, starts a process per node and holds no chip
+(harness/cluster.py); the clients are processes of their own (client_worker.py).
+The last line of standard output is the result; the lines before it say what a
+refused run needs to be read: the server's install report, the ramp's lateness,
+op counts by type, bytes on the drives.
 """
 
 from __future__ import annotations
@@ -22,8 +24,9 @@ import shutil  # noqa: E402
 import subprocess  # noqa: E402
 import sys  # noqa: E402
 
-from . import check, readers, reference, trace, window  # noqa: E402
+from . import check, readers, reference, window  # noqa: E402
 from .client_worker import S3Conn, body_buffer, client_keys  # noqa: E402 - imports no jax
+from .cluster import ClusterDeployment  # noqa: E402 - imports no jax
 from .server import (  # noqa: E402
     ACCESS, BUCKET, REGION, SECRET, SHM, Deployment, SetupError, hash_order,
 )
@@ -59,8 +62,8 @@ class Clients:
     def __init__(self, cell: Cell, seed: int, dep: Deployment):
         self.procs = []
         for i in range(cell.clients):
-            spec = cell.client_spec(i, seed, dep.endpoint, BUCKET, ACCESS, SECRET, REGION,
-                                    dep.drive_dirs)
+            spec = cell.client_spec(i, seed, dep.client_endpoint(i), BUCKET, ACCESS, SECRET,
+                                    REGION, dep.drive_dirs)
             self.procs.append(subprocess.Popen(
                 [sys.executable, WORKER, json.dumps(spec)],
                 stdin=subprocess.PIPE, stdout=subprocess.PIPE, text=True, bufsize=1,
@@ -197,21 +200,21 @@ def run_window(cell: Cell, dep: Deployment, clients: Clients, seed: int, seconds
         say(f"set-up done after {setup_s:.2f}s; ramp {t['ramp_s']}s, window {seconds}s")
 
         sleep_until(t0)
-        snap_a, cpu_a = dep.snapshot(), time.process_time()
+        snap_a, cpu_a = dep.snapshot(), dep.cpu_seconds()
         traced_pair, trace_wall = None, 0.0
         if traced:
             tr_s = min(float(t.get("trace_seconds", 3.0)), seconds / 2)
             sleep_until(t0 + (seconds - tr_s) / 2)
             shutil.rmtree(TRACE_DIR, ignore_errors=True)
-            trace.start(TRACE_DIR)
+            dep.trace_start(TRACE_DIR)
             ta = dep.snapshot()
             sleep_until(ta["t"] + tr_s)
             tb = dep.snapshot()
-            trace.stop()
+            dep.trace_stop()
             traced_pair, trace_wall = (ta, tb), tb["t"] - ta["t"]
             say(f"traced {trace_wall:.2f}s; stop_trace took {time.monotonic() - tb['t']:.2f}s")
         sleep_until(t1)
-        snap_b, cpu_b = dep.snapshot(), time.process_time()
+        snap_b, cpu_b = dep.snapshot(), dep.cpu_seconds()
 
         results = clients.gather(range(cell.runners))
         if reaper is not None:
@@ -234,7 +237,7 @@ def run_window(cell: Cell, dep: Deployment, clients: Clients, seed: int, seconds
         for r in results:
             for e in r["errors"]:
                 say(f"client error: {e}")
-        say(f"server process used {(cpu_b - cpu_a) / (b - a):.2f} cores over the window "
+        say(f"server process(es) used {(cpu_b - cpu_a) / (b - a):.2f} cores over the window "
             f"({len(os.sched_getaffinity(0))} cpus to run on)")
 
         numbers, stored, live = run_check(cell, dep, clients, ops, (a, b), seed, before, after,
@@ -260,13 +263,9 @@ def run_window(cell: Cell, dep: Deployment, clients: Clients, seed: int, seconds
            "device_kind": device["kind"], "lost_data": cell.lost_data,
            "replaced": len(cell.replaced)}
     if traced:
-        xplane = trace.find_xplane(TRACE_DIR)
-        if describe_to:
-            with open(describe_to, "w") as f:
-                f.write("\n".join(trace.describe(xplane)) + "\n")
-        src["trace"] = reduced = trace.reduce(
-            trace.load(xplane), cell.geometry[2], wall_s=trace_wall,
-            idle_chips=device["count"] if device["platform"] != "cpu" else 0)
+        src["trace"] = reduced = dep.trace_reduce(
+            TRACE_DIR, cell.geometry[2], trace_wall,
+            device["count"] if device["platform"] != "cpu" else 0, describe_to)
         if reduced:
             reduced["span_s"] = trace_wall  # the host's clock frames the traced window
             device["busy_s"], device["window_s"] = reduced["busy_s"], trace_wall
@@ -366,9 +365,13 @@ def run_check(cell: Cell, dep: Deployment, clients: Clients, ops: list,
     stored = dep.stored_bytes()
     live = sum(v["live"] for v in clients.ask_all({"cmd": "live"}))
     back = verify_keys(clients, check.readback_sample(
-        ops, seed, int(chk.get("readback_sample", 12))))
+        ops, seed, int(chk.get("readback_sample", 12))), dep.readback_endpoint)
     numbers["readback_mismatch"] = sum(v["mismatches"] for v in back)
     read_back = sum(v["verified"] for v in back)
+    if cell.nodes > 1:
+        numbers["reference_mismatch"] = check_shard_files(
+            cell, dep, clients, check.reference_sample(
+                ops, a, b, seed, int(chk.get("reference_sample", 2))), seed)
     n_degraded = int(chk.get("degraded_sample", 4))
     sample, drawn_from = check.degraded_sample(ops, a, b, seed, n_degraded), "PUT in the window"
     if check.wrote_nothing(ops) and cell.populates:
@@ -376,7 +379,7 @@ def run_check(cell: Cell, dep: Deployment, clients: Clients, ops: list,
     lost_all = int(cell.config["guarantees"]["drives_lost_tolerated"])
     for key in sample:
         dep.lose_shards(key, lost_all, spare=tuple(cell.replaced))
-    deg = verify_keys(clients, sample)
+    deg = verify_keys(clients, sample, dep.rebuild_endpoint)
     numbers["degraded_mismatch"] = sum(v["mismatches"] for v in deg)
     numbers["degraded_short"] = max(1, len(sample)) - sum(v["verified"] for v in deg)
     block = cell.config["block_bytes"]
@@ -422,18 +425,48 @@ def run_check(cell: Cell, dep: Deployment, clients: Clients, ops: list,
     return numbers, stored, live
 
 
-def verify_keys(clients: Clients, keys: list[str]) -> list[dict]:
-    """Have each key's owner read it back and hold it to its record."""
+def verify_keys(clients: Clients, keys: list[str], via) -> list[dict]:
+    """Have each key's owner read it back and hold it to its record, through
+    the node `via(owner)` names (None: the owner's own connection; in a
+    cluster, another node than the one its PUTs went to for a read-back, a
+    chip node for a degraded read: read-after-write across nodes)."""
     by_owner: dict[int, list[str]] = {}
     for key in keys:
         by_owner.setdefault(owner_of(key), []).append(key)
     for i, mine in by_owner.items():
-        clients.send(i, {"cmd": "verify", "keys": mine})
+        clients.send(i, {"cmd": "verify", "keys": mine, "via": via(i)})
     return clients.gather(list(by_owner))
 
 
+def check_shard_files(cell: Cell, dep: Deployment, clients: Clients, sample: list[str],
+                      seed: int) -> int:
+    """Every shard file of each sampled key (its newest data directory on each
+    of the set's drives, whichever node holds it) against what the plain
+    reference (harness/reference.py) makes of the body its owner last put:
+    the files that differ or are missing. An empty sample reads 1."""
+    t_ref = time.monotonic()
+    k, m, size = cell.config["data"], cell.config["parity"], int(cell.traffic["object_bytes"])
+    wrong = 0 if sample else 1
+    for key in sample:
+        owner = owner_of(key)
+        offset = clients.ask([owner], {"cmd": "slices", "keys": [key]})[0]["offsets"][key]
+        buf, _, _ = body_buffer(seed, owner, size)
+        rows = [r - 1 for r in hash_order(f"{BUCKET}/{key}", dep.drives)]
+        due = reference.shard_files(buf[offset:offset + size], k, m,
+                                    int(cell.config["block_bytes"]), rows)
+        for d, (row, want) in enumerate(zip(rows, due)):
+            if file_bytes(dep.newest_shard_file(d, key)) != want:
+                wrong += 1
+                say(f"check: {key}: the shard file of row {row} on drive "
+                    f"{os.path.basename(dep.drive_dirs[d])} is not the plain reference's")
+    say(f"check of the shard files took {time.monotonic() - t_ref:.2f}s: {wrong} of "
+        f"{len(sample) * dep.drives} ({', '.join(sample)}) differ from the plain reference")
+    return wrong
+
+
 def result_line(cell: Cell, out: dict, traced: bool, rehearse: bool, control: str | None) -> dict:
-    correct, compared = check.decide(out["numbers"], check.limits(bool(cell.replaced)))
+    correct, compared = check.decide(out["numbers"], check.limits(bool(cell.replaced),
+                                                                  cell.nodes > 1))
     metrics: dict[str, dict] = {}
     if rehearse:
         pass  # a CPU run prints no number under the name of a device metric
@@ -473,19 +506,17 @@ def execute(args, deployment_hook=None) -> tuple[int, dict | None]:
     t_process = T_IMPORT - process_age()
     cell = Cell(args.workload, rehearse=args.rehearse)
     parity = cell.config["parity"] - 1 if args.control == "parity-1" else None
-    dep = Deployment(cell.config, rehearse=args.rehearse, parity=parity)
+    dep = (ClusterDeployment if cell.nodes > 1 else Deployment)(
+        cell.config, rehearse=args.rehearse, parity=parity)
     clients = Clients(cell, args.seed, dep)
     line = None
     try:
         dep.start(cell.footprint_bytes(), cell.traffic.get("mallopt"))
-        import jax
-
-        if not args.rehearse and jax.device_count() != cell.chips:
-            raise SetupError(f"the cell asks for {cell.chips} chip(s), jax has {jax.device_count()}")
+        dep.check_chips(cell.chips)
         inst = dep.install
         say("install: " + json.dumps({k: inst.get(k) for k in (
             "state", "platform", "device_kind", "device_count", "geometry", "mesh", "warm",
-            "compile_cache", "setup_seconds")}))
+            "compile_cache", "setup_seconds", "nodes")}))
         say("kernels: " + json.dumps(inst.get("kernels")))
         make_bucket(dep)
         if deployment_hook is not None:

@@ -1,4 +1,5 @@
-"""The system under test: one node, in this process, which holds the chip.
+"""The system under test: one node, in this process, which holds the chip (a
+configuration of several nodes runs as harness/cluster.py, a process each).
 
 The node is built and served as ``minio_tpu.cli.serve`` does it (self-test,
 ``Node(...)``, ``make_app`` on a loopback port through ``_run_app_until``, then
@@ -21,6 +22,8 @@ import socket
 import threading
 import time
 import zlib
+
+from . import trace
 
 ACCESS, SECRET, REGION = "benchadmin", "bench-secret-key-0001", "us-east-1"
 BUCKET = "bench"
@@ -115,6 +118,21 @@ class Deployment:
     def start(self, need_bytes: int, mallopt: dict[str, int] | None = None) -> None:
         if mallopt:
             self.pin_allocator(mallopt)
+        self.make_drives(need_bytes)
+        for name in PINNED_ENV:
+            os.environ.pop(name, None)
+        os.environ.update(self.config.get("env", {}))
+        if self.rehearse:
+            os.environ["MINIO_TPU_CODEC"] = "xla-cpu"
+            os.environ["JAX_PLATFORMS"] = "cpu"
+        self.listen(self.drive_dirs, "",
+                    self.parity if self.parity != self.config["parity"] else None)
+        self.build()
+        self.hold_install(self.install)
+
+    def make_drives(self, need_bytes: int) -> None:
+        """The drive directories under /dev/shm, stale ones of dead runs removed
+        first; too little room is an error."""
         if not os.path.isdir(SHM):
             raise SetupError(f"{SHM} is not there: the drives are memory-backed or nothing")
         clean_stale()
@@ -123,13 +141,23 @@ class Deployment:
             raise SetupError(f"{SHM} has {free} bytes free, the cell needs {need_bytes}")
         for d in self.drive_dirs:
             os.makedirs(d)
-        for name in PINNED_ENV:
-            os.environ.pop(name, None)
-        os.environ.update(self.config.get("env", {}))
-        if self.rehearse:
-            os.environ["MINIO_TPU_CODEC"] = "xla-cpu"
-            os.environ["JAX_PLATFORMS"] = "cpu"
 
+    def hold_install(self, install: dict, who: str = "") -> None:
+        """The device codec serves, on the platform the run wants, warmed for
+        the set's geometry."""
+        if install.get("state") != "serving":
+            raise SetupError(f"{who}the device codec does not serve: {install}")
+        want = "cpu" if self.rehearse else "tpu"
+        if install.get("platform") != want:
+            raise SetupError(f"{who}the codec serves on {install.get('platform')!r}, not {want!r}")
+        k, m = self.drives - self.parity, self.parity
+        if install.get("geometry") != [k, m]:
+            raise SetupError(f"{who}warmed geometry {install.get('geometry')}, cell wants {[k, m]}")
+
+    def listen(self, endpoints: list[str], url: str, parity: int | None) -> None:
+        """``Node(endpoints, url=...)`` and ``make_app()`` served on this
+        deployment's port: S3, storage, lock and peer REST, before the build
+        (peers format through storage REST, as ``minio_tpu.cli.serve`` does)."""
         import jax
 
         # Every backend compile from here on is stamped: the window must hold none.
@@ -137,8 +165,6 @@ class Deployment:
 
         from minio_tpu.cli import _run_app_until, boot_self_test
         from minio_tpu.dist.node import Node
-        from minio_tpu.runtime import install_status
-
         from minio_tpu.ops import native
 
         # The host kernels (-march=native, git-ignored) are built where they
@@ -147,29 +173,25 @@ class Deployment:
         if not native.available():
             raise SetupError("the native host kernels did not build or load (see the log)")
         boot_self_test()
-        self.node = Node(
-            self.drive_dirs, root_user=ACCESS, root_password=SECRET,
-            parity=self.parity if self.parity != self.config["parity"] else None,
-            region=REGION,
-        )
+        self.node = Node(endpoints, url=url, root_user=ACCESS, root_password=SECRET,
+                         parity=parity, region=REGION)
         app = self.node.make_app()
         self._http, errors = _run_app_until(app, "127.0.0.1", self.port, self._stop)
         if errors:
             raise SetupError(f"HTTP server failed to start: {errors[0]}")
+
+    def build(self) -> None:
+        """``node.build()``, which blocks until the device codec is warmed and
+        serving, then the scanner; ``install`` is what the install reported."""
+        from minio_tpu.runtime import install_status
+
         try:
-            self.node.build()  # blocks until the device codec is warmed and serving
+            self.node.build()
         except Exception as e:  # noqa: BLE001 - boundary: any install failure ends the run
             raise SetupError(f"node bootstrap failed: {type(e).__name__}: {e}") from e
-        self.install = install_status()
-        if self.install.get("state") != "serving":
-            raise SetupError(f"the device codec does not serve: {self.install}")
-        want = "cpu" if self.rehearse else "tpu"
-        if self.install.get("platform") != want:
-            raise SetupError(f"the codec serves on {self.install.get('platform')!r}, not {want!r}")
-        k, m = self.drives - self.parity, self.parity
-        if self.install.get("geometry") != [k, m]:
-            raise SetupError(f"warmed geometry {self.install.get('geometry')}, cell wants {[k, m]}")
-        self.node.scanner.start()
+        self.install = dict(install_status())
+        if self.install.get("state") == "serving":
+            self.node.scanner.start()
 
     def _on_event(self, event: str, duration: float, **_kw) -> None:
         if "backend_compile" in event:
@@ -197,7 +219,41 @@ class Deployment:
             if mallopt(MALLOPT_PARAMS[name], value) != 1:
                 raise SetupError(f"mallopt({name}, {value}) was refused")
 
+    def check_chips(self, chips: int) -> None:
+        import jax
+
+        if not self.rehearse and jax.device_count() != chips:
+            raise SetupError(f"the cell asks for {chips} chip(s), jax has {jax.device_count()}")
+
+    # -- clients ------------------------------------------------------------------
+
+    def client_endpoint(self, client: int) -> str:
+        return self.endpoint
+
+    def readback_endpoint(self, client: int) -> str | None:
+        """Where the check reads client `client`'s keys back: its own connection."""
+        return None
+
+    def rebuild_endpoint(self, client: int) -> str | None:
+        """Where the check reads client `client`'s degraded keys back, rebuilt on
+        the chip: its own connection."""
+        return None
+
     # -- sources the per-layer readers take ----------------------------------------
+
+    def cpu_seconds(self) -> float:
+        """cpu time of the server so far: this process's."""
+        return time.process_time()
+
+    def trace_start(self, log_dir: str) -> None:
+        trace.start(log_dir)
+
+    def trace_stop(self) -> None:
+        trace.stop()
+
+    def trace_reduce(self, log_dir: str, shard_len: int, wall_s: float, idle_chips: int,
+                     describe_to: str | None) -> dict:
+        return trace.reduce_dir(log_dir, shard_len, wall_s, idle_chips, describe_to)
 
     def snapshot(self) -> dict:
         """Ledger, codec counters, the S3 front's counters, compile-cache
@@ -271,6 +327,13 @@ class Deployment:
         found = glob.glob(os.path.join(self.drive_dirs[drive], BUCKET, key, "*", "part.1"))
         return found[0] if len(found) == 1 else None
 
+    def newest_shard_file(self, drive: int, key: str) -> str | None:
+        """The shard file of the object's newest data directory on drive
+        `drive` (0-based), by the time it was written, or None: what its last
+        PUT left, where the sweep keeps the one before beside it."""
+        found = glob.glob(os.path.join(self.drive_dirs[drive], BUCKET, key, "*", "part.1"))
+        return max(found, key=os.path.getmtime) if found else None
+
     def stored_bytes(self) -> int:
         return sum(dir_bytes(os.path.join(d, BUCKET)) for d in self.drive_dirs)
 
@@ -297,4 +360,5 @@ class Deployment:
         self._stop.set()
         if self._http is not None:
             self._http.join(5)
-        shutil.rmtree(self.root, ignore_errors=True)
+        if self.root:
+            shutil.rmtree(self.root, ignore_errors=True)

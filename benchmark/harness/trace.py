@@ -97,6 +97,17 @@ def describe(xplane_path: str, limit: int = 6) -> list[str]:
     return text
 
 
+def reduce_dir(log_dir: str, shard_len: int, wall_s: float, idle_chips: int,
+               describe_to: str | None = None) -> dict:
+    """``reduce`` of the trace this process wrote under `log_dir`; with
+    `describe_to`, ``describe``'s lines written there too."""
+    xplane = find_xplane(log_dir)
+    if describe_to:
+        with open(describe_to, "w") as f:
+            f.write("\n".join(describe(xplane)) + "\n")
+    return reduce(load(xplane), shard_len, wall_s=wall_s, idle_chips=idle_chips)
+
+
 # -- arithmetic on event tuples ------------------------------------------------
 
 

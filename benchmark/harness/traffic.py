@@ -29,7 +29,7 @@ MANIFEST = os.path.join(ROOT, "BENCHMARK.json")
 CONFIG_FIELDS = {
     "name", "source", "deployment", "drives", "parity", "data", "block_bytes",
     "chips", "drive_medium", "drive_medium_why", "env", "guarantees", "reduced",
-    "assumed", "device_state",
+    "assumed", "device_state", "nodes",
 }
 TRAFFIC_FIELDS = {
     "name", "source", "reduced", "assumed", "why", "loop", "clients", "object_bytes",
@@ -74,6 +74,12 @@ def load_config(name: str) -> dict:
         raise ValueError(f"configuration file {name}.json names itself {cfg['name']!r}")
     if cfg["data"] + cfg["parity"] != cfg["drives"]:
         raise ValueError(f"configuration {name}: data + parity != drives")
+    nodes = cfg.get("nodes", 1)
+    if (not isinstance(nodes, int) or nodes < 1 or cfg["drives"] % nodes
+            or (nodes > 1 and cfg["chips"] not in (1, nodes))):
+        raise ValueError(f"configuration {name}: nodes must divide drives, and a cluster "
+                         "(nodes > 1) holds one chip, on its first node, or one a node "
+                         "(harness/cluster.py)")
     if cfg["drive_medium"] != "tmpfs":
         raise ValueError(f"configuration {name}: drive_medium {cfg['drive_medium']!r}: only "
                          "tmpfs has a harness; a real-disk medium is a later benchmark PR")
@@ -206,6 +212,12 @@ class Cell:
             t["keys"] = {"kind": "pool", "objects": 3 * t["clients"]}
         t["ramp_s"], t["typical_op_s"] = 1.0, 0.5
         t["tmpfs_bytes"] = int(t["tmpfs_bytes"]) // 100
+
+    @property
+    def nodes(self) -> int:
+        """Node processes of the deployment: 1 runs in the run's process
+        (harness/server.py), more as a cluster (harness/cluster.py)."""
+        return int(self.config.get("nodes", 1))
 
     @property
     def clients(self) -> int:

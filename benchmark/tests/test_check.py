@@ -141,3 +141,37 @@ def test_lose_shards_passes_over_the_spare_drives(tmp_path):
     assert not set(victims) & set(dep.drive_dirs[i] + "/bench/" + key for i in range(3))
     assert all(os.path.isdir(dep.drive_dirs[i] + "/bench/" + key) for i in range(3))
     assert dep.lose_shards(key, 4, spare=(0, 1, 2)) == victims  # nothing more the second time
+
+
+def test_a_cluster_is_held_to_its_shard_files_and_samples_them_from_the_window():
+    """A cell of several nodes is held to `reference_mismatch` besides
+    the five; its sample is of keys whose last acknowledged write was a PUT
+    that ended inside the window (an MPUT's parts lay out otherwise)."""
+    held = check.limits(False, cluster=True)
+    assert set(held) - set(check.LIMITS) == {"reference_mismatch"}
+    assert check.decide(dict.fromkeys(held, 0), held)[0] is True
+    assert check.decide({**dict.fromkeys(check.LIMITS, 0)}, held)[0] is False  # not read: fails
+    ops = [op("PUT", "c000/k0000", 1, 2, 64 * MIB), op("PUT", "c001/k0000", 2, 3, 64 * MIB),
+           op("MPUT", "c002/k0000", 2, 4, 64 * MIB), op("PUT", "c003/k0000", 9, 12, 64 * MIB),
+           op("PUT", "c004/k0000", 3, 5, 64 * MIB, ok=False)]
+    sample = check.reference_sample(ops, 0.0, 10.0, 7, 8)
+    assert sample[0] == "c001/k0000"  # the newest PUT that ended inside
+    assert sorted(sample) == ["c000/k0000", "c001/k0000"]
+    assert check.reference_sample(ops, 0.0, 10.0, 7, 1) == ["c001/k0000"]
+    assert check.reference_sample([], 0.0, 10.0, 7, 2) == []
+
+
+def test_the_newest_shard_file_is_the_last_put_s_where_the_sweep_keeps_two(tmp_path):
+    dep = server.Deployment.__new__(server.Deployment)
+    dep.drive_dirs = [str(tmp_path / "n1d1")]
+    key = "c000/k0001"
+    assert dep.newest_shard_file(0, key) is None
+    paths = []
+    for i, name in enumerate(("b-older", "a-newer")):
+        d = tmp_path / "n1d1" / "bench" / key / name
+        os.makedirs(d)
+        (d / "part.1").write_bytes(b"x")
+        os.utime(d / "part.1", (1000 + i, 1000 + i))
+        paths.append(str(d / "part.1"))
+    assert dep.newest_shard_file(0, key) == paths[1]
+    assert dep.shard_file(0, key) is None  # the heal's helper wants exactly one

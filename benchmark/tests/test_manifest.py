@@ -127,9 +127,10 @@ def test_metric_files_match_manifest_entries(man):
     assert all("\n" not in layer and len(layer) <= 200 for layer in layers)
 
 
-CELLS_AND_CHIPS = {  # the manifest as it stands (PR 37): the cells in order, each one's chips
+CELLS_AND_CHIPS = {  # the manifest as it stands: the cells in order, each one's chips
     "put64m-c8": 1, "put64m-c8-ec4p4": 1, "mixed10m-c20": 1, "degraded-get64m-c8": 1,
     "put64k-c32-ec2p2": 1, "mpput64m-p8m-c4": 1, "put64m-c8-chip4": 4, "heal64m-d3": 1,
+    "put64m-c8-n4": 1,
 }
 
 
@@ -141,8 +142,8 @@ def test_the_cells_and_the_chips_each_asks_for(man):
 
 
 def test_the_multipart_cell_its_metrics_and_the_facts_they_divide_by(man):
-    """PR 34: the multipart cell on one chip; `throughput` in five cells (PR 35
-    added the four-chip PUT); the PUT-path metrics the multipart path feeds take
+    """The multipart cell on one chip; `throughput` in six cells (with the
+    four-node cluster and the four-chip PUT); the PUT-path metrics the multipart path feeds take
     the cell, five new ones read it alone; every ledger reader's `per` is a fact
     the harness counts."""
     cell_name = "mpput64m-p8m-c4"
@@ -150,7 +151,7 @@ def test_the_multipart_cell_its_metrics_and_the_facts_they_divide_by(man):
     e2e = {m["name"]: m for m in man["end_to_end"]}
     assert e2e["throughput"]["workloads"] == ["put64m-c8", "put64m-c8-ec4p4",
                                               "degraded-get64m-c8", cell_name,
-                                              "put64m-c8-chip4"]
+                                              "put64m-c8-n4", "put64m-c8-chip4"]
     cell = traffic.Cell(cell_name, man)
     assert (cell.entry["config"], cell.entry["traffic"]) == ("ec12p4-d16-chip1", cell_name)
     assert [m["name"] for m in cell.end_to_end] == ["throughput", "setup_s"]
@@ -213,6 +214,69 @@ def test_the_heal_cell_its_metrics_and_the_facts_they_divide_by(man):
     pool = traffic.load_traffic("degraded-get64m-c8")
     for key in ("clients", "object_bytes", "keys", "tmpfs_bytes"):
         assert t[key] == pool[key], key
+
+
+CLUSTER_METRICS = {  # what each reads, by reader kind
+    "device_idle.n4": "trace", "codec_dev_ms_per_GiB.n4": "trace", "codec_roofline.n4": "trace",
+    "blocks_per_batch.n4": "counter", "batcher_idle.n4": "counter",
+    "codec_host_ms_per_MiB.n4": "ledger", "put_host_ms_per_MiB.n4": "ledger",
+    "rpc_ms_per_put.n4": "ledger", "rpc_calls_per_put.n4": "ledger",
+    "lock_rpc_ms_per_put.n4": "ledger",
+    "compiles_in_window.n4": "counter", "warmup_s.n4": "process",
+}
+
+
+def test_the_cluster_cell_its_configuration_and_metrics(man):
+    """`put64m-c8-n4`, four nodes of four drives, the first of them on the
+    host's one chip, under `put64m-c8`'s traffic file unchanged; `throughput`
+    and `setup_s`; the
+    `.n4` metrics read it alone; the internode ones read the `rpc-peer` rows
+    by prefix, the lock client's a subset of them."""
+    cell_name = "put64m-c8-n4"
+    cell = traffic.Cell(cell_name, man)
+    assert (cell.entry["config"], cell.entry["traffic"], cell.chips, cell.nodes) == (
+        "ec12p4-n4d4-chip1", "put64m-c8", 1, 4)
+    cfg = cell.config
+    assert (cfg["drives"], cfg["data"], cfg["parity"], cfg["block_bytes"], cfg["chips"]) == (
+        16, 12, 4, 1 << 20, 1)
+    assert cfg["env"] == {"MINIO_TPU_CODEC": "device"} and "MTPU_MESH_SHAPE" in cfg["assumed"]
+    assert {"node_lost_tolerated", "write_quorum", "namespace_lock"} <= set(cfg["guarantees"])
+    assert set(cfg["reduced"]) == {"hosts", "network", "drive_medium", "concurrency", "chips"}
+    entry = [c for c in man["configs"] if c["name"] == cfg["name"]][0]
+    assert set(entry["reduced"]) == set(cfg["reduced"])
+    assert cell.traffic == traffic.load_traffic("put64m-c8")
+    assert [m["name"] for m in cell.end_to_end] == ["throughput", "setup_s"]
+    mine = {m["name"]: m for m in cell.per_layer}
+    assert set(mine) == set(CLUSTER_METRICS)
+    facts = run.op_facts([], 0.0, 1.0, [])
+    for name, kind in CLUSTER_METRICS.items():
+        m = mine[name]
+        assert m["workloads"] == [cell_name] and m["reader"]["kind"] == kind, name
+        assert m["moves"] == ("setup_s" if name == "warmup_s.n4" else "throughput")
+        per = m["reader"].get("per")
+        assert per is None or per in facts
+    rpc = mine["rpc_ms_per_put.n4"]["reader"]["prefixes"]
+    assert rpc == ["rpc-peer/"]
+    assert all(p.startswith(rpc[0]) for p in mine["lock_rpc_ms_per_put.n4"]["reader"]["prefixes"])
+    assert mine["codec_roofline.n4"]["unit"] == "%"
+    assert mine["codec_roofline.n4"]["reader"]["value"] == "codec_roofline"
+
+
+@pytest.mark.parametrize("change, loads", [
+    ({}, True), ({"chips": 4}, True),  # one chip on the first node, or one a node
+    ({"nodes": 3}, False), ({"chips": 2}, False), ({"nodes": 0}, False)])
+def test_a_cluster_configuration_holds_one_chip_or_one_a_node(tmp_path, monkeypatch, change,
+                                                              loads):
+    cfg = traffic.load_config("ec12p4-n4d4-chip1")
+    monkeypatch.setattr(traffic, "BENCH_DIR", str(tmp_path))
+    os.makedirs(tmp_path / "configs")
+    with open(tmp_path / "configs" / "ec12p4-n4d4-chip1.json", "w") as f:
+        json.dump({**cfg, **change}, f)
+    if loads:
+        assert traffic.load_config("ec12p4-n4d4-chip1")["chips"] == change.get("chips", 1)
+        return
+    with pytest.raises(ValueError, match="nodes must divide drives"):
+        traffic.load_config("ec12p4-n4d4-chip1")
 
 
 def test_unknown_fields_are_errors(tmp_path, monkeypatch):

@@ -8,8 +8,10 @@ Run by hand (about ten minutes; each case boots a server in this process):
 
 These skip the harness's look for a chip (``--rehearse``) and drive the rest of
 a run: clients as processes, ramp, window, drain, read-back, degraded read-back.
-Of the faults the builder's contract lists, "the exchange between chips left
-out" has no cell here: every cell takes one chip.
+The exchange between nodes is the cluster cell's: four node processes, the
+first serving the device programs on jax's CPU backend and every client, the
+others the host codec, 12 of 16 shards over storage REST; its fault is a node
+whose shard writes to the others answer OK and write nothing.
 """
 
 import argparse
@@ -20,6 +22,7 @@ import pytest
 
 from benchmark.harness import check, server, traffic
 from benchmark.harness import run as bench_run
+from benchmark.tests import faults
 
 pytestmark = pytest.mark.skipif(
     os.environ.get("JAX_PLATFORMS", "") != "cpu",
@@ -59,23 +62,6 @@ def test_control_one_parity_shard_fewer_is_not_correct():
     assert n["ops_failed"] == 0 and n["readback_mismatch"] == 0
 
 
-def _break_encode(mutate):
-    """Plant a fault where the device's answer is produced: the pipeline's
-    encode, which returns (parity [B, M, S], digests [B, K+M, 32]) since PR 30."""
-    from minio_tpu.models import pipeline
-
-    orig = pipeline.ErasurePipeline.encode
-
-    def broken(self, data_shards):
-        parity, digests = orig(self, data_shards)
-        parity, digests = np.array(parity), np.array(digests)
-        mutate(self, parity, digests)
-        return parity, digests
-
-    pipeline.ErasurePipeline.encode = broken
-    return lambda: setattr(pipeline.ErasurePipeline, "encode", orig)
-
-
 def _run_broken(workload, seed, mutate, seconds):
     """The fault goes in once the server has started: the install's own
     warm-up holds every program to the host codec and refuses a wrong one, so a
@@ -85,7 +71,8 @@ def _run_broken(workload, seed, mutate, seconds):
     restore = []
     try:
         return bench_run.execute(args(workload, seed, seconds=seconds),
-                                 deployment_hook=lambda dep: restore.append(_break_encode(mutate)))
+                                 deployment_hook=lambda dep: restore.append(
+                                     faults.break_encode(mutate)))
     finally:
         for r in restore:
             r()
@@ -99,30 +86,17 @@ ENCODING_CELLS = [("put64m-c8", 20.0), ("mpput64m-p8m-c4", MP_SECONDS)]
 def test_fault_parity_altered_where_it_is_produced(workload, seconds):
     """One parity byte of every block flipped, and the row's digest made to
     match: bitrot verification passes, only the bytes read back can tell."""
-    from minio_tpu.ops.highwayhash import hash256
-
-    def mutate(pipe, parity, digests):
-        k = pipe.geom.data
-        parity[:, 0, 0] ^= 0x5A
-        for b in range(parity.shape[0]):
-            digests[b, k] = np.frombuffer(hash256(parity[b, 0].tobytes()), dtype=np.uint8)
-
-    rc, line = _run_broken(workload, 13, mutate, seconds)
+    rc, line = _run_broken(workload, 13, faults.alter_parity, seconds)
     assert rc == 0 and line["correct"] is False
     assert numbers(line)["degraded_mismatch"] >= 1
 
 
 @pytest.mark.parametrize("workload,seconds", ENCODING_CELLS)
 def test_fault_half_of_the_batch_left_out(workload, seconds):
-    """The second half of every device batch comes back without parity."""
-
-    def mutate(pipe, parity, digests):
-        half = max(1, parity.shape[0] // 2)
-        parity[half:] = 0
-        if parity.shape[0] == 1:
-            parity[:] = 0
-
-    rc, line = _run_broken(workload, 14, mutate, seconds)
+    """The second half of every device batch's real blocks comes back without
+    parity (the half is of the real blocks, not of the padded batch: a fault
+    that fell on padding slots alone would leave nothing to find)."""
+    rc, line = _run_broken(workload, 14, faults.leave_out_half, seconds)
     assert rc == 0 and line["correct"] is False
     assert numbers(line)["degraded_mismatch"] >= 1
 
@@ -519,3 +493,55 @@ def test_heal_fault_every_verify_on_the_host_codec():
     n = numbers(line)
     assert n["host_fallback_digest_chunks"] >= 1
     assert n["healed_shard_mismatch"] == 0 and n["reference_mismatch"] == 0
+
+
+# -- the cluster cell ---------------------------------------------------------------
+
+CLUSTER = "put64m-c8-n4"
+
+
+def test_cluster_cell_is_correct_on_the_sound_program():
+    """Four node processes; each key read back through another node than its
+    writer's; every shard file of the sample as the plain reference has it;
+    one chip, on the node every client sends to."""
+    rc, line = bench_run.execute(args(CLUSTER, 41, seconds=10.0))
+    assert rc == 0 and line["correct"] is True, line
+    n = numbers(line)
+    assert set(n) == set(check.limits(False, cluster=True)) and all(v == 0 for v in n.values()), n
+    assert line["attempted"] >= 4 and line["failed"] == 0 and line["metrics"] == {}
+    assert line["device"]["count"] == 1 and [n["node"] for n in line["device"]["nodes"]] == ["n1"]
+    assert not [d for d in os.listdir(server.SHM) if d == f"{server.PREFIX}{os.getpid()}"]
+
+
+def test_cluster_cell_control_one_parity_shard_fewer_is_not_correct():
+    rc, line = bench_run.execute(args(CLUSTER, 42, seconds=10.0, control="parity-1"))
+    assert rc == 0 and line["correct"] is False
+    n = numbers(line)
+    assert n["reference_mismatch"] >= 1 and n["degraded_mismatch"] >= 1
+    assert n["ops_failed"] == 0 and n["readback_mismatch"] == 0
+
+
+def test_cluster_fault_remote_shard_writes_answer_ok_and_write_nothing():
+    """Planted in node n1 once it serves: its PUTs' 12 remote shards go nowhere."""
+    rc, line = bench_run.execute(
+        args(CLUSTER, 43, seconds=10.0),
+        deployment_hook=lambda dep: dep.plant(0, "benchmark.tests.faults:hollow_remote_writes"))
+    assert rc == 0 and line["correct"] is False
+    n = numbers(line)
+    assert n["ops_failed"] + n["readback_mismatch"] + n["reference_mismatch"] >= 1
+
+
+@pytest.mark.parametrize("fault,caught_by", [
+    ("parity_altered", ("degraded_mismatch", "reference_mismatch")),
+    ("half_of_the_batch_left_out", ("degraded_mismatch", "reference_mismatch")),
+    ("put_returns_the_state_unchanged", ("readback_mismatch", "ops_failed")),
+])
+def test_cluster_fault_planted_in_one_node(fault, caught_by):
+    """The one-node cells' faults, planted in the chip node n1, which takes
+    every PUT: the check reads across nodes and still sees them."""
+    rc, line = bench_run.execute(
+        args(CLUSTER, 44, seconds=12.0),
+        deployment_hook=lambda dep: dep.plant(0, f"benchmark.tests.faults:{fault}"))
+    assert rc == 0 and line["correct"] is False
+    n = numbers(line)
+    assert sum(n[name] for name in caught_by) >= 1, n

@@ -2,7 +2,7 @@
 
 import pytest
 
-from benchmark.harness import trace
+from benchmark.harness import cluster, readers, roofline, trace
 
 S = 87382
 
@@ -80,3 +80,31 @@ def test_no_device_op_on_a_chip_the_process_holds_is_an_idle_chip_not_a_missing_
     # the hint changes nothing for a slice that has ops
     one = {"d": {"ops": OPS, "modules": MODULES}}
     assert trace.reduce(one, S, (0, 750), idle_chips=1, wall_s=5.0) == trace.reduce(one, S, (0, 750))
+
+
+def test_a_cluster_s_traces_merge_chip_by_chip():
+    """Each chip node reduces its own chip's trace; the merge averages busy
+    time (idle is the mean of the chips), sums the codec time (no block is
+    shared between chips), and names each gap by its node. The plain
+    `codec_roofline` over the merge is the chips' least time over their codec
+    time, summed: each chip under 100 % keeps the whole under it."""
+    one = trace.reduce({"d": {"ops": [ev("a", 0, 100, (S,))], "modules": [ev("m", 0, 100)]}},
+                       S, (0, 100))
+    two = trace.reduce({"d": {"ops": [ev("a", 0, 50, (S,))], "modules": [ev("m", 0, 50)]}},
+                       S, (0, 100))
+    idle = trace.reduce({}, S, idle_chips=1, wall_s=100e-9)
+    m = cluster.merge_traces([one, two, idle], wall_s=100e-9)
+    assert m["devices"] == 3 and m["span_s"] == 100e-9
+    assert m["busy_s"] == pytest.approx(50e-9)
+    assert m["codec_s"] == pytest.approx(150e-9)
+    assert m["device_ops"][0] == ["a", pytest.approx(150e-9)]
+    assert m["idle_gaps"][0][0].startswith("n3 ") and len(m["idle_gaps"]) <= 10
+    assert cluster.merge_traces([{}, {}], 5.0) == {}
+    k, m_, kind = 12, 4, "TPU v5 lite"
+    least = roofline.least_seconds(64, k, m_, S, kind)[0]
+    merged = cluster.merge_traces([{"busy_s": 0.1, "codec_s": 4 * least},
+                                   {"busy_s": 0.1, "codec_s": 2 * least}], wall_s=1.0)
+    src = {"trace": merged, "geometry": (k, m_, S), "device_kind": kind, "block_bytes": 1 << 20,
+           "traced": ({"codec": {"blocks_encoded": 0}}, {"codec": {"blocks_encoded": 128}})}
+    value = {"reader": {"kind": "trace", "value": "codec_roofline"}}
+    assert readers.read(value, src) == pytest.approx(100.0 * 2 / 6)  # chips at 25 and 50 %

@@ -2,7 +2,7 @@
 
 import pytest
 
-from benchmark.harness import run, window
+from benchmark.harness import cluster, readers, run, window
 
 MIB = 1 << 20
 
@@ -90,3 +90,53 @@ def test_an_mput_that_straddles_the_edge_is_prorated_like_any_op_and_counts_as_a
     assert facts["put_MiB"] == pytest.approx(64 + 32 + 64) and facts["get_MiB"] == 0
     assert window.counts_by_kind(ops, 0.0, 10.0)["MPUT"] == {
         "touching": 3, "ended_inside": 2, "failed": 1}
+
+
+def node_snap(t, rows, codec, compiles=0, cache=3):
+    return {"t": t, "ledger": {r: {"count": c, "wall_s": w, "cpu_s": w / 2}
+                               for r, (c, w) in rows.items()},
+            "codec": codec, "front": {"get_stream_hops": 1}, "cache_entries": cache,
+            "compiles": compiles}
+
+
+def test_a_cluster_snapshot_sums_the_nodes_row_by_row():
+    """The four nodes' ledgers, codec counters and front counters add up
+    row by row; a list counter is left out; the compile cache is one
+    directory every node reads."""
+    a = node_snap(5.0, {"object/encode": (2, 1.0), "rpc-peer//lock@h:1": (3, 0.3)},
+                  {"blocks_encoded": 64, "chip_blocks": [64], "mesh_devices": 1}, compiles=1)
+    b = node_snap(6.0, {"object/encode": (1, 0.5), "rpc-peer//createfile@h:2": (12, 2.4)},
+                  {"blocks_encoded": 32, "chip_blocks": [32], "mesh_devices": 1}, cache=4)
+    m = cluster.merge_snapshots([a, b])
+    assert m["ledger"]["object/encode"] == {"count": 3, "wall_s": 1.5, "cpu_s": 0.75}
+    assert m["ledger"]["rpc-peer//lock@h:1"]["count"] == 3
+    assert m["codec"] == {"blocks_encoded": 96, "mesh_devices": 2}
+    assert m["front"] == {"get_stream_hops": 2}
+    assert (m["cache_entries"], m["compiles"], m["t"]) == (4, 1, 6.0)
+
+
+def test_the_ledger_reader_takes_rows_by_prefix():
+    """`prefixes` adds every row of the window that starts with one (the
+    `rpc-peer/{path}@{peer}` rows), over the nodes' summed snapshots; no such
+    row reads nothing."""
+    def snap(rows, encoded):
+        return cluster.merge_snapshots(
+            [node_snap(0.0, rows, {"encoded_user_bytes": e}) for e in encoded])
+
+    a = snap({"rpc-peer//lock@h:1": (1, 0.1)}, [0, 0])
+    b = snap({"rpc-peer//lock@h:1": (5, 0.5), "rpc-peer//createfile?disk=x@h:2": (20, 2.0),
+              "object/encode": (4, 9.0)}, [300, 100])
+    src = {"window": (a, b), "facts": {"puts_ended": 2}}
+    rpc = {"kind": "ledger", "prefixes": ["rpc-peer/"], "field": "wall_s", "scale": 1000.0,
+           "per": "puts_ended"}
+    # (0.5 - 0.1) + 2.0 over the two nodes' sums, per PUT, in ms
+    assert readers.read({"reader": rpc}, src) == pytest.approx(2 * 2.4 * 1000 / 2)
+    calls = {**rpc, "field": "count", "scale": 1.0}
+    assert readers.read({"reader": calls}, src) == pytest.approx(2 * 24 / 2)
+    lock = {**rpc, "prefixes": ["rpc-peer//lock@", "rpc-peer//unlock@"]}
+    assert readers.read({"reader": lock}, src) == pytest.approx(2 * 0.4 * 1000 / 2)
+    assert readers.read({"reader": {**rpc, "prefixes": ["rpc-none/"]}}, src) is None
+    with pytest.raises(ValueError):
+        readers.validate("x", {"kind": "ledger", "field": "count"})
+    with pytest.raises(ValueError):
+        readers.validate("x", {"kind": "nodes", "counter": "encoded_user_bytes"})
