@@ -26,9 +26,11 @@ Design:
   * The batcher thread collects requests until `max_batch` or
     `batch_timeout_s` after the first arrival, pads the batch to a bucketed
     size (1/2/4/8/16/32...) to bound XLA compilations, runs the program, and
-    resolves futures. Under sustained load it double-buffers: batch i+1 is
-    dispatched (JAX async) before batch i's bytes are pulled off the
-    device, so host transfer overlaps device compute.
+    resolves futures. Each batch's copy home starts when it is launched.
+    Under sustained load the worker double-buffers: batch i+1 is
+    dispatched (JAX async) before batch i is resolved, so batch i's
+    transfer runs beside the device's compute of i+1 and the worker's own
+    host work on the batches around it.
 """
 
 from __future__ import annotations
@@ -207,6 +209,10 @@ class BatchingDeviceCodec(BlockCodec):
         # Batches whose device->host transfer overlapped the next batch's
         # compute (the worker's one-deep pending slot engaged).
         self.double_buffered_batches = 0
+        # Of those, the batches whose copy home had been started at their
+        # launch, so that the transfer also ran beside the worker's host
+        # work before it came back for them. Every launch starts its copy.
+        self.d2h_early_batches = 0
         # Multi-chip fan-out accounting: chip_blocks[g] counts real (non-pad)
         # blocks the dp-group g carried; with no mesh both stay trivial.
         self.mesh_devices = 1
@@ -416,7 +422,10 @@ class BatchingDeviceCodec(BlockCodec):
         pipe = self._pipelines[key]
         # One-deep pending slot: under sustained load batch i+1 is
         # dispatched (JAX queues transfer+compute asynchronously) before
-        # batch i's np.asarray blocks, so D2H of i overlaps compute of i+1.
+        # batch i is resolved. Batch i's copy home started at its launch,
+        # so its D2H overlaps the device's compute of i+1 and the worker's
+        # scatter of i-1 and collect, pack and h2d of i+1; d2h is what is
+        # left of it when the worker comes back.
         pending = None
         mark = _time.perf_counter()
         while not self._stop.is_set():
@@ -438,6 +447,7 @@ class BatchingDeviceCodec(BlockCodec):
                     if dispatched is not None:
                         with self._stats_lock:
                             self.double_buffered_batches += 1
+                            self.d2h_early_batches += 1
                 pending = dispatched
                 if pending is not None and q.empty():
                     # No follow-on work queued: resolve now, don't buy overlap
@@ -524,6 +534,11 @@ class BatchingDeviceCodec(BlockCodec):
                         arr = pipe.place(arr)
                     h2d *= pipe.mesh.shape["tp"]
                 parity, digests = pipe.encode(arr)
+                # Start the way home now, every shard of it: the bytes come
+                # back while the worker does other host work, and
+                # _resolve_batch's d2h waits only for what is left.
+                parity.copy_to_host_async()
+                digests.copy_to_host_async()
                 GLOBAL_PROFILER.copy.record("device-h2d", COPIED, h2d)
                 with self._stats_lock:
                     self.h2d_bytes += h2d
@@ -542,9 +557,9 @@ class BatchingDeviceCodec(BlockCodec):
         try:
             # What the worker pays waiting for the device (not device time:
             # under double-buffering the next batch is already in flight),
-            # then the way back to the host of what the host lacks: the
-            # parity rows and the digests. The data rows are in the staging
-            # array.
+            # then what is left of the way back to the host, started at
+            # launch, of what the host lacks: the parity rows and the
+            # digests. The data rows are in the staging array.
             with tracing.stage("device-wait", "codec"):
                 jax.block_until_ready((parity, digests))
             with tracing.stage("d2h", "codec"):
@@ -847,6 +862,7 @@ class BatchingDeviceCodec(BlockCodec):
                 "small_worker_wall_seconds": self.small_worker_wall_seconds,
                 "small_user_bytes": self.small_user_bytes,
                 "double_buffered_batches": self.double_buffered_batches,
+                "d2h_early_batches": self.d2h_early_batches,
                 "mesh_devices": self.mesh_devices,
                 "chip_blocks": list(self.chip_blocks),
                 "mesh_blocks_even": self.mesh_blocks_even,

@@ -2,13 +2,17 @@
 concurrency."""
 
 import threading
+import time
 
+import jax
 import numpy as np
 import pytest
 
 from minio_tpu.object.codec import HostCodec, strided_runs
 from minio_tpu.ops import rs_matrix
+from minio_tpu.parallel import mesh as mesh_lib
 from minio_tpu.parallel.batching import BatchingDeviceCodec
+from tests import reference_store as ref
 
 # Stressed under adversarial thread scheduling by tools/race_gate.py.
 pytestmark = pytest.mark.race
@@ -566,3 +570,140 @@ def test_strided_runs_split_at_every_break():
     assert [(a, b) for a, b, _ in strided_runs([mv[8:16], mv[4:12], mv[0:8]], 8)] == [
         (0, 1), (1, 2), (2, 3)]
     del runs, whole
+
+
+# -- a batch's copy home starts at its launch ---------------------------------
+
+
+class _Recorded:
+    """A device array that logs the start of its copy home."""
+
+    def __init__(self, arr, tag, log):
+        self._arr, self._tag, self._log = arr, tag, log
+
+    def copy_to_host_async(self):
+        self._log.append(("copy", self._tag))
+        self._arr.copy_to_host_async()
+
+    def block_until_ready(self):
+        self._arr.block_until_ready()
+        return self
+
+    def __array__(self, dtype=None, copy=None):
+        return np.asarray(self._arr, dtype=dtype)
+
+
+def _log_batch_life(codec, k, m, log):
+    """Log each batch's launch (numbered), the start of its outputs' copies,
+    the bounds of every _dispatch_batch and the entry of its _resolve_batch."""
+    codec._ensure_worker(k, m)
+    pipe = codec._pipelines[(k, m)]
+    encode, dispatch, resolve = pipe.encode, codec._dispatch_batch, codec._resolve_batch
+
+    def launched(arr):
+        n = sum(e[0] == "launch" for e in log)
+        log.append(("launch", n))
+        parity, digests = encode(arr)
+        return _Recorded(parity, ("parity", n), log), _Recorded(digests, ("digests", n), log)
+
+    def dispatched(*args):
+        log.append(("dispatch-in",))
+        rec = dispatch(*args)
+        log.append(("dispatch-out",))
+        return rec
+
+    def resolved(rec):
+        log.append(("resolve", rec[1]._tag[1]))
+        resolve(rec)
+
+    pipe.encode, codec._dispatch_batch, codec._resolve_batch = launched, dispatched, resolved
+
+
+def _encode_queue_full(codec, k, m, blocks):
+    """Encode `blocks` in one call with every block queued before the first
+    batch launches, so that the worker finds the next batch waiting each time
+    it comes back, up to the last."""
+    q = codec._ensure_worker(k, m)
+    gate = threading.Event()
+    taken: list[int] = []
+    dispatch = codec._dispatch_batch
+
+    def held(pipe, kk, mm, batch):
+        taken.append(len(batch))
+        gate.wait(30)
+        return dispatch(pipe, kk, mm, batch)
+
+    codec._dispatch_batch = held
+    out: dict = {}
+    t = threading.Thread(target=lambda: out.setdefault("got", codec.encode(blocks, k, m)))
+    t.start()
+    deadline = time.monotonic() + 30
+    while q.qsize() + sum(taken) < len(blocks) and time.monotonic() < deadline:
+        time.sleep(0.001)
+    gate.set()
+    t.join(60)
+    return out["got"]
+
+
+def _mesh_of(shape):
+    if shape is None:
+        return None
+    if jax.device_count() < 4:
+        pytest.skip("needs conftest's virtual devices")
+    return mesh_lib.make_mesh(4, shape)
+
+
+@pytest.mark.parametrize("shape", [None, (2, 2, 1)], ids=["one-device", "mesh-2x2x1"])
+@pytest.mark.parametrize("k,m,n", PACK_GEOMS)
+def test_every_batch_starts_its_copy_home_inside_its_dispatch(k, m, n, shape):
+    codec = BatchingDeviceCodec(block_size=n, max_batch=4, batch_timeout_s=0.25,
+                                mesh=_mesh_of(shape))
+    log: list = []
+    try:
+        _log_batch_life(codec, k, m, log)
+        _, blocks = _window(41, 14, n)
+        assert _encode_queue_full(codec, k, m, blocks) == _host_of(blocks, k, m)
+        launches = [e[1] for e in log if e[0] == "launch"]
+        assert launches == list(range(codec.stats()["batches_run"])) and len(launches) >= 4
+        for b in launches:
+            at = log.index(("launch", b))
+            copies = [log.index(("copy", (name, b))) for name in ("parity", "digests")]
+            into = max(i for i, e in enumerate(log[:at]) if e == ("dispatch-in",))
+            out = log.index(("dispatch-out",), into)
+            assert into < at < min(copies) and max(copies) < out
+            assert max(copies) < log.index(("resolve", b))
+    finally:
+        codec.close()
+
+
+@pytest.mark.parametrize("shape", [None, (2, 2, 1)], ids=["one-device", "mesh-2x2x1"])
+@pytest.mark.parametrize("k,m,n", PACK_GEOMS)
+def test_a_full_queue_resolves_early_copies_bit_exact(k, m, n, shape):
+    codec = BatchingDeviceCodec(block_size=n, max_batch=4, batch_timeout_s=0.25,
+                                mesh=_mesh_of(shape))
+    try:
+        _, blocks = _window(43, 18, n)
+        got = _encode_queue_full(codec, k, m, blocks)
+        for block, (rows, digests) in zip(blocks, got):
+            assert (rows, digests) == ref.encode_block(bytes(block), k, m)
+        st = codec.stats()
+        assert st["double_buffered_batches"] >= 3
+        assert 0 < st["d2h_early_batches"] <= st["double_buffered_batches"] <= st["batches_run"]
+        assert st["blocks_encoded"] == len(blocks)
+    finally:
+        codec.close()
+
+
+@pytest.mark.parametrize("k,m,n", PACK_GEOMS)
+def test_a_lone_batch_resolved_at_once_counts_no_early_batch(k, m, n):
+    codec = BatchingDeviceCodec(block_size=n, max_batch=64, batch_timeout_s=0.25, mesh=None)
+    log: list = []
+    try:
+        _log_batch_life(codec, k, m, log)
+        _, blocks = _window(47, 5, n)
+        assert codec.encode(blocks, k, m) == _host_of(blocks, k, m)
+        st = codec.stats()
+        assert (st["batches_run"], st["double_buffered_batches"], st["d2h_early_batches"]) == (1, 0, 0)
+        assert log.index(("copy", ("parity", 0))) < log.index(("resolve", 0))
+    finally:
+        codec.close()
